@@ -24,6 +24,7 @@ import numpy as np
 from repro.analysis.stencil_roofline import model_program
 from repro.apps import pw_advection, pw_advection_update, tracer_advection
 from repro.core import compile_program, run_time_loop
+from repro.compile_cache import enable_compile_cache
 
 # paper sizes: 8M / 32M points (134M is modeled only on this container)
 SIZES = {
@@ -129,6 +130,7 @@ def run_fused_loop(emit, grid=FUSED_GRID, steps=FUSED_STEPS,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fused-loop", action="store_true",
                     help="run only the fused-vs-host time-loop comparison")

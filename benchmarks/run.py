@@ -500,6 +500,8 @@ def lm_roofline_summary(emit):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # no prefix abbreviation: the import-time _mesh_arg scanner (which sized
     # the simulated device count before jax loaded) only matches the full
     # --mesh spelling, and the two must never diverge

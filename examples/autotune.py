@@ -24,6 +24,9 @@ import numpy as np
 
 from repro.apps import pw_advection, pw_advection_update
 from repro.core import CompileOptions, PlanCache, TuneConfig, compile_program
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser(description=__doc__)
 ap.add_argument("--backend", default="jnp_fused",

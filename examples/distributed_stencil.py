@@ -26,9 +26,11 @@ import numpy as np                                      # noqa: E402
 from repro.apps import pw_advection, pw_advection_update  # noqa: E402
 from repro.core import compile_program, run_time_loop   # noqa: E402
 from repro.dist.sharding import make_auto_mesh          # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     mesh = make_auto_mesh((2, 2, 2), ("X", "Y", "Z"))
     grid = (64, 64, 128)
     steps = 8

@@ -20,11 +20,13 @@ import numpy as np
 
 from repro.apps import pw_advection, pw_advection_update
 from repro.core import compile_program, run_time_loop
+from repro.compile_cache import enable_compile_cache
 
 SIZES = {"1M": (128, 64, 128), "8M": (256, 256, 128), "32M": (512, 256, 256)}
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", default="1M", choices=list(SIZES))
     ap.add_argument("--steps", type=int, default=5)

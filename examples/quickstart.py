@@ -11,6 +11,9 @@ import numpy as np
 
 from repro.core import ProgramBuilder, compile_program
 from repro.core.schedule import auto_plan, vmem_cost
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # -- 1. write the maths (stencil dialect analogue) --------------------------
 b = ProgramBuilder("diffusion", ndim=3)

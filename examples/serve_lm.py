@@ -15,9 +15,11 @@ import numpy as np
 from repro.configs import ARCHS, get_smoke
 from repro.models import init_lm
 from repro.models import ServeEngine
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2_2b",
                     choices=[a for a in ARCHS if a != "whisper_small"])

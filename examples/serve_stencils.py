@@ -18,6 +18,7 @@ import numpy as np
 from repro.apps import pw_advection, pw_advection_update
 from repro.core import compile_program
 from repro.serve import StencilEngine, StencilRequest
+from repro.compile_cache import enable_compile_cache
 
 GRIDS = [(16, 16, 16), (12, 14, 16), (16, 16, 24), (10, 16, 16)]
 
@@ -34,6 +35,7 @@ def make_request(p, update, grid, rng, steps):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--steps", type=int, default=3)

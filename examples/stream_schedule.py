@@ -26,6 +26,9 @@ from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
 from repro.core import CompileOptions, compile_program, lower_to_dataflow
 from repro.core.schedule import auto_plan
 from repro.analysis.stencil_roofline import plan_bytes_per_point
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser(description=__doc__)
 ap.add_argument("--kernel", default="pw", choices=("pw", "tracer"))

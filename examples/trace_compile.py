@@ -28,6 +28,9 @@ from repro.apps import pw_advection, pw_advection_update
 from repro.core import PlanCache, TuneConfig, compile_program
 from repro.obs import Tracer, global_metrics
 from repro.serve import StencilEngine, StencilRequest
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser(description=__doc__)
 ap.add_argument("--out", default="TRACE_compile.json",

@@ -13,6 +13,7 @@ import argparse
 from repro.configs.base import ModelConfig
 from repro.data import BatchSpec, SyntheticLM
 from repro.train import OptConfig, TrainConfig, Trainer
+from repro.compile_cache import enable_compile_cache
 
 
 def preset(name: str):
@@ -32,6 +33,7 @@ def preset(name: str):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=["tiny", "100m"])
     ap.add_argument("--steps", type=int, default=30)

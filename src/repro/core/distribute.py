@@ -52,11 +52,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # public since jax 0.6; experimental before that
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..kernels.stencil3d import build_group_call
 from ..obs.trace import current_tracer
 from . import boundary as bc
@@ -134,12 +129,8 @@ def halo_exchange_pad(x: jnp.ndarray, lo: Sequence[int], hi: Sequence[int],
 # --------------------------------------------------------------------------
 
 def _smap(fn, mesh: Mesh, in_specs, out_specs):
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax 0.4.x spells the replication check check_rep
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _origin_inputs(shard: ShardSpec):
@@ -320,14 +311,12 @@ def _pallas_calls(p: Program, plan: DataflowPlan, local_grid, global_grid,
     the *shard-local* stream extent by ``lower_to_dataflow``)."""
     if plan.schedule == "stream":
         return [build_stream_call(p, region, local_grid, dtype=jdtype,
-                                  interpret=plan.interpret,
                                   global_extent=global_grid,
                                   time_tile=time_tile, update=update,
                                   plane_tile=getattr(graph, "plane_tile", 1),
                                   stream_sharded=graph.stream_sharded)
                 for region in graph.regions]
     return [build_group_call(p, grp, plan.block, local_grid, dtype=jdtype,
-                             interpret=plan.interpret,
                              global_extent=global_grid)
             for grp in plan.groups]
 
@@ -658,8 +647,7 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
 def make_sharded_executor(p: Program, global_grid, mesh: Mesh,
                           mesh_axes: Sequence, *,
                           plan: DataflowPlan | None = None,
-                          backend: str = "pallas",
-                          interpret: bool = True, dtype: str = "float32"):
+                          backend: str = "pallas", dtype: str = "float32"):
     """Deprecated: use ``compile_program(p, grid, mesh=..., mesh_axes=...)``.
 
     Kept as a thin forwarding wrapper so existing callers keep working;
@@ -672,7 +660,7 @@ def make_sharded_executor(p: Program, global_grid, mesh: Mesh,
         DeprecationWarning, stacklevel=2)
     from .pipeline import CompileOptions, compile_program
     ex = compile_program(p, global_grid, options=CompileOptions(
-        backend=backend, plan=plan, interpret=interpret, dtype=dtype,
+        backend=backend, plan=plan, dtype=dtype,
         mesh=mesh, mesh_axes=mesh_axes))
     ex.local_grid = ex.shard.local_grid
     ex.mesh_axes = ex.shard.mesh_axes

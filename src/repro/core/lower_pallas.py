@@ -70,8 +70,7 @@ def lower(p: Program, plan: DataflowPlan, grid_shape):
     """Return fn(fields, scalars) -> dict of output arrays."""
     dtype = _DTYPES[plan.dtype]
     grid_shape = tuple(int(g) for g in grid_shape)
-    calls = [build_group_call(p, grp, plan.block, grid_shape, dtype=dtype,
-                              interpret=plan.interpret)
+    calls = [build_group_call(p, grp, plan.block, grid_shape, dtype=dtype)
              for grp in plan.groups]
     return lower_from_calls(p, dtype, calls)
 
@@ -120,8 +119,7 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     """
     dtype = _DTYPES[plan.dtype]
     grid_shape = tuple(int(g) for g in grid_shape)
-    calls = [build_group_call(p, grp, plan.block, grid_shape, dtype=dtype,
-                              interpret=plan.interpret)
+    calls = [build_group_call(p, grp, plan.block, grid_shape, dtype=dtype)
              for grp in plan.groups]
     return time_loop_from_calls(p, dtype, grid_shape, spec, update, calls)
 

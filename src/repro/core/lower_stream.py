@@ -42,6 +42,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import hw
 from ..obs.trace import current_tracer
 from .dataflow import StreamGraph, StreamRegion, lower_to_dataflow
 from .expr_eval import evaluate
@@ -51,8 +52,8 @@ from .schedule import DataflowPlan, TimeLoopSpec, adapt_update
 
 
 def build_stream_call(p: Program, region: StreamRegion, grid_shape,
-                      dtype=jnp.float32, interpret: bool = True,
-                      global_extent=None, time_tile: int = 1, update=None,
+                      dtype=jnp.float32, global_extent=None,
+                      time_tile: int = 1, update=None,
                       stream_sharded: bool = False, plane_tile: int = 1):
     """Build a callable(padded_inputs, scalars, coeffs, origin) -> outputs
     streaming one region over the outer axis (see module docstring).
@@ -178,8 +179,8 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
 
     def kernel(*refs):
         i = 0
-        s_ref = refs[i]; i += 1                      # scalars (SMEM, f32)
-        org_ref = refs[i]; i += 1                    # shard origin (SMEM, i32)
+        s_ref = refs[i]; i += 1                      # (1, n) scalars, SMEM
+        org_ref = refs[i]; i += 1                    # (1, ndim) origin, SMEM
         in_refs = {f: refs[i + k] for k, f in enumerate(gh.group_inputs)}
         i += len(gh.group_inputs)
         coeff_refs = {c: refs[i + k] for k, c in enumerate(gh.group_coeffs)}
@@ -234,12 +235,12 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                                for s in range(1, T)]
         ring_vals_all = [{t: stage_ring_refs[s][t][...] for t in ring_names}
                          for s in range(T)]
-        coeff_windows = {c: r[...] for c, r in coeff_refs.items()}
+        coeff_windows = {c: r[0] for c, r in coeff_refs.items()}
 
         def scalar(name: str):
-            return s_ref[scalar_index[name]]
+            return s_ref[0, scalar_index[name]]
 
-        sdict = {nm: s_ref[scalar_index[nm]] for nm in p.scalars}
+        sdict = {nm: s_ref[0, scalar_index[nm]] for nm in p.scalars}
         completed = {f: [] for f in store_names}
 
         for k_plane in range(P):
@@ -321,7 +322,7 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                         for ax in range(1, ndim):
                             if not m[ax].any():
                                 continue
-                            g0 = org_ref[ax] - int(m[ax, 0])
+                            g0 = org_ref[0, ax] - int(m[ax, 0])
                             coord = g0 + jax.lax.broadcasted_iota(
                                 jnp.int32, ext, ax - 1)
                             ok = (coord >= 0) & (coord < global_extent[ax])
@@ -337,7 +338,7 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                         # legalised into splits).  Rings shift per *virtual*
                         # step in registers; the refs commit once per grid
                         # step below.
-                        cg = org_ref[0] + c_plane
+                        cg = org_ref[0, 0] + c_plane
                         ok = (cg >= 0) & (cg < global_extent[0])
                         stored = jnp.where(ok, res, jnp.zeros_like(res))
                         ring_vals[op.out] = jnp.concatenate(
@@ -385,14 +386,14 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                 # the rings stand in for the outer loop's re-padded carry,
                 # so out-of-domain cells (non-stream margins and warm-up/
                 # out-of-sweep planes) must store as zeros
-                cg = org_ref[0] + c_plane
+                cg = org_ref[0, 0] + c_plane
                 ok = (cg >= 0) & (cg < global_extent[0])
                 mask = jnp.broadcast_to(ok, ext_s)
                 for ax in range(1, ndim):
                     if acc * (hl[ax] + hh[ax]) == 0 and grid_shape[ax] == \
                             global_extent[ax]:
                         continue
-                    g0 = org_ref[ax] - acc * hl[ax]
+                    g0 = org_ref[0, ax] - acc * hl[ax]
                     coord = g0 + jax.lax.broadcasted_iota(jnp.int32, ext_s,
                                                           ax - 1)
                     mask = mask & (coord >= 0) & (coord < global_extent[ax])
@@ -440,7 +441,7 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
     for c in gh.group_coeffs:
         ax = coeff_axis[c]
         length = n_steps if ax == 0 else plane_ext[ax - 1]
-        in_specs.append(pl.BlockSpec((length,), lambda s: (0,)))
+        in_specs.append(pl.BlockSpec((1, length), lambda s: (0, 0)))
 
     out_block = (P,) + grid_shape[1:]
     out_specs = tuple(
@@ -476,7 +477,10 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
         out_specs=out_specs if len(store_names) > 1 else out_specs[0],
         out_shape=out_shape if len(store_names) > 1 else out_shape[0],
         scratch_shapes=scratch,
-        interpret=interpret,
+        # the window buffers and rings carry state from one sweep step to
+        # the next, so the sweep axis must run in order on one core
+        compiler_params=hw.pallas_compiler_params(("arbitrary",)),
+        interpret=hw.pallas_interpret(),
     )
 
     expect = tuple(halo_lo[a] + grid_shape[a] + halo_hi[a]
@@ -493,7 +497,9 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                 else jnp.zeros((max(n_scalars, 1),), jnp.float32))
         org = (origin if origin is not None
                else jnp.zeros((ndim,), jnp.int32))
-        args = [svec, org]
+        # every small operand is a (1, n) row: vmap then prepends the batch
+        # axis ahead of the last two, where Mosaic's block rule allows it
+        args = [svec.reshape(1, -1), org.reshape(1, -1)]
         for f in gh.group_inputs:
             x = padded_inputs[f]
             if input_pad is not None and f in input_pad:
@@ -510,7 +516,7 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                 x = jnp.pad(x, [(0, pad_round)] + [(0, 0)] * (ndim - 1))
             args.append(x)
         for c in gh.group_coeffs:
-            args.append(padded_coeffs[c])
+            args.append(padded_coeffs[c].reshape(1, -1))
         res = call(*args)
         if len(store_names) == 1:
             res = (res,)
@@ -549,7 +555,6 @@ def _build_calls(p: Program, plan: DataflowPlan, grid_shape,
     if graph is None:
         graph = lower_to_dataflow(p, plan, grid_shape)
     calls = [build_stream_call(p, region, grid_shape, dtype=dtype,
-                               interpret=plan.interpret,
                                plane_tile=getattr(graph, "plane_tile", 1))
              for region in graph.regions]
     return dtype, calls
@@ -605,13 +610,13 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     region = graph.regions[0]       # chain legality implies a single region
     upd = adapt_update(update)
     calls = [build_stream_call(p, region, grid_shape, dtype=dtype,
-                               interpret=plan.interpret, time_tile=T,
+                               time_tile=T,
                                update=upd, plane_tile=P)]
     rem = int(spec.steps) % T
     epilogue = None
     if rem:
         epilogue = [build_stream_call(
-            p, region, grid_shape, dtype=dtype, interpret=plan.interpret,
-            time_tile=rem, update=upd, plane_tile=P)]
+            p, region, grid_shape, dtype=dtype, time_tile=rem, update=upd,
+            plane_tile=P)]
     return time_loop_from_calls(p, dtype, grid_shape, spec, update, calls,
                                 chain=T, epilogue=epilogue)

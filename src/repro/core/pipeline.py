@@ -86,7 +86,6 @@ class CompileOptions:
     backend: str = "pallas"
     plan: DataflowPlan | None = None
     jit: bool = True
-    interpret: bool = True
     dtype: str = "float32"
     strategy: str = "auto"
     steps: int | None = None
@@ -180,6 +179,19 @@ class CompiledStencil:
                  coeffs: Mapping | None = None) -> dict:
         return self._fn(dict(fields), dict(scalars or {}), dict(coeffs or {}))
 
+    def lower(self, fields: Mapping, scalars: Mapping | None = None,
+              coeffs: Mapping | None = None):
+        """Ahead-of-time lowering (``jax.stages.Lowered``) for these
+        arguments — arrays or ``jax.ShapeDtypeStruct`` — so a caller can
+        time the compile apart from the run, or read the program's HLO.
+        The returned stage's ``compile()`` is called with the same three
+        dicts."""
+        if not self.jitted:
+            raise ValueError("lower() needs an executable compiled with "
+                             "jit=True")
+        return self._fn.lower(dict(fields), dict(scalars or {}),
+                              dict(coeffs or {}))
+
 
 def compile_program(p: Program, grid, *,
                     options: CompileOptions | None = None,
@@ -262,7 +274,7 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
     ``tracer`` installed as the ambient one, so the layers below — plan
     legalisation, tuning, sharded/stream lowering — emit into it without
     threading a tracer argument everywhere)."""
-    backend, plan, jit, interpret = o.backend, o.plan, o.jit, o.interpret
+    backend, plan, jit = o.backend, o.plan, o.jit
     dtype, strategy, steps, update = o.dtype, o.strategy, o.steps, o.update
     carry_write, tune_config = o.carry_write, o.tune_config
     plan_cache, mesh, mesh_axes = o.plan_cache, o.mesh, o.mesh_axes
@@ -311,16 +323,14 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
     if plan is None:
         if strategy == "tuned":
             from . import tune
-            res = tune.get_tuned_plan(p, grid, backend=backend,
-                                      interpret=interpret, dtype=dtype,
+            res = tune.get_tuned_plan(p, grid, backend=backend, dtype=dtype,
                                       update=update, config=tune_config,
                                       cache=plan_cache,
                                       mesh=mesh, mesh_axes=mesh_axes)
             plan, tuned_cw = res.plan, res.carry_write
             tuned_rec = res.record
         else:
-            plan = auto_plan(p, plan_grid, backend=backend,
-                             interpret=interpret, dtype=dtype,
+            plan = auto_plan(p, plan_grid, backend=backend, dtype=dtype,
                              strategy=strategy, steps=steps,
                              schedule=schedule or "block",
                              time_tile=time_tile or 1,
@@ -347,8 +357,8 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
             overrides.setdefault("time_tile", 1)
             overrides.setdefault("plane_tile", 1)
             overrides["block"] = auto_plan(
-                p, plan_grid, backend=backend, interpret=interpret,
-                dtype=plan.dtype, steps=steps).block
+                p, plan_grid, backend=backend, dtype=plan.dtype,
+                steps=steps).block
     plan = dataclasses.replace(plan, groups=[list(g) for g in plan.groups],
                                **overrides)
     if carry_write is None:

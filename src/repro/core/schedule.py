@@ -123,8 +123,6 @@ class DataflowPlan:
     dtype: str = "float32"
     # backend: "pallas" | "jnp_fused" | "jnp_naive"
     backend: str = "pallas"
-    # run pallas in interpret mode (CPU container) — real runs set False
-    interpret: bool = True
     # distributed layout: mesh axis name per grid axis (None entry =
     # unsharded axis).  ``None`` means fully unsharded; stored tuples are
     # normalised to the program's ndim via :meth:`mesh_axes_for` rather than
@@ -211,7 +209,6 @@ def plan_to_dict(plan: DataflowPlan) -> dict:
         "block": [int(b) for b in plan.block],
         "dtype": plan.dtype,
         "backend": plan.backend,
-        "interpret": bool(plan.interpret),
         "mesh_axes": (None if plan.mesh_axes is None
                       else list(plan.mesh_axes)),
         "halo_every": int(plan.halo_every),
@@ -233,7 +230,6 @@ def plan_from_dict(d: dict) -> DataflowPlan:
         block=tuple(d["block"]),
         dtype=d.get("dtype", "float32"),
         backend=d.get("backend", "pallas"),
-        interpret=bool(d.get("interpret", True)),
         mesh_axes=None if ma is None else tuple(ma),
         halo_every=int(d.get("halo_every", 1)),
         schedule=d.get("schedule", "block"),
@@ -345,7 +341,7 @@ def mesh_fingerprint(mesh, mesh_axes) -> str:
 
 def bucket_fingerprint(p: Program, bucket: Sequence[int], *,
                        backend: str, dtype: str = "float32",
-                       interpret: bool = True, schedule: str | None = None,
+                       schedule: str | None = None,
                        steps: int | None = None,
                        mesh=None, mesh_axes=None,
                        plane_tile: int | None = None) -> str:
@@ -363,7 +359,7 @@ def bucket_fingerprint(p: Program, bucket: Sequence[int], *,
         "bucket=" + "x".join(str(int(b)) for b in bucket),
         f"backend={backend}",
         f"dtype={dtype}",
-        f"interpret={int(bool(interpret))}",
+        f"interpret={int(hw.pallas_interpret())}",
         f"schedule={schedule or 'plan'}",
         f"steps={'single' if steps is None else int(steps)}",
         f"plane_tile={'plan' if plane_tile is None else int(plane_tile)}",
@@ -806,19 +802,45 @@ def _vmem_cost_stream(p: Program, plan: DataflowPlan, grid: tuple,
     return 2 * worst  # double-buffered pipeline, as in the block schedule
 
 
+#: Grid points one block-schedule tile starts from: a 32x32x128 tile's
+#: worth, or 4 planes of a 256x128 grid.  Mosaic compiles a PW-advection
+#: kernel over 16 such planes for a v5e in ~30 s of host CPU, over 4 in
+#: ~5 s.
+TILE_POINTS = 32 * 32 * 128
+
+
+def cap_tile(block: Sequence[int], grid: Sequence[int]) -> tuple:
+    """The block-schedule tile shape: ``block`` on the leading axes (clipped
+    to ``grid``) and the whole grid on the last two, with the leading axes
+    halved, outermost first, until the tile holds at most
+    :data:`TILE_POINTS` points (or every leading extent is 1).  Mosaic
+    unrolls the kernel body over the whole tile, so its compile time grows
+    with the tile."""
+    n_tiled = max(0, len(grid) - 2)
+    blk = ([max(1, min(int(block[ax]), int(grid[ax])))
+            for ax in range(n_tiled)] + [int(g) for g in grid[n_tiled:]])
+    for ax in range(n_tiled):
+        while blk[ax] > 1 and int(np.prod(blk)) > TILE_POINTS:
+            blk[ax] //= 2
+    return tuple(blk)
+
+
 def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
-              interpret: bool = True, strategy: str = "auto",
+              strategy: str = "auto",
               dtype: str = "float32",
               vmem_budget: int = hw.VMEM_PLAN_BUDGET,
               steps: int | None = None,
               schedule: str = "block",
               time_tile: int = 1,
               plane_tile: int = 1) -> DataflowPlan:
-    """Pick fuse groups and a lane-aligned block shape that fits VMEM.
+    """Pick fuse groups and a block shape that fits VMEM.
 
     Mirrors the paper's auto-optimisation: the planner, not the programmer,
-    chooses the dataflow structure.  Last axis is lane-aligned to 128
-    (the 512-bit-burst analogue); the remaining axes shrink first.
+    chooses the dataflow structure.  Only the leading axes tile; the last
+    two stay whole.  Mosaic takes a block whose last two extents match the
+    array's or its (8, 128) tiling, and an overlapping window (tile plus
+    halo) matches neither unless it spans the whole padded axis — whole
+    rows along the lane axis are also the 512-bit-burst analogue.
 
     With ``steps`` (the plan will drive a fused time loop) the budget check
     uses the carry-aware :func:`vmem_cost`, so blocks whose loop-carry
@@ -830,7 +852,7 @@ def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
     groups = stage_split(p, strategy)
     if schedule == "stream":
         return _auto_plan_stream(p, grid, groups, backend=backend,
-                                 interpret=interpret, dtype=dtype,
+                                 dtype=dtype,
                                  vmem_budget=vmem_budget,
                                  time_tile=time_tile,
                                  plane_tile=plane_tile)
@@ -841,31 +863,24 @@ def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
         raise ValueError("plane_tile > 1 requires schedule='stream' "
                          "(spatial unrolling widens the stream sweep)")
 
-    # start from a generous tile and shrink to fit the budget
-    blk = []
-    for ax in range(ndim):
-        if ax == ndim - 1:  # lane axis: multiples of 128, at least 128
-            blk.append(min(grid[ax], max(hw.LANE, hw.align_down(grid[ax], hw.LANE))))
-        else:
-            blk.append(min(grid[ax], 32 if ndim == 3 else 256))
-    blk = [max(1, b) for b in blk]
+    # start from a tile of at most TILE_POINTS, then shrink to fit the
+    # VMEM budget
+    n_tiled = max(0, ndim - 2)
+    blk = list(cap_tile([32 if ndim == 3 else 256] * n_tiled, grid))
 
     def fits(b):
         plan = DataflowPlan(groups=groups, block=tuple(b), dtype=dtype,
-                            backend=backend, interpret=interpret,
-                            mesh_axes=(None,) * ndim)
+                            backend=backend, mesh_axes=(None,) * ndim)
         return vmem_cost(p, plan, grid, steps=steps) <= vmem_budget
 
-    # shrink non-lane axes first, then the lane axis (keep 128 quanta)
+    # halve the leading axes, outermost first
     guard = 0
     while not fits(blk) and guard < 64:
         guard += 1
-        order = list(range(ndim - 1)) + [ndim - 1]
         shrunk = False
-        for ax in order:
-            quantum = hw.LANE if ax == ndim - 1 else 1
-            if blk[ax] > quantum:
-                blk[ax] = max(quantum, blk[ax] // 2)
+        for ax in range(n_tiled):
+            if blk[ax] > 1:
+                blk[ax] //= 2
                 shrunk = True
                 break
         if not shrunk:
@@ -875,12 +890,11 @@ def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
             else:
                 break
     return DataflowPlan(groups=groups, block=tuple(blk), dtype=dtype,
-                        backend=backend, interpret=interpret,
-                        mesh_axes=(None,) * ndim)
+                        backend=backend, mesh_axes=(None,) * ndim)
 
 
 def _auto_plan_stream(p: Program, grid: tuple, groups: list, *,
-                      backend: str, interpret: bool, dtype: str,
+                      backend: str, dtype: str,
                       vmem_budget: int, time_tile: int = 1,
                       plane_tile: int = 1) -> DataflowPlan:
     """Stream-scheduled plan: one rolling-window sweep over the outer axis
@@ -900,8 +914,8 @@ def _auto_plan_stream(p: Program, grid: tuple, groups: list, *,
 
     def build(groups, tile, ptile):
         plan = DataflowPlan(groups=groups, block=block, dtype=dtype,
-                            backend=backend, interpret=interpret,
-                            mesh_axes=(None,) * ndim, schedule="stream",
+                            backend=backend, mesh_axes=(None,) * ndim,
+                            schedule="stream",
                             time_tile=tile, plane_tile=ptile)
         graph = lower_to_dataflow(p, plan, grid)
         plan.stream = graph.spec()

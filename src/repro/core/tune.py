@@ -19,7 +19,8 @@ auto_plan` is the one-shot heuristic seed; this module closes the loop:
    ``benchmarks/fig4_throughput.py``), in both single-step and fused
    ``steps=N`` modes when an update rule is available;
 4. **persist** the winner in a JSON plan cache keyed by (program
-   fingerprint, grid, backend, jax version, interpret flag), so
+   fingerprint, grid, backend, jax version, interpreted or compiled
+   kernels), so
    ``compile_program(..., strategy="tuned")`` is a pure cache hit — zero
    measured runs — after the first tune.
 
@@ -54,7 +55,7 @@ from ..obs.metrics import MetricsRegistry, global_metrics
 from ..obs.trace import current_tracer
 from .ir import Program
 from .schedule import (PLAN_SCHEMA_VERSION, DataflowPlan, auto_plan,
-                       mesh_fingerprint, plan_from_dict, plan_to_dict,
+                       cap_tile, mesh_fingerprint, plan_from_dict, plan_to_dict,
                        program_fingerprint, vmem_cost)
 
 __all__ = [
@@ -233,11 +234,12 @@ def _mesh_tag(mesh, mesh_axes) -> str:
 
 
 def cache_key(p: Program, grid: Sequence[int], backend: str,
-              interpret: bool, dtype: str = "float32",
+              dtype: str = "float32",
               mode: str = "loop", mesh=None, mesh_axes=None) -> str:
     """Tuned plans transfer only between identical search problems: same
     program semantics (boundary conditions included, via the fingerprint),
-    grid, backend, jax version, interpret flag, requested dtype, mesh
+    grid, backend, jax version, whether the kernels run interpreted or
+    compiled (:func:`repro.hw.pallas_interpret`), requested dtype, mesh
     topology, and tuning mode (``"loop"`` = ranked by the fused ``steps=N``
     measurement with carry-aware VMEM pruning, ``"single"`` = single-step
     only) — a single-step winner must not silently serve a fused compile,
@@ -247,7 +249,7 @@ def cache_key(p: Program, grid: Sequence[int], backend: str,
         "grid=" + "x".join(str(int(g)) for g in grid),
         f"backend={backend}",
         f"jax={jax.__version__}",
-        f"interpret={int(bool(interpret))}",
+        f"interpret={int(hw.pallas_interpret())}",
         f"dtype={dtype}",
         f"mode={mode}",
         f"mesh={_mesh_tag(mesh, mesh_axes)}",
@@ -294,21 +296,16 @@ class TuneResult:
 # --------------------------------------------------------------------------
 
 def _block_candidates(p: Program, grid: Sequence[int]) -> list:
-    """Lane-quantised last axis (x128 bursts), coarse sweep elsewhere."""
-    ndim = p.ndim
+    """A coarse sweep over the leading axes, each tile capped as
+    :func:`~repro.core.schedule.auto_plan` caps its own
+    (:func:`~repro.core.schedule.cap_tile`: the last two axes whole, at
+    most ``TILE_POINTS`` points)."""
     grid = [int(g) for g in grid]
-    per_axis = []
-    for ax in range(ndim - 1):
-        opts = {grid[ax]}
-        for c in (8, 32):
-            if c < grid[ax]:
-                opts.add(c)
-        per_axis.append(sorted(opts))
-    lane_opts = {min(grid[-1], hw.LANE)}
-    if grid[-1] > hw.LANE:
-        lane_opts.add(min(grid[-1], 2 * hw.LANE))
-    per_axis.append(sorted(lane_opts))
-    return [tuple(b) for b in itertools.product(*per_axis)]
+    n_tiled = max(0, p.ndim - 2)
+    per_axis = [sorted({grid[ax]} | {c for c in (8, 32) if c < grid[ax]})
+                for ax in range(n_tiled)]
+    return list(dict.fromkeys(cap_tile(b, grid)
+                              for b in itertools.product(*per_axis)))
 
 
 def _behaviour_key(plan: DataflowPlan, carry_write: str, backend: str,
@@ -339,8 +336,8 @@ def _behaviour_key(plan: DataflowPlan, carry_write: str, backend: str,
             plan.dtype, cw)
 
 
-def _candidates(p: Program, grid, backend: str, interpret: bool,
-                dtype: str, cfg: TuneConfig, with_loop: bool) -> list:
+def _candidates(p: Program, grid, backend: str, dtype: str,
+                cfg: TuneConfig, with_loop: bool) -> list:
     ndim = p.ndim
     out: list[_Candidate] = []
     seen: set = set()
@@ -356,15 +353,14 @@ def _candidates(p: Program, grid, backend: str, interpret: bool,
     steps = cfg.steps if with_loop else None
     # the heuristic seed is always candidate 0: the tuned plan can only keep
     # or beat it on the tuner's own measurements
-    base = auto_plan(p, grid, backend=backend, interpret=interpret,
-                     dtype=dtype, vmem_budget=cfg.vmem_budget, steps=steps)
+    base = auto_plan(p, grid, backend=backend, dtype=dtype,
+                     vmem_budget=cfg.vmem_budget, steps=steps)
     add(base, "repad", "auto_plan")
     for cw in carry_writes:
         add(base, cw, f"auto_plan/cw={cw}")
     blocks = _block_candidates(p, grid)
     for strat, dt in itertools.product(cfg.strategies, cfg.dtypes or (dtype,)):
-        plan0 = auto_plan(p, grid, backend=backend, interpret=interpret,
-                          dtype=dt, strategy=strat,
+        plan0 = auto_plan(p, grid, backend=backend, dtype=dt, strategy=strat,
                           vmem_budget=cfg.vmem_budget, steps=steps)
         for blk, cw in itertools.product(blocks, carry_writes):
             plan = dataclasses.replace(plan0, block=tuple(blk),
@@ -380,8 +376,7 @@ def _candidates(p: Program, grid, backend: str, interpret: bool,
             tiles = tuple(cfg.time_tiles) if with_loop else (1,)
             ptiles = tuple(cfg.plane_tiles) or (1,)
             for tt, pt in itertools.product(tiles, ptiles):
-                plan_s = auto_plan(p, grid, backend=backend,
-                                   interpret=interpret, dtype=dt,
+                plan_s = auto_plan(p, grid, backend=backend, dtype=dt,
                                    strategy=strat,
                                    vmem_budget=cfg.vmem_budget, steps=steps,
                                    schedule="stream", time_tile=int(tt),
@@ -463,7 +458,7 @@ def _measure(p, grid, cand: _Candidate, data, update, cfg: TuneConfig,
 # --------------------------------------------------------------------------
 
 def tune_plan(p: Program, grid, *, backend: str = "pallas",
-              interpret: bool = True, dtype: str = "float32",
+              dtype: str = "float32",
               update=None, config: TuneConfig | None = None,
               cache: PlanCache | None = None,
               mesh=None, mesh_axes=None) -> TuneResult:
@@ -508,7 +503,7 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
     # local block (with exact neighbour ghost planes when the stream axis
     # itself is sharded), so ``plan_grid`` prices VMEM and the roofline
     # per shard and the measurement runs the real shard_map executable
-    cands = _candidates(p, plan_grid, backend, interpret, dtype, cfg,
+    cands = _candidates(p, plan_grid, backend, dtype, cfg,
                         with_loop)
     baseline, rest = cands[0], cands[1:]
 
@@ -546,7 +541,7 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
                    key=lambda i: (survivors[i].score(), i))
     winner = survivors[order[0]]
 
-    key = cache_key(p, grid, backend, interpret, dtype,
+    key = cache_key(p, grid, backend, dtype,
                     "loop" if with_loop else "single",
                     mesh=mesh, mesh_axes=mesh_axes)
     record = {
@@ -594,7 +589,7 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
 
 
 def get_tuned_plan(p: Program, grid, *, backend: str = "pallas",
-                   interpret: bool = True, dtype: str = "float32",
+                   dtype: str = "float32",
                    update=None, config: TuneConfig | None = None,
                    cache: PlanCache | None = None,
                    mesh=None, mesh_axes=None) -> TuneResult:
@@ -611,8 +606,8 @@ def get_tuned_plan(p: Program, grid, *, backend: str = "pallas",
         if mesh_axes is None:
             mesh_axes = tuple(mesh.axis_names)
         mesh_axes = normalize_mesh_axes(mesh_axes, p.ndim)
-    key = cache_key(p, tuple(int(g) for g in grid), backend, interpret,
-                    dtype, "loop" if update is not None else "single",
+    key = cache_key(p, tuple(int(g) for g in grid), backend, dtype,
+                    "loop" if update is not None else "single",
                     mesh=mesh, mesh_axes=mesh_axes)
     rec = None if (config is not None and config.force_retune) \
         else cache.lookup(key)
@@ -625,8 +620,8 @@ def get_tuned_plan(p: Program, grid, *, backend: str = "pallas",
                           key=key, record=rec, cache_hit=True)
     if tracer.enabled:
         tracer.emit(CacheMiss(cache="tuned_plan", key=key))
-    return tune_plan(p, grid, backend=backend, interpret=interpret,
-                     dtype=dtype, update=update, config=config, cache=cache,
+    return tune_plan(p, grid, backend=backend, dtype=dtype, update=update,
+                     config=config, cache=cache,
                      mesh=mesh, mesh_axes=mesh_axes)
 
 

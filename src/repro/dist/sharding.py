@@ -27,11 +27,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def make_auto_mesh(shape, axes, **kwargs):
-    """``jax.make_mesh`` with Auto axis types where the API supports them
-    (jax >= 0.5); older releases treat every axis as auto implicitly."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs.setdefault("axis_types", (axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
     return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
 
 

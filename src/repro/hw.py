@@ -1,14 +1,16 @@
 """Target-hardware constants (TPU v5e) used by planners and roofline analysis.
 
-The container executes on CPU; these numbers describe the *target* the plans,
-kernels and rooflines are derived for.  Sources: public TPU v5e datasheet
-figures as given in the task brief (197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI).
+These numbers describe the *target* the plans, kernels and rooflines are
+derived for, whatever backend the process runs on.  Sources: public TPU v5e
+datasheet figures (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI).
 """
 
 from __future__ import annotations
 
 import dataclasses
+
+import jax
+from jax.experimental.pallas import tpu as pltpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,16 +42,37 @@ TPU_V5E = ChipSpec(
     smem_bytes=1024**2,
 )
 
-# Budget the stencil planner may claim for windows+outputs inside one kernel
-# instance (leave headroom for Mosaic spills and double buffering: the Pallas
-# pipeline keeps 2 copies of every block in flight).
-VMEM_PLAN_BUDGET = TPU_V5E.vmem_bytes // 4
+# Scoped-VMEM limit every generated Pallas kernel is compiled with (Mosaic's
+# own default is 16 MiB, below what the planner's plans claim).
+VMEM_LIMIT_BYTES = TPU_V5E.vmem_bytes // 2
+# Budget the stencil planner prices a kernel instance against (windows,
+# margin-extended temps and outputs, already doubled for the Pallas
+# pipeline's two copies of every block): half the compiler's limit, leaving
+# the other half for the values Mosaic materialises while evaluating the
+# kernel body (concatenated windows, masks, spills).
+VMEM_PLAN_BUDGET = VMEM_LIMIT_BYTES // 2
 
 LANE = TPU_V5E.lanes
 SUBLANE = TPU_V5E.sublanes
 
 # field storage dtypes the planner/cost models understand
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float64": 8}
+
+
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run in the interpreter: compiled Mosaic on a
+    TPU backend, the interpreter anywhere else (the CPU test suite)."""
+    return jax.default_backend() != "tpu"
+
+
+def pallas_compiler_params(dimension_semantics) -> object:
+    """Mosaic compiler parameters of a generated kernel: the scoped-VMEM
+    limit the planner's budget is derived from, and the semantics of each
+    grid axis (``"parallel"`` for independent tiles, ``"arbitrary"`` for a
+    sweep whose scratch carries state from one grid step to the next)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def align_up(x: int, m: int) -> int:

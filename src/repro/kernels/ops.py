@@ -19,20 +19,16 @@ from .swa import swa_pallas
 
 
 def stencil_apply(program, grid, fields, scalars=None, coeffs=None,
-                  *, interpret: bool = True, strategy: str = "auto"):
-    ex = compile_program(program, grid, backend="pallas",
-                         interpret=interpret, strategy=strategy)
+                  *, strategy: str = "auto"):
+    ex = compile_program(program, grid, backend="pallas", strategy=strategy)
     return ex(fields, scalars or {}, coeffs or {})
 
 
-@functools.partial(jax.jit, static_argnames=("window", "q_block",
-                                             "interpret"))
-def sliding_window_attention(q, k, v, *, window: int, q_block: int = 128,
-                             interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("window", "q_block"))
+def sliding_window_attention(q, k, v, *, window: int, q_block: int = 128):
     """q: (B,S,H,D); k, v: (B,S,KV,D) — KV heads repeated here for GQA."""
     H, KV = q.shape[2], k.shape[2]
     if KV != H:
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)
-    return swa_pallas(q, k, v, window=window, q_block=q_block,
-                      interpret=interpret)
+    return swa_pallas(q, k, v, window=window, q_block=q_block)

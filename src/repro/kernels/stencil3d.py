@@ -25,8 +25,8 @@ domain (the kernel receives the shard origin at runtime), so fused overlapped
 tiling is bit-compatible with streamed per-field execution on any shard of a
 distributed run.
 
-Works identically under ``interpret=True`` (CPU validation) and compiled
-Mosaic (TPU target).
+Runs compiled through Mosaic on a TPU and in the Pallas interpreter
+elsewhere (:func:`repro.hw.pallas_interpret` decides).
 """
 
 from __future__ import annotations
@@ -40,24 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:  # Element block dims: public in newer JAX, core in 0.8.x
-    from jax.experimental.pallas import Element  # type: ignore
-
-    def _window_spec(shape, index_map):
-        return pl.BlockSpec(tuple(Element(s) for s in shape), index_map)
-except ImportError:  # pragma: no cover
-    try:
-        from jax._src.pallas.core import Element  # type: ignore
-
-        def _window_spec(shape, index_map):
-            return pl.BlockSpec(tuple(Element(s) for s in shape), index_map)
-    except ImportError:
-        # jax 0.4.x: Unblocked indexing takes element offsets directly,
-        # which is exactly what the overlapping-window maps emit.
-        def _window_spec(shape, index_map):
-            return pl.BlockSpec(tuple(shape), index_map,
-                                indexing_mode=pl.Unblocked())
-
+from .. import hw
 from ..core.expr_eval import evaluate
 from ..core.ir import Access, Program
 from ..core.passes import GroupHalo, infer_halo
@@ -67,9 +50,13 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _window_spec(shape, index_map):
+    """Overlapping VMEM window: the index map yields element offsets."""
+    return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
+
+
 def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                      grid_shape: Sequence[int], dtype=jnp.float32,
-                     interpret: bool = True,
                      global_extent: Sequence[int] | None = None):
     """Build a callable(padded_inputs, scalars, coeffs, origin) -> outputs.
 
@@ -101,6 +88,12 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     scalar_index = {s: i for i, s in enumerate(p.scalars)}
     out_names = [op.out for op in ops if op.out in set(gh.group_outputs)]
     coeff_axis = {c: p.coeffs[c] for c in gh.group_coeffs}
+    # a coefficient vector lies along its own axis (extent 1 on the others),
+    # resident whole: the kernel slices it without a relayout, and a tile's
+    # slice along a tiled (leading, untiled-layout) axis may start anywhere
+    coeff_shape = {c: tuple(halo_lo[a] + padded_out[a] + halo_hi[a]
+                            if a == ax else 1 for a in range(ndim))
+                   for c, ax in coeff_axis.items()}
     # which ops need the zero-halo mask on margin-extended recompute: a
     # periodic op's wraparound windows make the recomputed values exact at
     # every position, so masking them to zero would be wrong; a zero-BC
@@ -111,8 +104,8 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
 
     def kernel(*refs):
         i = 0
-        s_ref = refs[i]; i += 1                      # scalars (SMEM, f32)
-        org_ref = refs[i]; i += 1                    # shard origin (SMEM, i32)
+        s_ref = refs[i]; i += 1                      # (1, n) scalars, SMEM
+        org_ref = refs[i]; i += 1                    # (1, ndim) origin, SMEM
         in_refs = {f: refs[i + k] for k, f in enumerate(gh.group_inputs)}
         i += len(gh.group_inputs)
         coeff_refs = {c: refs[i + k] for k, c in enumerate(gh.group_coeffs)}
@@ -121,12 +114,11 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
 
         # single load_data stage: every window loads exactly once
         windows = {f: r[...] for f, r in in_refs.items()}
-        coeff_windows = {c: r[...] for c, r in coeff_refs.items()}
         results: dict = {}
         memo: dict = {}
 
         def scalar(name: str):
-            return s_ref[scalar_index[name]]
+            return s_ref[0, scalar_index[name]]
 
         for op in ops:
             m = margins[op.out]
@@ -135,10 +127,11 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 ax = coeff_axis[c.coeff]
                 start = int(gh.input_halo[ax, 0] - m[ax, 0] + c.offset)
                 size = block[ax] + int(m[ax, 0]) + int(m[ax, 1])
-                v = coeff_windows[c.coeff][start:start + size]
-                shape = [1] * ndim
-                shape[ax] = size
-                return v.reshape(shape)
+                if tiles[ax] > 1:       # this tile's slice of the vector
+                    start = pl.program_id(ax) * block[ax] + start
+                return coeff_refs[c.coeff][tuple(
+                    pl.ds(start, size) if a == ax else slice(None)
+                    for a in range(ndim))]
 
             def access(a: Access, m=m):
                 sl = []
@@ -170,7 +163,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 # domain must read as 0 to downstream consumers.
                 mask = None
                 for ax in range(ndim):
-                    g0 = (org_ref[ax] + pl.program_id(ax) * block[ax]
+                    g0 = (org_ref[0, ax] + pl.program_id(ax) * block[ax]
                           - int(m[ax, 0]))
                     coord = g0 + jax.lax.broadcasted_iota(jnp.int32, ext, ax)
                     ok = (coord >= 0) & (coord < global_extent[ax])
@@ -191,10 +184,8 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         in_specs.append(_window_spec(
             tuple(win[a] for a in range(ndim)), window_map))
     for c in gh.group_coeffs:
-        ax = coeff_axis[c]
-        in_specs.append(_window_spec(
-            (win[ax],),
-            (lambda *idx, ax=ax: (idx[ax] * block[ax],))))
+        in_specs.append(pl.BlockSpec(coeff_shape[c],
+                                     lambda *idx: (0,) * ndim))
     out_specs = tuple(pl.BlockSpec(block, lambda *idx: tuple(idx))
                       for _ in out_names)
     out_shape = tuple(jax.ShapeDtypeStruct(padded_out, dtype) for _ in out_names)
@@ -205,7 +196,8 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         in_specs=in_specs,
         out_specs=out_specs if len(out_names) > 1 else out_specs[0],
         out_shape=out_shape if len(out_names) > 1 else out_shape[0],
-        interpret=interpret,
+        compiler_params=hw.pallas_compiler_params(("parallel",) * ndim),
+        interpret=hw.pallas_interpret(),
     )
 
     crop = tuple(slice(0, grid_shape[a]) for a in range(ndim))
@@ -225,7 +217,10 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 else jnp.zeros((max(n_scalars, 1),), jnp.float32))
         org = (origin if origin is not None
                else jnp.zeros((ndim,), jnp.int32))
-        args = [svec, org]
+        # scalars and origin are (1, n) rows, coefficients span only their
+        # own axis: vmap then prepends the batch axis ahead of the last two,
+        # where Mosaic's block rule allows it
+        args = [svec.reshape(1, -1), org.reshape(1, -1)]
         for f in gh.group_inputs:
             x = padded_inputs[f]
             if input_pad is not None and f in input_pad:
@@ -236,7 +231,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 x = x[sl]
             args.append(x)
         for c in gh.group_coeffs:
-            args.append(padded_coeffs[c])
+            args.append(padded_coeffs[c].reshape(coeff_shape[c]))
         res = call(*args)
         if len(out_names) == 1:
             res = (res,)

@@ -12,8 +12,8 @@ Grid: (batch, heads, q_tiles).  Block layout keeps the head dim on lanes
 is computed tile-locally (the whole window is in VMEM — no running-max
 pass needed, unlike global flash attention).
 
-Validated in interpret mode against ``ref.swa_reference``; on TPU the same
-code lowers through Mosaic.
+Validated in the Pallas interpreter against ``ref.swa_reference``; on TPU
+the same code lowers through Mosaic (:func:`repro.hw.pallas_interpret`).
 """
 
 from __future__ import annotations
@@ -25,27 +25,14 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import Element  # type: ignore
-
-    def _kv_spec(slab, D, index_map):
-        return pl.BlockSpec((1, 1, Element(slab), Element(D)), index_map)
-except ImportError:  # pragma: no cover
-    try:
-        from jax._src.pallas.core import Element  # type: ignore
-
-        def _kv_spec(slab, D, index_map):
-            return pl.BlockSpec((1, 1, Element(slab), Element(D)), index_map)
-    except ImportError:
-        # jax 0.4.x: fully element-indexed spec; the leading dims have block
-        # size 1, so their element offsets coincide with block indices.
-        def _kv_spec(slab, D, index_map):
-            return pl.BlockSpec((1, 1, slab, D), index_map,
-                                indexing_mode=pl.Unblocked())
+from .. import hw
 
 
-def swa_pallas(q, k, v, *, window: int, q_block: int = 128,
-               interpret: bool = True):
+def _kv_spec(slab, D, index_map):
+    return pl.BlockSpec((1, 1, pl.Element(slab), pl.Element(D)), index_map)
+
+
+def swa_pallas(q, k, v, *, window: int, q_block: int = 128):
     """q, k, v: (B, S, H, D) with H already GQA-repeated.  Causal SWA.
 
     Returns (B, S, H, D).  ``window`` counts the current position, i.e.
@@ -99,6 +86,6 @@ def swa_pallas(q, k, v, *, window: int, q_block: int = 128,
         ],
         out_specs=pl.BlockSpec((1, 1, Bq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=interpret,
+        interpret=hw.pallas_interpret(),
     )(qt, kp, vp)
     return out.transpose(0, 2, 1, 3)

@@ -83,8 +83,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per module
-        cost = cost[0]
     hlo = compiled.as_text()
     af = analytic_flops(cfg, shape)
     tp = mesh.shape["model"] if rules.tp else 1

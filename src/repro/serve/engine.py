@@ -90,6 +90,11 @@ class ServeResult:
     batch_size: int               # real requests in the executed batch
 
 
+#: how JAX's ``NotImplementedError`` for a primitive that ``vmap`` cannot
+#: batch begins (``jax._src.interpreters.batching``)
+_NO_BATCHING_RULE = "Batching rule for "
+
+
 @dataclasses.dataclass
 class _Item:
     req: StencilRequest
@@ -166,13 +171,13 @@ class StencilEngine:
     #: ``options=`` object seeds these, loose kwargs override (a loose
     #: kwarg moved off its engine default that *disagrees* with the
     #: options value is an error, mirroring ``compile_program``).
-    _OPTION_KNOBS = (("backend", "jnp_fused"), ("interpret", True),
-                     ("schedule", None), ("strategy", "auto"),
+    _OPTION_KNOBS = (("backend", "jnp_fused"), ("schedule", None),
+                     ("strategy", "auto"),
                      ("dtype", "float32"), ("mesh", None),
                      ("mesh_axes", None), ("time_tile", None),
                      ("plane_tile", None))
 
-    def __init__(self, *, backend: str = "jnp_fused", interpret: bool = True,
+    def __init__(self, *, backend: str = "jnp_fused",
                  schedule: str | None = None, strategy: str = "auto",
                  dtype: str = "float32", mesh=None,
                  mesh_axes: tuple | None = None, time_tile: int | None = None,
@@ -182,7 +187,7 @@ class StencilEngine:
                  max_executors: int | None = None,
                  plan_cache: PlanCache | None = None, lane: int = hw.LANE,
                  autostart: bool = True, tracer=None):
-        loose = dict(backend=backend, interpret=interpret, schedule=schedule,
+        loose = dict(backend=backend, schedule=schedule,
                      strategy=strategy, dtype=dtype, mesh=mesh,
                      mesh_axes=mesh_axes, time_tile=time_tile,
                      plane_tile=plane_tile)
@@ -301,8 +306,8 @@ class StencilEngine:
                     f"{req.update.__module__}.{req.update.__qualname__}")
         key = "|".join([
             bucket_fingerprint(sp, spec.bucket, backend=self.backend,
-                               dtype=self.dtype, interpret=self.interpret,
-                               schedule=self.schedule, steps=req.steps,
+                               dtype=self.dtype, schedule=self.schedule,
+                               steps=req.steps,
                                mesh=self.mesh, mesh_axes=self.mesh_axes,
                                plane_tile=self.plane_tile),
             f"update={ukey}",
@@ -330,6 +335,23 @@ class StencilEngine:
     def map(self, reqs, timeout: float | None = None) -> list:
         futs = [self.submit(r) for r in reqs]
         return [f.result(timeout) for f in futs]
+
+    def batch_hlo(self, reqs) -> str:
+        """StableHLO text of the program the engine dispatches (batched or
+        unrolled) to serve ``reqs`` as one batch — e.g. to check that a
+        served bucket runs the generated kernels.  The requests share one
+        executor, which an earlier batch of theirs must have built."""
+        items = [self.describe(r) + (r,) for r in reqs]
+        keys = {key for _, _, key, _ in items}
+        if len(keys) != 1:
+            raise ValueError(f"requests span {len(keys)} executors, not one")
+        ex = self._executors.get(keys.pop())
+        if ex is None:
+            raise ValueError("no executor for these requests: serve them "
+                             "first")
+        args, _ = self._stack_batch(
+            ex, [(spec, req) for _, spec, _, req in items])
+        return ex.batched.lower(*args).as_text()
 
     # ------------------------------------------------------------------
     # worker: micro-batching loop
@@ -434,7 +456,7 @@ class StencilEngine:
             ex = compile_program(
                 sp, spec.bucket, options=CompileOptions(
                     backend=self.backend, plan=plan, jit=False,
-                    interpret=self.interpret, dtype=self.dtype,
+                    dtype=self.dtype,
                     strategy=self.strategy, steps=req.steps, update=update,
                     carry_write=carry_write, schedule=self.schedule,
                     mesh=self.mesh, mesh_axes=self.mesh_axes,
@@ -466,40 +488,55 @@ class StencilEngine:
                               n=len(items)) as sp:
             self._run_batch_traced(ex, items, sp)
 
+    def _stack_batch(self, ex: _BucketExecutor, pairs: list):
+        """Embed each ``(BucketSpec, request)`` in its bucket and stack them
+        into the executor's ``(fields, scalars, coeffs)`` arguments, padded
+        to a power-of-two batch; also returns that padded size."""
+        embedded = [embed_request(ex.program, spec, req.fields, req.scalars,
+                                  req.coeffs) for spec, req in pairs]
+        n = len(pairs)
+        pad = _pow2_at_least(n)
+
+        def stack(leaves, cast):
+            arr = np.stack(leaves)
+            if cast and arr.dtype != self._np_dtype:
+                arr = arr.astype(self._np_dtype)
+            if pad > n:  # replicate slot 0 into the filler slots
+                arr = np.concatenate(
+                    [arr, np.repeat(arr[:1], pad - n, axis=0)])
+            return arr
+
+        fields = {f: stack([e[0][f] for e in embedded], True)
+                  for f in embedded[0][0]}
+        scalars = {s: stack(np.asarray([e[1][s] for e in embedded],
+                                       dtype=np.float32), False)
+                   for s in embedded[0][1]}
+        coeffs = {c: stack([e[2][c] for e in embedded], True)
+                  for c in embedded[0][2]}
+        return (fields, scalars, coeffs), pad
+
     def _run_batch_traced(self, ex: _BucketExecutor, items: list, sp) -> None:
         t0 = time.monotonic()
         try:
-            embedded = [embed_request(ex.program, it.spec, it.req.fields,
-                                      it.req.scalars, it.req.coeffs)
-                        for it in items]
             n = len(items)
-            pad = _pow2_at_least(n)
-
-            def stack(leaves, cast):
-                arr = np.stack(leaves)
-                if cast and arr.dtype != self._np_dtype:
-                    arr = arr.astype(self._np_dtype)
-                if pad > n:  # replicate slot 0 into the filler slots
-                    arr = np.concatenate(
-                        [arr, np.repeat(arr[:1], pad - n, axis=0)])
-                return arr
-
-            fields = {f: stack([e[0][f] for e in embedded], True)
-                      for f in embedded[0][0]}
-            scalars = {s: stack(np.asarray([e[1][s] for e in embedded],
-                                           dtype=np.float32), False)
-                       for s in embedded[0][1]}
-            coeffs = {c: stack([e[2][c] for e in embedded], True)
-                      for c in embedded[0][2]}
+            (fields, scalars, coeffs), pad = self._stack_batch(
+                ex, [(it.spec, it.req) for it in items])
             try:
                 out = ex.batched(fields, scalars, coeffs)
-            except Exception:
-                if ex.vmap_failed:
+            except NotImplementedError as e:
+                # only JAX's error for a primitive with no batching rule
+                # unrolls; any other failure (a kernel the compiler
+                # refuses) fails the batch instead of quietly losing
+                # batching
+                if ex.vmap_failed or not str(e).startswith(
+                        _NO_BATCHING_RULE):
                     raise
                 ex.fallback_unrolled()
                 out = ex.batched(fields, scalars, coeffs)
             out = {k: np.asarray(v) for k, v in out.items()}
             self.stats.batches += 1
+            if ex.vmap_failed:
+                self.stats.unrolled_batches += 1
             self.stats.batched_requests += n
             self.stats.padded_slots += pad - n
             sp.set(padded=pad - n, vmap_failed=ex.vmap_failed)

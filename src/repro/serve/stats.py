@@ -29,6 +29,9 @@ _COUNTERS = (
     "compiles",          # executor builds that ran compile_program
     "batches", "batched_requests",
     "padded_slots",      # replicated filler slots across all batches
+    # batches run request by request: the executor's lowering has no
+    # batching rule, so vmap could not batch it
+    "unrolled_batches",
 )
 
 _GAUGES = ("wall_s",)    # time spent inside batch execution

@@ -88,6 +88,24 @@ def test_shape_sweep_odd_grids(grid):
     check_parity(b.build(), grid)
 
 
+def test_coeff_along_a_tiled_leading_axis():
+    """The block kernel slices each tile's part of a coefficient along a
+    leading axis at a start that moves with the grid step, margins for a
+    fused temp included; 40 planes in tiles of 32 also pad the last tile."""
+    from repro.core.frontend import ProgramBuilder
+    b = ProgramBuilder("coeff_tiled", ndim=3)
+    x = b.input("x")
+    c = b.coeff("c", axis=0)
+    t = b.temp("t")
+    o = b.output("o")
+    b.define(t, c[1] * x[-1, 0, 0] - c[-1] * x[1, 0, 1])
+    b.define(o, c[0] * t[-1, 0, 0] + t[1, 1, 0])
+    p = b.build()
+    grid = (40, 4, 16)
+    assert auto_plan(p, grid, strategy="fused").block[0] < grid[0]
+    check_parity(p, grid, strategy="fused")
+
+
 def test_bfloat16_dtype():
     p = pw_advection()
     grid = (8, 8, 128)

@@ -154,9 +154,12 @@ def test_vmem_cost_accounts_for_fused_loop_carry():
     """Regression: a plan can fit the budget single-step yet claim more
     VMEM under steps=N, where windows are sliced from the align_hi-padded
     carry; the steps-aware cost must be >= the single-step cost."""
+    import dataclasses
+
     p = pw_advection()
-    grid = (8, 8, 130)      # 130 -> 2x128 lane tiles: align_hi = 126
-    plan = auto_plan(p, grid, backend="pallas")
+    grid = (9, 8, 128)      # 9 -> 2 tiles of 8 on axis 0: align_hi = 7
+    plan = dataclasses.replace(auto_plan(p, grid, backend="pallas"),
+                               block=(8, 8, 128))
     single = vmem_cost(p, plan, grid)
     looped = vmem_cost(p, plan, grid, steps=3)
     assert looped > single

@@ -159,9 +159,12 @@ def test_partial_update_keeps_untouched_fields():
 # ------------------------------------------------------------ plan layer
 
 def test_time_loop_spec_geometry():
+    import dataclasses
+
     p = pw_advection()
-    grid = (8, 8, 130)
-    plan = auto_plan(p, grid, backend="pallas")
+    grid = (9, 8, 128)
+    plan = dataclasses.replace(auto_plan(p, grid, backend="pallas"),
+                               block=(8, 8, 128))
     spec = plan_time_loop(p, plan, grid, 7)
     assert spec.steps == 7
     assert spec.persistent == ["u", "v", "w"]
@@ -172,8 +175,8 @@ def test_time_loop_spec_geometry():
         pad = spec.field_pad[f]
         assert pad.shape == (3, 2)
         assert (pad >= 0).all()
-        # lane axis alignment: 130 -> 2x128 tiles pads 126 on the hi side
-        assert pad[2, 1] >= 126
+        # tile alignment: 9 -> 2x8 tiles on axis 0 pads 7 on the hi side
+        assert pad[0, 1] >= 7
     # offsets place every group window inside the carry
     for offs in spec.group_offsets:
         for f, o in offs.items():

@@ -180,7 +180,7 @@ def test_tuner_enumerates_plane_tiles(with_loop):
     unlike the temporal chain, a wider sweep step needs no update rule."""
     from repro.core.tune import _candidates
     cfg = TuneConfig(steps=4, timer=lambda fn: 1.0)
-    cands = _candidates(pw_advection(), (8, 8, 32), "pallas", True,
+    cands = _candidates(pw_advection(), (8, 8, 32), "pallas",
                         "float32", cfg, with_loop=with_loop)
     eff = {c.plan.stream.plane_tile for c in cands
            if c.plan.schedule == "stream" and c.plan.stream is not None}
@@ -198,7 +198,7 @@ def test_tuned_plane_tile_round_trips_through_plan_cache(tmp_path):
     assert plan.stream.plane_tile == 4
     path = str(tmp_path / "plan_cache.json")
     PlanCache(path=path).store(
-        cache_key(p, grid, "pallas", True, "float32", "loop"),
+        cache_key(p, grid, "pallas", "float32", "loop"),
         {"plan": plan_to_dict(plan), "carry_write": "repad"})
 
     def no_timer(fn):                        # a timed run would be a bug
@@ -227,7 +227,7 @@ def test_stale_v3_cache_is_clean_miss_and_rewritten(tmp_path):
     path = str(tmp_path / "plans.json")
     p = pw_advection()
     grid = (8, 8, 32)
-    key = cache_key(p, grid, "pallas", True, "float32", "loop")
+    key = cache_key(p, grid, "pallas", "float32", "loop")
     plan_doc = plan_to_dict(auto_plan(p, grid, schedule="stream"))
     plan_doc.pop("plane_tile", None)          # a genuine v3 record
     with open(path, "w") as f:

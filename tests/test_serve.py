@@ -228,6 +228,36 @@ def test_same_bucket_requests_batch_together():
         eng.close()
 
 
+@pytest.mark.parametrize("error,unrolls", [
+    (NotImplementedError("Batching rule for 'foo' not implemented"), True),
+    (NotImplementedError("Unimplemented primitive in Pallas TPU lowering"),
+     False),
+    (RuntimeError("scoped vmem limit exceeded"), False)])
+def test_only_a_missing_batching_rule_unrolls(error, unrolls):
+    """The unrolled per-request dispatch stands in for vmap only when JAX
+    has no batching rule; a compile error fails the request instead of
+    quietly losing batching."""
+    p = pw_advection()
+    req = make_request(p, (8, 8, 16))
+    with StencilEngine(window_s=0.0) as eng:
+        first = eng.run(req, timeout=300)
+        ex = eng._executors[first.key]
+
+        def refuse(*args):
+            raise error
+        ex.batched = refuse
+        if unrolls:
+            res = eng.run(req, timeout=300)
+            for k in first.outputs:
+                np.testing.assert_allclose(res.outputs[k], first.outputs[k],
+                                           atol=1e-6, rtol=1e-6)
+        else:
+            with pytest.raises(type(error)):
+                eng.run(req, timeout=300)
+        assert ex.vmap_failed is unrolls
+        assert eng.stats.unrolled_batches == int(unrolls)
+
+
 def test_mixed_shape_traffic_end_to_end():
     p = pw_advection(boundary="periodic")
     grids = [(8, 8, 16), (6, 7, 14), (8, 8, 24), (5, 8, 16), (8, 8, 16)]
@@ -367,9 +397,9 @@ def test_engine_accepts_compile_options():
     # options seeds every knob the caller left at its engine default
     eng = StencilEngine(options=CompileOptions(schedule="block",
                                                dtype="float32",
-                                               interpret=False),
+                                               strategy="per_field"),
                         autostart=False)
-    assert eng.schedule == "block" and eng.interpret is False
+    assert eng.schedule == "block" and eng.strategy == "per_field"
     # a knob moved off its engine default that disagrees is an error
     with pytest.raises(ValueError, match="dtype"):
         StencilEngine(dtype="bfloat16",

@@ -147,7 +147,7 @@ def test_tuned_strategy_serves_stream_plan_from_cache():
     fields, scalars, coeffs = data_fn(grid)
     plan = auto_plan(p, grid, schedule="stream")
     cache = PlanCache(path=None)
-    key = cache_key(p, grid, "pallas", True, "float32", "loop")
+    key = cache_key(p, grid, "pallas", "float32", "loop")
     cache.store(key, {"plan": plan_to_dict(plan), "carry_write": "repad"})
 
     def no_timer(fn):                        # a timed run would be a bug

@@ -168,13 +168,13 @@ def test_vmem_cost_prices_chain_depth():
 def test_tuner_enumerates_chained_stream_candidates():
     from repro.core.tune import _candidates
     cfg = TuneConfig(steps=4, timer=lambda fn: 1.0)
-    cands = _candidates(pw_advection(), (8, 8, 32), "pallas", True,
+    cands = _candidates(pw_advection(), (8, 8, 32), "pallas",
                         "float32", cfg, with_loop=True)
     eff = {c.plan.stream.time_tile for c in cands
            if c.plan.schedule == "stream" and c.plan.stream is not None}
     assert {1, 2, 4} <= eff
     # single-step sweeps never chain: the T variants dedup away
-    cands1 = _candidates(pw_advection(), (8, 8, 32), "pallas", True,
+    cands1 = _candidates(pw_advection(), (8, 8, 32), "pallas",
                          "float32", cfg, with_loop=False)
     assert all(c.plan.stream.time_tile == 1 for c in cands1
                if c.plan.schedule == "stream" and c.plan.stream is not None)
@@ -191,7 +191,7 @@ def test_tuned_time_tile_round_trips_through_plan_cache(tmp_path):
     assert plan.stream.time_tile == 4
     path = str(tmp_path / "plan_cache.json")
     PlanCache(path=path).store(
-        cache_key(p, grid, "pallas", True, "float32", "loop"),
+        cache_key(p, grid, "pallas", "float32", "loop"),
         {"plan": plan_to_dict(plan), "carry_write": "repad"})
 
     def no_timer(fn):                        # a timed run would be a bug

@@ -12,6 +12,7 @@ Invariants:
 
 import dataclasses
 import json
+import math
 
 import pytest
 
@@ -20,8 +21,8 @@ from repro.core import (PlanCache, TuneConfig, compile_program,
                         get_tuned_plan, plan_from_dict, plan_to_dict,
                         program_fingerprint, tune_plan)
 from repro.core.frontend import ProgramBuilder
-from repro.core.schedule import auto_plan
-from repro.core.tune import CACHE_SCHEMA_VERSION, cache_key
+from repro.core.schedule import TILE_POINTS, auto_plan
+from repro.core.tune import CACHE_SCHEMA_VERSION, _block_candidates, cache_key
 
 GRID = (8, 8, 16)
 
@@ -173,22 +174,27 @@ def test_cache_invalidated_by_grid_change(tmp_path):
     res = get_tuned_plan(p, (16, 8, 16), backend="jnp_fused",
                          update=small_update, config=cfg, cache=cache)
     assert not res.cache_hit and calls["n"] > n_first
-    assert cache_key(p, GRID, "jnp_fused", True) != \
-        cache_key(p, (16, 8, 16), "jnp_fused", True)
+    assert cache_key(p, GRID, "jnp_fused") != \
+        cache_key(p, (16, 8, 16), "jnp_fused")
 
 
-def test_cache_keyed_by_backend_dtype_and_mode():
+def test_cache_keyed_by_backend_dtype_and_mode(monkeypatch):
+    from repro import hw
+
     p = small_program()
-    assert cache_key(p, GRID, "pallas", True) != \
-        cache_key(p, GRID, "jnp_fused", True)
-    assert cache_key(p, GRID, "pallas", True) != \
-        cache_key(p, GRID, "pallas", False)
+    assert cache_key(p, GRID, "pallas") != \
+        cache_key(p, GRID, "jnp_fused")
+    # a winner timed in the interpreter must not serve compiled kernels
+    interpreted = cache_key(p, GRID, "pallas")
+    monkeypatch.setattr(hw, "pallas_interpret", lambda: False)
+    assert cache_key(p, GRID, "pallas") != interpreted
+    monkeypatch.undo()
     # a float32 winner must not serve a bfloat16 compile, nor a single-step
     # winner a fused steps=N compile (different pruning + ranking)
-    assert cache_key(p, GRID, "pallas", True, "float32") != \
-        cache_key(p, GRID, "pallas", True, "bfloat16")
-    assert cache_key(p, GRID, "pallas", True, mode="loop") != \
-        cache_key(p, GRID, "pallas", True, mode="single")
+    assert cache_key(p, GRID, "pallas", "float32") != \
+        cache_key(p, GRID, "pallas", "bfloat16")
+    assert cache_key(p, GRID, "pallas", mode="loop") != \
+        cache_key(p, GRID, "pallas", mode="single")
 
 
 # ------------------------------------------- measured quality guarantee
@@ -246,6 +252,18 @@ def test_candidate_blocks_lane_quantised():
         assert last == grid[-1] or last % 128 == 0
 
 
+def test_block_candidates_capped_like_auto_plan():
+    """At the paper's 8M grid every block candidate keeps the last two axes
+    whole and holds at most TILE_POINTS points, the cap auto_plan starts
+    from, so the tuner never compiles a tile the planner would refuse."""
+    grid = (256, 256, 128)
+    blocks = _block_candidates(pw_advection(), grid)
+    assert auto_plan(pw_advection(), grid).block in blocks
+    for blk in blocks:
+        assert blk[1:] == grid[1:]
+        assert math.prod(blk) <= TILE_POINTS
+
+
 def test_force_retune_bypasses_cache(tmp_path):
     """The key encodes the problem, not the search effort; force_retune is
     the escape hatch for re-searching with different knobs."""
@@ -285,17 +303,17 @@ def test_cache_keyed_by_mesh_topology_and_boundary():
             self.shape = shape
 
     p = pw_advection()
-    k_local = cache_key(p, GRID, "pallas", True)
-    k_222 = cache_key(p, GRID, "pallas", True,
+    k_local = cache_key(p, GRID, "pallas")
+    k_222 = cache_key(p, GRID, "pallas",
                       mesh=FakeMesh({"X": 2, "Y": 2, "Z": 2}),
                       mesh_axes=("X", "Y", "Z"))
-    k_42 = cache_key(p, GRID, "pallas", True,
+    k_42 = cache_key(p, GRID, "pallas",
                      mesh=FakeMesh({"X": 4, "Y": 2}),
                      mesh_axes=("X", "Y", None))
-    k_24 = cache_key(p, GRID, "pallas", True,
+    k_24 = cache_key(p, GRID, "pallas",
                      mesh=FakeMesh({"X": 2, "Y": 4}),
                      mesh_axes=("X", "Y", None))
-    k_periodic = cache_key(p.with_boundary("periodic"), GRID, "pallas", True)
+    k_periodic = cache_key(p.with_boundary("periodic"), GRID, "pallas")
     assert len({k_local, k_222, k_42, k_24, k_periodic}) == 5
 
 
@@ -395,14 +413,14 @@ def test_tuner_enumerates_stream_and_block_schedules():
     winner's schedule round-trips through the record."""
     from repro.core.tune import _candidates
     cfg = TuneConfig(steps=2, timer=lambda fn: 1.0)
-    cands = _candidates(pw_advection(), GRID, "pallas", True, "float32",
+    cands = _candidates(pw_advection(), GRID, "pallas", "float32",
                         cfg, with_loop=True)
     schedules = {c.plan.schedule for c in cands}
     assert schedules == {"block", "stream"}
     stream_cands = [c for c in cands if c.plan.schedule == "stream"]
     assert all(c.plan.stream is not None for c in stream_cands)
     # ...and the jnp backends never see stream candidates
-    jcands = _candidates(pw_advection(), GRID, "jnp_fused", True, "float32",
+    jcands = _candidates(pw_advection(), GRID, "jnp_fused", "float32",
                          cfg, with_loop=True)
     assert {c.plan.schedule for c in jcands} == {"block"}
 
