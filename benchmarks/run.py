@@ -89,11 +89,9 @@ def run_smoke(out_path: str, mesh_shape: tuple | None = None,
     baseline (see :func:`check_smoke_baseline`)."""
     rows = []
 
-    def emit_row(name: str, us: float, derived: str = "", **extra):
+    def emit_row(name: str, us: float, derived: str = ""):
         emit(name, us, derived)
-        row = {"name": name, "us": round(us, 2), "derived": derived}
-        row.update({k: v for k, v in extra.items() if v is not None})
-        rows.append(row)
+        rows.append({"name": name, "us": round(us, 2), "derived": derived})
 
     grid, steps = (16, 16, 16), 3
     fig4_throughput.run_fused_loop(
@@ -149,21 +147,6 @@ def check_smoke_baseline(rows: list, baseline_path: str) -> None:
             failures.append(f"  {name}: {got:.2f} steps/s < {floor:.2f} "
                             f"floor (baseline {float(floor_sps):.2f} "
                             f"- {tol:.0%})")
-    # roofline-achieved floors WARN, never fail: the fraction on CI hosts
-    # is noisy (interpret mode on shared CPU vs a TPU-priced model), so a
-    # dip is a flag for a human, not a red build — ROADMAP item 3 hardens
-    # this into a gate once the trend stabilises
-    fractions = {row["name"]: row["roofline_fraction"] for row in rows
-                 if "roofline_fraction" in row}
-    for name, floor in base.get("roofline_floor", {}).items():
-        got = fractions.get(name)
-        if got is None:
-            print(f"WARNING: {name}: no roofline_fraction in artifact "
-                  f"(floor {float(floor):.2e})", flush=True)
-        elif got < float(floor):
-            print(f"WARNING: {name}: roofline_fraction {got:.2e} below "
-                  f"floor {float(floor):.2e} (achieved share of the model "
-                  "prediction dropped — not failing the build)", flush=True)
     if failures:
         raise SystemExit("smoke compute-row regression:\n"
                          + "\n".join(failures))
@@ -181,7 +164,6 @@ def run_schedule_rows(emit_row, grid: tuple, steps: int) -> None:
     import jax
     from repro.apps import pw_advection, pw_advection_update
     from repro.core import CompileOptions, compile_program
-    from repro.obs.achieved import fraction_for
 
     p = pw_advection()
     update = pw_advection_update(0.1)
@@ -189,9 +171,7 @@ def run_schedule_rows(emit_row, grid: tuple, steps: int) -> None:
     fields, scalars, coeffs = fig4_throughput._data(p, grid)
 
     def measure(opts, nsteps):
-        """Best-of-3 seconds per call plus the roofline-achieved fraction
-        (measured vs model_plan prediction — tiny under CPU interpret, the
-        per-commit *trend* is what the baseline floor watches)."""
+        """Best-of-3 seconds per call."""
         exN = compile_program(p, grid, options=opts)
         jax.block_until_ready(exN(fields, scalars, coeffs)["u"])
         dt = float("inf")
@@ -200,17 +180,16 @@ def run_schedule_rows(emit_row, grid: tuple, steps: int) -> None:
             out = exN(fields, scalars, coeffs)
             jax.block_until_ready(out["u"])
             dt = min(dt, time.perf_counter() - t0)
-        return dt, fraction_for(exN, dt)
+        return dt
 
     sps = {}
     for schedule in ("block", "stream"):
-        dt, rf = measure(CompileOptions(backend="pallas", steps=steps,
-                                        update=update, schedule=schedule),
-                         steps)
+        dt = measure(CompileOptions(backend="pallas", steps=steps,
+                                    update=update, schedule=schedule),
+                     steps)
         sps[schedule] = steps / dt
         emit_row(f"sched/pw_advection/{tag}/pallas/{schedule}/fused_loop",
-                 dt * 1e6, f"{steps / dt:.2f} steps/s",
-                 roofline_fraction=rf)
+                 dt * 1e6, f"{steps / dt:.2f} steps/s")
     emit_row(f"sched/pw_advection/{tag}/pallas/stream_vs_block", 0.0,
              f"{sps['stream'] / sps['block']:.2f}x stream vs block")
 
@@ -220,13 +199,12 @@ def run_schedule_rows(emit_row, grid: tuple, steps: int) -> None:
     tsteps = max(steps, 4)
     tiled = {}
     for tt in (1, 4):
-        dt, rf = measure(CompileOptions(backend="pallas", steps=tsteps,
-                                        update=update, schedule="stream",
-                                        time_tile=tt), tsteps)
+        dt = measure(CompileOptions(backend="pallas", steps=tsteps,
+                                    update=update, schedule="stream",
+                                    time_tile=tt), tsteps)
         tiled[tt] = tsteps / dt
         emit_row(f"sched/pw_advection/{tag}/pallas/stream/time_tile={tt}"
-                 f"/fused_loop", dt * 1e6, f"{tsteps / dt:.2f} steps/s",
-                 roofline_fraction=rf)
+                 f"/fused_loop", dt * 1e6, f"{tsteps / dt:.2f} steps/s")
     emit_row(f"sched/pw_advection/{tag}/pallas/stream/t4_vs_t1", 0.0,
              f"{tiled[4] / tiled[1]:.2f}x time_tile=4 vs 1")
 
@@ -236,15 +214,14 @@ def run_schedule_rows(emit_row, grid: tuple, steps: int) -> None:
     matrix = {}
     for pt in (1, 4):
         for tt in (1, 4):
-            dt, rf = measure(CompileOptions(backend="pallas", steps=tsteps,
-                                            update=update, schedule="stream",
-                                            time_tile=tt, plane_tile=pt),
-                             tsteps)
+            dt = measure(CompileOptions(backend="pallas", steps=tsteps,
+                                        update=update, schedule="stream",
+                                        time_tile=tt, plane_tile=pt),
+                         tsteps)
             matrix[pt, tt] = tsteps / dt
             emit_row(f"sched/pw_advection/{tag}/pallas/stream"
                      f"/plane_tile={pt}/time_tile={tt}/fused_loop",
-                     dt * 1e6, f"{tsteps / dt:.2f} steps/s",
-                     roofline_fraction=rf)
+                     dt * 1e6, f"{tsteps / dt:.2f} steps/s")
     emit_row(f"sched/pw_advection/{tag}/pallas/stream/p4_vs_p1", 0.0,
              f"{matrix[4, 1] / matrix[1, 1]:.2f}x plane_tile=4 vs 1")
 

@@ -194,12 +194,6 @@ def model_plan(p: Program, plan, grid) -> float:
 
     The jnp backends ignore block shape and fuse groups, so their candidates
     collapse to the backend-level bytes/point of :func:`model_program`.
-
-    The prediction is checked against reality by :mod:`repro.obs.achieved`:
-    ``achieved_fraction = model_plan(...) * steps / measured_seconds`` rides
-    on tune records, ``PlanChosen`` trace events and the smoke-benchmark
-    rows, so the model's calibration drift is observable per commit
-    (ROADMAP item 3).
     """
     pts = float(np.prod([int(g) for g in grid]))
     bs = hw.DTYPE_BYTES[plan.dtype]

@@ -52,8 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import obs
 from ..kernels.stencil3d import build_group_call
-from ..obs.trace import current_tracer
 from . import boundary as bc
 from .dataflow import STREAM_AXIS, lower_to_dataflow
 from .ir import Program
@@ -76,34 +76,35 @@ def _exchange_axis(x: jnp.ndarray, ax: int, lo: int, hi: int, align: int,
     lo, hi, align = int(lo), int(hi), int(align)
     if lo == 0 and hi == 0 and align == 0:
         return x
-    sharded = axis_name is not None and n > 1
-    size = x.shape[ax]
-    pieces = []
-    if lo > 0:
-        if sharded:
-            src = jax.lax.slice_in_dim(x, size - lo, size, axis=ax)
-            pieces.append(jax.lax.ppermute(
-                src, axis_name, bc.ring_perms(n, +1, periodic)))
-        elif periodic:
-            pieces.append(jax.lax.slice_in_dim(x, size - lo, size, axis=ax))
-        else:
-            shp = list(x.shape); shp[ax] = lo
+    with obs.phase("halo"):
+        sharded = axis_name is not None and n > 1
+        size = x.shape[ax]
+        pieces = []
+        if lo > 0:
+            if sharded:
+                src = jax.lax.slice_in_dim(x, size - lo, size, axis=ax)
+                pieces.append(jax.lax.ppermute(
+                    src, axis_name, bc.ring_perms(n, +1, periodic)))
+            elif periodic:
+                pieces.append(jax.lax.slice_in_dim(x, size - lo, size, axis=ax))
+            else:
+                shp = list(x.shape); shp[ax] = lo
+                pieces.append(jnp.zeros(shp, x.dtype))
+        pieces.append(x)
+        if hi > 0:
+            if sharded:
+                src = jax.lax.slice_in_dim(x, 0, hi, axis=ax)
+                pieces.append(jax.lax.ppermute(
+                    src, axis_name, bc.ring_perms(n, -1, periodic)))
+            elif periodic:
+                pieces.append(jax.lax.slice_in_dim(x, 0, hi, axis=ax))
+            else:
+                shp = list(x.shape); shp[ax] = hi
+                pieces.append(jnp.zeros(shp, x.dtype))
+        if align > 0:
+            shp = list(x.shape); shp[ax] = align
             pieces.append(jnp.zeros(shp, x.dtype))
-    pieces.append(x)
-    if hi > 0:
-        if sharded:
-            src = jax.lax.slice_in_dim(x, 0, hi, axis=ax)
-            pieces.append(jax.lax.ppermute(
-                src, axis_name, bc.ring_perms(n, -1, periodic)))
-        elif periodic:
-            pieces.append(jax.lax.slice_in_dim(x, 0, hi, axis=ax))
-        else:
-            shp = list(x.shape); shp[ax] = hi
-            pieces.append(jnp.zeros(shp, x.dtype))
-    if align > 0:
-        shp = list(x.shape); shp[ax] = align
-        pieces.append(jnp.zeros(shp, x.dtype))
-    return jnp.concatenate(pieces, axis=ax) if len(pieces) > 1 else pieces[0]
+        return jnp.concatenate(pieces, axis=ax) if len(pieces) > 1 else pieces[0]
 
 
 def halo_exchange_pad(x: jnp.ndarray, lo: Sequence[int], hi: Sequence[int],
@@ -336,11 +337,6 @@ def lower_sharded(p: Program, plan: DataflowPlan, global_grid,
     jdtype = _DTYPES[plan.dtype]
     bnd = p.boundaries()
     backend = plan.backend
-    tracer = current_tracer()
-    if tracer.enabled:
-        tracer.event("ShardLowered", program=p.name, mode="single",
-                     backend=backend, mesh=dict(mesh.shape),
-                     local_grid="x".join(str(g) for g in shard.local_grid))
     mesh_axes, axis_sizes = shard.mesh_axes, shard.axis_sizes
     out_names = p.output_fields()
     origin_arrs, origin_specs = _origin_inputs(shard)
@@ -358,22 +354,25 @@ def lower_sharded(p: Program, plan: DataflowPlan, global_grid,
             reach = _pallas_reach(calls, p)
 
         def local_fn(svec, fields, coeffs, origs):
-            origin = _origin(shard, origs)
-            # degenerate mesh: the local pad path, so the graph (and its
-            # rounding) bit-matches the single-device lowering
-            pc_per_call = (_pad_coeffs(p, calls, coeffs, jdtype) if degen
-                           else _pallas_coeff_windows(p, calls, coeffs,
-                                                      origin, shard, reach))
+            with obs.phase("entry"):
+                origin = _origin(shard, origs)
+                # degenerate mesh: the local pad path, so the graph (and
+                # its rounding) bit-matches the single-device lowering
+                pc_per_call = (_pad_coeffs(p, calls, coeffs, jdtype) if degen
+                               else _pallas_coeff_windows(
+                                   p, calls, coeffs, origin, shard, reach))
 
             def resolve(call, f, env):
                 x = env[f] if f in env else fields[f]
-                if degen:
-                    return bc.pad_field(x, call.halo_lo, call.halo_hi,
-                                        bnd[f], align_hi=call.align_hi), None
-                return halo_exchange_pad(
-                    x, call.halo_lo, call.halo_hi, call.align_hi,
-                    mesh_axes, axis_sizes,
-                    periodic=bnd[f] == "periodic"), None
+                with obs.phase("group_pad"):
+                    if degen:
+                        return bc.pad_field(x, call.halo_lo, call.halo_hi,
+                                            bnd[f],
+                                            align_hi=call.align_hi), None
+                    return halo_exchange_pad(
+                        x, call.halo_lo, call.halo_hi, call.align_hi,
+                        mesh_axes, axis_sizes,
+                        periodic=bnd[f] == "periodic"), None
 
             outputs = _run_groups(p, calls, svec, pc_per_call, resolve,
                                   origin=origin)
@@ -396,10 +395,12 @@ def lower_sharded(p: Program, plan: DataflowPlan, global_grid,
             coeffs: Mapping | None = None):
         scalars = scalars or {}
         coeffs = coeffs or {}
-        fdict = {f: jnp.asarray(fields[f], dtype=jdtype)
-                 for f in p.input_fields()}
-        cdict = _host_coeffs(p, coeffs, jdtype, reach)
-        res = smapped(pack_scalars(scalars), fdict, cdict, origin_arrs)
+        with obs.phase("entry"):
+            fdict = {f: jnp.asarray(fields[f], dtype=jdtype)
+                     for f in p.input_fields()}
+            cdict = _host_coeffs(p, coeffs, jdtype, reach)
+            svec = pack_scalars(scalars)
+        res = smapped(svec, fdict, cdict, origin_arrs)
         return dict(zip(out_names, res))
 
     return run
@@ -443,12 +444,6 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
         raise ValueError("spec has no ShardSpec; use the local lowerings")
     update = adapt_update(update)
     global_grid = tuple(int(g) for g in global_grid)
-    tracer = current_tracer()
-    if tracer.enabled:
-        tracer.event("ShardLowered", program=p.name, mode="loop",
-                     backend=plan.backend, mesh=dict(mesh.shape),
-                     local_grid="x".join(str(g) for g in shard.local_grid),
-                     steps=int(spec.steps))
     ndim = p.ndim
     jdtype = _DTYPES[plan.dtype]
     bnd = p.boundaries()
@@ -487,10 +482,11 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
         # boundary, zero lane-alignment slab on the hi side
         if f not in refreshed:
             return carry_f
-        return halo_exchange_pad(
-            carry_f[interior[f]], fpad[f][:, 0],
-            [int(fpad[f][a, 1]) - int(align[a]) for a in range(ndim)],
-            align, mesh_axes, axis_sizes, periodic=bnd[f] == "periodic")
+        with obs.phase("halo"):
+            return halo_exchange_pad(
+                carry_f[interior[f]], fpad[f][:, 0],
+                [int(fpad[f][a, 1]) - int(align[a]) for a in range(ndim)],
+                align, mesh_axes, axis_sizes, periodic=bnd[f] == "periodic")
 
     origin_arrs, origin_specs = _origin_inputs(shard)
     scal_spec, pack_scalars = _scalar_io(p, backend)
@@ -547,14 +543,15 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
                     if f in fresh:      # persistent: window from the carry
                         return fresh[f], fpad[f]
                     # transient inter-group: exchange to the call's geometry
-                    if degen:
-                        return bc.pad_field(env[f], call.halo_lo,
-                                            call.halo_hi, bnd[f],
-                                            align_hi=call.align_hi), None
-                    return halo_exchange_pad(
-                        env[f], call.halo_lo, call.halo_hi, call.align_hi,
-                        mesh_axes, axis_sizes,
-                        periodic=bnd[f] == "periodic"), None
+                    with obs.phase("group_pad"):
+                        if degen:
+                            return bc.pad_field(env[f], call.halo_lo,
+                                                call.halo_hi, bnd[f],
+                                                align_hi=call.align_hi), None
+                        return halo_exchange_pad(
+                            env[f], call.halo_lo, call.halo_hi,
+                            call.align_hi, mesh_axes, axis_sizes,
+                            periodic=bnd[f] == "periodic"), None
 
                 return _run_groups(p, calls_, svec, pc_per_call, resolve,
                                    origin=origin)
@@ -580,14 +577,15 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
         raise ValueError(f"unknown backend {backend!r}")
 
     def local_fn(scal, fields, coeffs, origs):
-        origin = _origin(shard, origs)
-        step = make_step(origin, coeffs, calls)
-        step_epi = (make_step(origin, coeffs, epilogue_calls)
-                    if epilogue_calls is not None else None)
-        # initial carry: zero-padded; the loop body refreshes halos before
-        # the first compute, so the fill value is never observed
-        carry = {f: jnp.pad(fields[f], carry_pads[f])
-                 for f in spec.persistent}
+        with obs.phase("entry"):
+            origin = _origin(shard, origs)
+            step = make_step(origin, coeffs, calls)
+            step_epi = (make_step(origin, coeffs, epilogue_calls)
+                        if epilogue_calls is not None else None)
+            # initial carry: zero-padded; the loop body refreshes halos
+            # before the first compute, so the fill value is never observed
+            carry = {f: jnp.pad(fields[f], carry_pads[f])
+                     for f in spec.persistent}
 
         def advance(carry, stepfn):
             fresh = {f: refresh(f, carry[f]) for f in spec.persistent}
@@ -596,34 +594,39 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
                 new = stepfn(fresh, scal)
             else:
                 outputs = stepfn(fresh, scal)
-                cur = {f: fresh[f][interior[f]] for f in spec.persistent}
-                new = dict(cur)
-                # the packed pallas scalar vector unpacks back to the
-                # name->value dict the update rule sees everywhere else
-                sdict = ({s: scal[i] for i, s in enumerate(p.scalars)}
-                         if backend == "pallas" else scal)
-                if getattr(update, "_takes_origin", False) and not degen:
-                    # shard-aware rules (the serving bucket refresh) mask
-                    # in global coordinates; the degenerate mesh keeps the
-                    # local form so its graph stays bit-identical
-                    new.update(update(cur, outputs, sdict, origin=origin))
-                else:
-                    new.update(update(cur, outputs, sdict))
+                with obs.phase("update"):
+                    cur = {f: fresh[f][interior[f]] for f in spec.persistent}
+                    new = dict(cur)
+                    # the packed pallas scalar vector unpacks back to the
+                    # name->value dict the update rule sees everywhere else
+                    sdict = ({s: scal[i] for i, s in enumerate(p.scalars)}
+                             if backend == "pallas" else scal)
+                    if getattr(update, "_takes_origin", False) and not degen:
+                        # shard-aware rules (the serving bucket refresh)
+                        # mask in global coordinates; the degenerate mesh
+                        # keeps the local form so its graph stays
+                        # bit-identical
+                        new.update(update(cur, outputs, sdict,
+                                          origin=origin))
+                    else:
+                        new.update(update(cur, outputs, sdict))
             out = {}
-            for f in spec.persistent:
-                if spec.carry_write == "inplace":
-                    out[f] = fresh[f].at[interior[f]].set(
-                        jnp.asarray(new[f], dtype=jdtype))
-                else:   # "repad": halos are rebuilt next iteration anyway
-                    out[f] = jnp.pad(jnp.asarray(new[f], dtype=jdtype),
-                                     carry_pads[f])
+            with obs.phase("carry_write"):
+                for f in spec.persistent:
+                    if spec.carry_write == "inplace":
+                        out[f] = fresh[f].at[interior[f]].set(
+                            jnp.asarray(new[f], dtype=jdtype))
+                    else:   # "repad": halos are rebuilt next iteration
+                        out[f] = jnp.pad(jnp.asarray(new[f], dtype=jdtype),
+                                         carry_pads[f])
             return out
 
         carry = jax.lax.fori_loop(0, int(spec.steps) // chain,
                                   lambda _, c: advance(c, step), carry)
         if step_epi is not None:
             carry = advance(carry, step_epi)
-        return tuple(carry[f][interior[f]] for f in spec.persistent)
+        with obs.phase("exit"):
+            return tuple(carry[f][interior[f]] for f in spec.persistent)
 
     smapped = _smap(local_fn, mesh, in_specs, out_specs)
 
@@ -631,10 +634,12 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
             coeffs: Mapping | None = None):
         scalars = scalars or {}
         coeffs = coeffs or {}
-        fdict = {f: jnp.asarray(fields[f], dtype=jdtype)
-                 for f in p.input_fields()}
-        cdict = _host_coeffs(p, coeffs, jdtype, reach)
-        res = smapped(pack_scalars(scalars), fdict, cdict, origin_arrs)
+        with obs.phase("entry"):
+            fdict = {f: jnp.asarray(fields[f], dtype=jdtype)
+                     for f in p.input_fields()}
+            cdict = _host_coeffs(p, coeffs, jdtype, reach)
+            svec = pack_scalars(scalars)
+        res = smapped(svec, fdict, cdict, origin_arrs)
         return dict(zip(spec.persistent, res))
 
     return run

@@ -4,6 +4,11 @@ Runs the plan's fuse groups in order.  Fields crossing a group boundary are
 materialised in HBM — the TPU equivalent of the paper's inter-stage streams —
 and re-padded for the consuming group's windows.  Inside a group everything
 flows through the generated kernel's VMEM windows (see kernels/stencil3d.py).
+
+The XLA ops around the kernels carry a ``repro_phase`` tag
+(:func:`repro.obs.phase`): ``entry`` and ``exit`` around the fused loop,
+``group_pad`` for the pads that feed a group, ``update`` and
+``carry_write`` in each step.
 """
 
 from __future__ import annotations
@@ -13,6 +18,7 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels.stencil3d import build_group_call
 from . import boundary as bc
 from .ir import Program
@@ -85,16 +91,19 @@ def lower_from_calls(p: Program, dtype, calls):
             coeffs: Mapping[str, jnp.ndarray] | None = None):
         scalars = scalars or {}
         coeffs = coeffs or {}
-        ext = {k: jnp.asarray(v, dtype=dtype) for k, v in fields.items()}
         bnd = p.boundaries()
+        with obs.phase("entry"):
+            ext = {k: jnp.asarray(v, dtype=dtype) for k, v in fields.items()}
+            svec = _scalar_vec(p, scalars)
+            pc_per_call = _pad_coeffs(p, calls, coeffs, dtype)
 
         def resolve(call, f, env):
             x = env[f] if f in env else ext[f]
-            return bc.pad_field(x, call.halo_lo, call.halo_hi, bnd[f],
-                                align_hi=call.align_hi), None
+            with obs.phase("group_pad"):
+                return bc.pad_field(x, call.halo_lo, call.halo_hi, bnd[f],
+                                    align_hi=call.align_hi), None
 
-        return _run_groups(p, calls, _scalar_vec(p, scalars),
-                           _pad_coeffs(p, calls, coeffs, dtype), resolve)
+        return _run_groups(p, calls, svec, pc_per_call, resolve)
 
     return run
 
@@ -167,22 +176,24 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
             coeffs: Mapping | None = None):
         scalars = scalars or {}
         coeffs = coeffs or {}
-        svec = _scalar_vec(p, scalars)
-        # coefficients never change across steps: pad per consuming group
-        # once, before the loop ("small data" stays resident)
-        pc_per_call = _pad_coeffs(p, calls, coeffs, dtype)
-        pc_epilogue = (_pad_coeffs(p, epilogue, coeffs, dtype)
-                       if epilogue is not None else None)
-        # pad the persistent carry buffers exactly once
-        carry = {f: refill(f, jnp.asarray(fields[f], dtype=dtype))
-                 for f in spec.persistent}
+        with obs.phase("entry"):
+            svec = _scalar_vec(p, scalars)
+            # coefficients never change across steps: pad per consuming
+            # group once, before the loop ("small data" stays resident)
+            pc_per_call = _pad_coeffs(p, calls, coeffs, dtype)
+            pc_epilogue = (_pad_coeffs(p, epilogue, coeffs, dtype)
+                           if epilogue is not None else None)
+            # pad the persistent carry buffers exactly once
+            carry = {f: refill(f, jnp.asarray(fields[f], dtype=dtype))
+                     for f in spec.persistent}
 
         def advance(carry, calls_, pc_):
             def resolve(call, f, env):
                 if f in carry:              # persistent: window from carry
                     return carry[f], fpad[f]
-                return bc.pad_field(env[f], call.halo_lo, call.halo_hi,
-                                    bnd[f], align_hi=call.align_hi), None
+                with obs.phase("group_pad"):
+                    return bc.pad_field(env[f], call.halo_lo, call.halo_hi,
+                                        bnd[f], align_hi=call.align_hi), None
 
             if getattr(calls_[0], "returns_fields", False):
                 # temporally-blocked chain: one call advances every field
@@ -193,19 +204,21 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                            input_pad={f: fpad[f] for f in call.group_inputs})
             else:
                 outputs = _run_groups(p, calls_, svec, pc_, resolve)
-                cur = {f: carry[f][interior[f]] for f in spec.persistent}
-                new = dict(cur)
-                new.update(update(cur, outputs, scalars))
+                with obs.phase("update"):
+                    cur = {f: carry[f][interior[f]] for f in spec.persistent}
+                    new = dict(cur)
+                    new.update(update(cur, outputs, scalars))
             out = {}
-            for f in spec.persistent:
-                if spec.carry_write == "inplace" and bnd[f] == "zero":
-                    # zero halos never change: scatter the interior only
-                    out[f] = carry[f].at[interior[f]].set(
-                        jnp.asarray(new[f], dtype=dtype))
-                else:
-                    # one fused interior write + constant (zero) or
-                    # refreshed (wraparound) halo slabs — no carry RMW
-                    out[f] = refill(f, jnp.asarray(new[f], dtype=dtype))
+            with obs.phase("carry_write"):
+                for f in spec.persistent:
+                    if spec.carry_write == "inplace" and bnd[f] == "zero":
+                        # zero halos never change: scatter the interior only
+                        out[f] = carry[f].at[interior[f]].set(
+                            jnp.asarray(new[f], dtype=dtype))
+                    else:
+                        # one fused interior write + constant (zero) or
+                        # refreshed (wraparound) halo slabs — no carry RMW
+                        out[f] = refill(f, jnp.asarray(new[f], dtype=dtype))
             return out
 
         def body(_, carry):
@@ -214,6 +227,7 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
         carry = jax.lax.fori_loop(0, outer, body, carry)
         if epilogue is not None and int(spec.steps) % chain:
             carry = advance(carry, epilogue, pc_epilogue)
-        return {f: carry[f][interior[f]] for f in spec.persistent}
+        with obs.phase("exit"):
+            return {f: carry[f][interior[f]] for f in spec.persistent}
 
     return run

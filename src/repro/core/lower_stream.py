@@ -42,8 +42,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import hw
-from ..obs.trace import current_tracer
+from .. import hw, obs
 from .dataflow import StreamGraph, StreamRegion, lower_to_dataflow
 from .expr_eval import evaluate
 from .ir import Access, Program
@@ -481,6 +480,10 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
         # the next, so the sweep axis must run in order on one core
         compiler_params=hw.pallas_compiler_params(("arbitrary",)),
         interpret=hw.pallas_interpret(),
+        # the region's stored fields name the kernel in the HLO and the
+        # trace; a chain also names its depth, so a remainder chain differs
+        name=(f"chain{T}_" if update is not None else "str_")
+        + "_".join(store_names),
     )
 
     expect = tuple(halo_lo[a] + grid_shape[a] + halo_hi[a]
@@ -493,36 +496,40 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
         (ndim, 2) padding the provided array actually carries when it
         exceeds this region's window geometry (fused-loop carries); the
         expected window is sliced out statically."""
-        svec = (scalars_vec if scalars_vec is not None
-                else jnp.zeros((max(n_scalars, 1),), jnp.float32))
-        org = (origin if origin is not None
-               else jnp.zeros((ndim,), jnp.int32))
-        # every small operand is a (1, n) row: vmap then prepends the batch
-        # axis ahead of the last two, where Mosaic's block rule allows it
-        args = [svec.reshape(1, -1), org.reshape(1, -1)]
-        for f in gh.group_inputs:
-            x = padded_inputs[f]
-            if input_pad is not None and f in input_pad:
-                ip = input_pad[f]
-                sl = tuple(slice(int(ip[a][0]) - halo_lo[a],
-                                 int(ip[a][0]) - halo_lo[a] + expect[a])
-                           for a in range(ndim))
-                x = x[sl]
-            if pad_round:
-                # round the stream extent up to the P-plane DMA grid; the
-                # zero planes only feed virtual steps whose completed
-                # planes land past the domain and are sliced off below, so
-                # the public pad_lo/pad_hi geometry is untouched
-                x = jnp.pad(x, [(0, pad_round)] + [(0, 0)] * (ndim - 1))
-            args.append(x)
-        for c in gh.group_coeffs:
-            args.append(padded_coeffs[c].reshape(1, -1))
+        with obs.phase("window"):
+            svec = (scalars_vec if scalars_vec is not None
+                    else jnp.zeros((max(n_scalars, 1),), jnp.float32))
+            org = (origin if origin is not None
+                   else jnp.zeros((ndim,), jnp.int32))
+            # every small operand is a (1, n) row: vmap then prepends the
+            # batch axis ahead of the last two, where Mosaic's block rule
+            # allows it
+            args = [svec.reshape(1, -1), org.reshape(1, -1)]
+            for f in gh.group_inputs:
+                x = padded_inputs[f]
+                if input_pad is not None and f in input_pad:
+                    ip = input_pad[f]
+                    sl = tuple(slice(int(ip[a][0]) - halo_lo[a],
+                                     int(ip[a][0]) - halo_lo[a] + expect[a])
+                               for a in range(ndim))
+                    x = x[sl]
+                if pad_round:
+                    # round the stream extent up to the P-plane DMA grid;
+                    # the zero planes only feed virtual steps whose
+                    # completed planes land past the domain and are sliced
+                    # off below, so the public pad_lo/pad_hi geometry is
+                    # untouched
+                    x = jnp.pad(x, [(0, pad_round)] + [(0, 0)] * (ndim - 1))
+                args.append(x)
+            for c in gh.group_coeffs:
+                args.append(padded_coeffs[c].reshape(1, -1))
         res = call(*args)
         if len(store_names) == 1:
             res = (res,)
-        if n_out * P != n0:
-            res = tuple(x[:n0] for x in res)
-        return dict(zip(store_names, res))
+        with obs.phase("window"):
+            if n_out * P != n0:
+                res = tuple(x[:n0] for x in res)
+            return dict(zip(store_names, res))
 
     # geometry for the shared orchestrators (identical to build_group_call)
     run.group_inputs = gh.group_inputs
@@ -571,11 +578,6 @@ def lower(p: Program, plan: DataflowPlan, grid_shape,
     if graph is None:
         graph = lower_to_dataflow(p, plan, grid_shape)
     dtype, calls = _build_calls(p, plan, grid_shape, graph)
-    tracer = current_tracer()
-    if tracer.enabled:
-        tracer.event("StreamLowered", program=p.name, mode="single",
-                     regions=len(calls), time_tile=1,
-                     plane_tile=int(graph.plane_tile))
     return lower_from_calls(p, dtype, calls)
 
 
@@ -599,10 +601,6 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
         graph = lower_to_dataflow(p, plan, grid_shape)
     T = int(getattr(graph, "time_tile", 1))
     P = int(getattr(graph, "plane_tile", 1))
-    tracer = current_tracer()
-    if tracer.enabled:
-        tracer.event("StreamLowered", program=p.name, mode="loop",
-                     regions=len(graph.regions), time_tile=T, plane_tile=P)
     if T <= 1:
         _, calls = _build_calls(p, plan, grid_shape, graph)
         return time_loop_from_calls(p, dtype, grid_shape, spec, update,

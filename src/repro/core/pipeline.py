@@ -480,8 +480,7 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
                 strategy=strategy, label=rec.get("label", "auto_plan"),
                 time_tile=int(eff_tt), plane_tile=int(eff_pt),
                 modeled_us=rec.get("modeled_us"),
-                measured_us=rec.get("us_fused") or rec.get("us_single"),
-                roofline_fraction=rec.get("roofline_fraction")))
+                measured_us=rec.get("us_fused") or rec.get("us_single")))
     return CompiledStencil(program=p, plan=plan, grid=grid, _fn=fn,
                            jitted=jit, time_spec=time_spec, shard=shard)
 

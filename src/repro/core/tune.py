@@ -422,20 +422,6 @@ def _default_timer_factory(warmup: int, repeats: int) -> Callable:
     return timer
 
 
-def _roofline_fraction(cand: _Candidate, steps: int | None) -> float | None:
-    """Achieved fraction of the plan model's prediction:
-    ``modeled_time / measured_time`` for the mode the candidate is ranked
-    by (fused ``steps=N`` when measured, else single-step).  ``None`` when
-    the candidate was never measured or the model degenerated."""
-    meas_us = cand.us_fused if cand.us_fused is not None else cand.us_single
-    if meas_us is None or meas_us <= 0:
-        return None
-    if not (cand.modeled_s > 0) or cand.modeled_s == float("inf"):
-        return None
-    mult = (steps or 1) if cand.us_fused is not None else 1
-    return (cand.modeled_s * 1e6 * mult) / meas_us
-
-
 def _measure(p, grid, cand: _Candidate, data, update, cfg: TuneConfig,
              timer, mesh=None, mesh_axes=None) -> None:
     # deferred: pipeline imports tune
@@ -533,9 +519,7 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
                 _measure(p, grid, c, data, update, cfg, timer,
                          mesh=mesh, mesh_axes=mesh_axes)
                 csp.set(modeled_us=c.modeled_s * 1e6,
-                        us_single=c.us_single, us_fused=c.us_fused,
-                        roofline_fraction=_roofline_fraction(
-                            c, cfg.steps if with_loop else None))
+                        us_single=c.us_single, us_fused=c.us_fused)
 
     order = sorted(range(len(survivors)),
                    key=lambda i: (survivors[i].score(), i))
@@ -561,11 +545,6 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
         "baseline_us_single": baseline.us_single,
         "baseline_us_fused": baseline.us_fused,
         "modeled_us": winner.modeled_s * 1e6,
-        # achieved fraction of the roofline plan model's prediction for the
-        # winner (modeled/measured; tiny under CPU interpret — the tracked
-        # quantity is its trend, see repro.obs.achieved)
-        "roofline_fraction": _roofline_fraction(
-            winner, cfg.steps if with_loop else None),
         "mesh": _mesh_tag(mesh, mesh_axes),
         "steps": cfg.steps if with_loop else None,
         "candidates": len(cands),
@@ -581,8 +560,7 @@ def tune_plan(p: Program, grid, *, backend: str = "pallas",
             schedule=winner.plan.schedule, strategy="tuned",
             label=winner.label, time_tile=record["time_tile"],
             plane_tile=record["plane_tile"], modeled_us=record["modeled_us"],
-            measured_us=winner.score(),
-            roofline_fraction=record["roofline_fraction"]))
+            measured_us=winner.score()))
     return TuneResult(plan=winner.plan, carry_write=winner.carry_write,
                       key=key, record=record, cache_hit=False,
                       measured=[survivors[i] for i in order])

@@ -40,7 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import hw
+from .. import hw, obs
 from ..core.expr_eval import evaluate
 from ..core.ir import Access, Program
 from ..core.passes import GroupHalo, infer_halo
@@ -198,6 +198,8 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         out_shape=out_shape if len(out_names) > 1 else out_shape[0],
         compiler_params=hw.pallas_compiler_params(("parallel",) * ndim),
         interpret=hw.pallas_interpret(),
+        # the group's outputs name the kernel in the HLO and the trace
+        name="blk_" + "_".join(out_names),
     )
 
     crop = tuple(slice(0, grid_shape[a]) for a in range(ndim))
@@ -213,29 +215,31 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         e.g. a fused time loop's carry-resident persistent buffer sized for
         the worst consuming group.  The window is sliced out statically; no
         reallocation or copy of the halo slabs happens here."""
-        svec = (scalars_vec if scalars_vec is not None
-                else jnp.zeros((max(n_scalars, 1),), jnp.float32))
-        org = (origin if origin is not None
-               else jnp.zeros((ndim,), jnp.int32))
-        # scalars and origin are (1, n) rows, coefficients span only their
-        # own axis: vmap then prepends the batch axis ahead of the last two,
-        # where Mosaic's block rule allows it
-        args = [svec.reshape(1, -1), org.reshape(1, -1)]
-        for f in gh.group_inputs:
-            x = padded_inputs[f]
-            if input_pad is not None and f in input_pad:
-                ip = input_pad[f]
-                sl = tuple(slice(int(ip[a][0]) - halo_lo[a],
-                                 int(ip[a][0]) - halo_lo[a] + expect[a])
-                           for a in range(ndim))
-                x = x[sl]
-            args.append(x)
-        for c in gh.group_coeffs:
-            args.append(padded_coeffs[c].reshape(coeff_shape[c]))
+        with obs.phase("window"):
+            svec = (scalars_vec if scalars_vec is not None
+                    else jnp.zeros((max(n_scalars, 1),), jnp.float32))
+            org = (origin if origin is not None
+                   else jnp.zeros((ndim,), jnp.int32))
+            # scalars and origin are (1, n) rows, coefficients span only
+            # their own axis: vmap then prepends the batch axis ahead of the
+            # last two, where Mosaic's block rule allows it
+            args = [svec.reshape(1, -1), org.reshape(1, -1)]
+            for f in gh.group_inputs:
+                x = padded_inputs[f]
+                if input_pad is not None and f in input_pad:
+                    ip = input_pad[f]
+                    sl = tuple(slice(int(ip[a][0]) - halo_lo[a],
+                                     int(ip[a][0]) - halo_lo[a] + expect[a])
+                               for a in range(ndim))
+                    x = x[sl]
+                args.append(x)
+            for c in gh.group_coeffs:
+                args.append(padded_coeffs[c].reshape(coeff_shape[c]))
         res = call(*args)
         if len(out_names) == 1:
             res = (res,)
-        return {f: r[crop] for f, r in zip(out_names, res)}
+        with obs.phase("window"):
+            return {f: r[crop] for f, r in zip(out_names, res)}
 
     # geometry for orchestrators (lower_pallas pads with zeros; distribute
     # pads via halo exchange)

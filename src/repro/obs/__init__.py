@@ -1,24 +1,18 @@
-"""repro.obs — the observability subsystem (tracing, metrics, achieved
-roofline).
+"""repro.obs — the observability subsystem (tracing, metrics).
 
-Three pillars, all dependency-free and off by default:
+Two pillars, off by default:
 
 * **tracing** (:mod:`repro.obs.trace` + :mod:`repro.obs.events`): nested
   wall-clock spans and typed events emitted from every layer of the stack
-  (compile, dataflow legalisation, tuner, distribution, serving), exported
-  to JSONL or Chrome ``trace_event`` JSON.  Enable with
+  (compile, dataflow legalisation, tuner, serving), exported to JSONL or
+  Chrome ``trace_event`` JSON, and mirrored into the JAX profiler's trace
+  as ``TraceAnnotation``s while a tracer records.  Enable with
   ``CompileOptions(trace=tracer)``, ``StencilEngine(tracer=...)``,
-  ``set_tracer``, or ``REPRO_TRACE=path``.
+  ``set_tracer``, or ``REPRO_TRACE=path``.  :func:`phase` tags the device
+  ops of the fused loop and the mesh by phase.
 * **metrics** (:mod:`repro.obs.metrics`): counters/gauges/histograms with
   a JSON-ready ``snapshot()``; ``ServeStats`` is the serve-scoped view,
   :func:`global_metrics` collects the compile side.
-* **achieved roofline** (:mod:`repro.obs.achieved`): measured performance
-  as a fraction of :func:`~repro.analysis.stencil_roofline.model_plan`'s
-  prediction — ROADMAP item 3's tracked quantity.
-
-``achieved`` imports the analysis/core layers, which themselves emit into
-``trace``/``metrics`` — it loads lazily here so those layers can import
-``repro.obs`` without a cycle.
 """
 
 from .events import (CacheHit, CacheMiss, ChainDemoted, ExecutorEvicted,
@@ -26,24 +20,12 @@ from .events import (CacheHit, CacheMiss, ChainDemoted, ExecutorEvicted,
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       global_metrics)
 from .trace import (NULL, TRACE_ENV, NullTracer, Tracer, current_tracer,
-                    resolve_tracer, set_tracer)
+                    phase, resolve_tracer, set_tracer)
 
 __all__ = [
     "CacheHit", "CacheMiss", "ChainDemoted", "ExecutorEvicted",
     "PlanChosen", "PlaneDemoted",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "global_metrics",
     "NULL", "TRACE_ENV", "NullTracer", "Tracer", "current_tracer",
-    "resolve_tracer", "set_tracer",
-    "AchievedResult", "achieved_fraction", "fraction_for",
-    "measure_achieved", "model_call_seconds",
+    "phase", "resolve_tracer", "set_tracer",
 ]
-
-_ACHIEVED = ("AchievedResult", "achieved_fraction", "fraction_for",
-             "measure_achieved", "model_call_seconds")
-
-
-def __getattr__(name: str):
-    if name in _ACHIEVED:
-        from . import achieved
-        return getattr(achieved, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

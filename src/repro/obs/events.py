@@ -9,7 +9,7 @@ The set mirrors the silent decisions the optimiser used to bury in field
 values:
 
 * :class:`PlanChosen` — a compile or tune settled on a plan (with the
-  modeled-vs-measured ``roofline_fraction`` when a measurement exists);
+  modeled and, when a measurement exists, measured microseconds);
 * :class:`ChainDemoted` / :class:`PlaneDemoted` — stream legalisation
   reduced a requested ``time_tile`` / ``plane_tile`` (the structured form
   of ``chain_split_reason`` / ``plane_split_reason``);
@@ -28,9 +28,8 @@ import dataclasses
 class PlanChosen:
     """A plan was settled on — by the heuristic, the tuner, or a cache.
 
-    ``roofline_fraction`` is achieved/predicted performance
-    (``modeled_s / measured_s``; > 1 means the run beat the model) and is
-    ``None`` when nothing was measured (pure-heuristic compiles)."""
+    ``measured_us`` is ``None`` when nothing was measured (pure-heuristic
+    compiles)."""
 
     program: str
     backend: str
@@ -41,7 +40,6 @@ class PlanChosen:
     plane_tile: int = 1
     modeled_us: float | None = None
     measured_us: float | None = None
-    roofline_fraction: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,6 +12,16 @@ A :class:`Tracer` records two record kinds:
   or ``tracer.emit(PlanChosen(...))`` for the typed payloads in
   :mod:`repro.obs.events`.
 
+While a real tracer records, each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+taken around the work shows the program's host spans on the clock of the
+device ops.
+
+:func:`phase` is the device-side counterpart: a trace-time tag
+(``repro_phase``) on the XLA ops of one piece of work around the
+generated kernels, which the compiled program's ``frontend_attributes``
+and the profiler's device ops carry.
+
 Everything is **off by default and near-zero cost when off**: the ambient
 tracer (:func:`current_tracer`) is a process-wide no-op singleton
 (:data:`NULL`) unless a real tracer was installed — explicitly
@@ -39,6 +49,9 @@ import os
 import threading
 import time
 
+from jax.experimental.xla_metadata import set_xla_metadata
+from jax.profiler import TraceAnnotation
+
 #: Environment variable: set to a path to trace the whole process and
 #: export at exit (Chrome trace_event JSON; ``*.jsonl`` for JSONL).
 TRACE_ENV = "REPRO_TRACE"
@@ -47,7 +60,7 @@ TRACE_ENV = "REPRO_TRACE"
 class _Span:
     """One open interval; closes (and records itself) on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "t0", "args", "depth")
+    __slots__ = ("_tracer", "name", "t0", "args", "depth", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -66,6 +79,8 @@ class _Span:
         self._tracer.event(name, **attrs)
 
     def __enter__(self) -> "_Span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = self._tracer._clock()
         stack = self._tracer._stack()
         self.depth = len(stack)
@@ -82,6 +97,7 @@ class _Span:
             "dur": max(0.0, t1 - self.t0), "depth": self.depth,
             "args": self.args,
         })
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -354,6 +370,23 @@ def resolve_tracer(trace) -> Tracer:
         return trace
     raise TypeError(f"trace= must be a Tracer, True, or None; got "
                     f"{type(trace).__name__}")
+
+
+def phase(name: str):
+    """Tag the XLA ops traced inside this context with
+    ``repro_phase=<name>`` (their ``frontend_attributes``).
+
+    The fused loop and the mesh tag the work around the generated kernels
+    by phase: ``entry`` (carry and coefficient pads before the loop),
+    ``window`` (a kernel's operands sliced out of an oversized carry and
+    its outputs cropped), ``group_pad`` (an intermediate padded for the
+    next fuse group), ``update`` (the update rule), ``carry_write`` (the
+    new state written into the carry), ``exit`` (the interior sliced out
+    after the loop) and ``halo`` (the halo refresh around ``ppermute``).
+    Kernels are never tagged: their ``pallas_call`` name says what they
+    are. A tag is set at trace time and changes nothing else in the
+    compiled program."""
+    return set_xla_metadata(repro_phase=name)
 
 
 def _reset_for_tests() -> None:

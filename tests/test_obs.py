@@ -1,4 +1,4 @@
-"""Observability subsystem (repro.obs): tracing, metrics, achieved roofline.
+"""Observability subsystem (repro.obs): tracing and metrics.
 
 Invariants:
 * Spans nest per thread with wall-clock timings; the Chrome export is
@@ -12,7 +12,6 @@ Invariants:
   typed ChainDemoted/PlaneDemoted events when traced.
 * PlanCache counts its own hits/misses; a warm tuned compile is provably
   zero timed runs via the ``tune.timed_runs`` counter.
-* ``measure_achieved`` reports a roofline fraction in (0, inf).
 """
 
 import json
@@ -27,8 +26,7 @@ from repro.core import (PlanCache, TileDemotionWarning, TuneConfig,
                         compile_program)
 from repro.core.frontend import ProgramBuilder
 from repro.obs import (MetricsRegistry, NullTracer, Tracer, current_tracer,
-                       global_metrics, measure_achieved, resolve_tracer,
-                       set_tracer)
+                       global_metrics, resolve_tracer, set_tracer)
 from repro.obs.trace import NULL, TRACE_ENV, _reset_for_tests
 from repro.serve import ServeStats, StencilEngine, StencilRequest
 
@@ -107,10 +105,10 @@ def test_emit_typed_event():
     from repro.obs.events import PlanChosen
     tr = Tracer()
     tr.emit(PlanChosen(program="p", backend="pallas", schedule="stream",
-                       strategy="auto", roofline_fraction=0.5))
+                       strategy="auto", measured_us=0.5))
     ev = tr.events("PlanChosen")[0]
     assert ev["args"]["schedule"] == "stream"
-    assert ev["args"]["roofline_fraction"] == 0.5
+    assert ev["args"]["measured_us"] == 0.5
 
 
 def test_jsonl_export_roundtrip(tmp_path):
@@ -484,44 +482,7 @@ def test_tuned_compile_trace_has_candidates_and_fraction():
     assert tr.events("CacheMiss")       # tuned_plan lookup missed
     chosen = tr.events("PlanChosen")
     assert chosen
-    rf = chosen[0]["args"]["roofline_fraction"]
-    assert rf is not None and 0 < rf < float("inf")
-
-
-def test_tune_record_carries_roofline_fraction():
-    from repro.core import tune_plan
-    timer, _ = fake_timer()
-    res = tune_plan(pw_advection(), GRID, backend="jnp_fused",
-                    update=pw_advection_update(0.1),
-                    config=TuneConfig(steps=2, max_measured=3, timer=timer),
-                    cache=PlanCache(path=None))
-    rf = res.record["roofline_fraction"]
-    assert rf is not None and 0 < rf < float("inf")
-
-
-# ------------------------------------------------------- achieved roofline
-
-def test_measure_achieved_fraction_in_open_interval():
-    p = small_program()
-    fields, scalars, coeffs = data_for(p)
-    ex = compile_program(p, GRID, backend="pallas")
-    tr = Tracer()
-    res = measure_achieved(ex, fields, scalars, coeffs, warmup=1, repeats=1,
-                           tracer=tr)
-    assert 0 < res.achieved_fraction < float("inf")
-    assert res.steps == 1 and res.points == float(np.prod(GRID))
-    assert res.steps_per_sec > 0 and res.bytes_moved > 0
-    d = res.to_dict()
-    assert json.loads(json.dumps(d)) == d
-    sp = tr.spans("roofline.achieved")[0]
-    assert sp["args"]["roofline_fraction"] == res.achieved_fraction
-
-
-def test_achieved_fraction_degenerate_inputs():
-    from repro.obs import achieved_fraction
-    assert achieved_fraction(1.0, 0.0) == 0.0
-    assert achieved_fraction(0.0, 1.0) == 0.0
-    assert achieved_fraction(2.0, 4.0) == 0.5
+    assert chosen[0]["args"]["measured_us"] > 0
 
 
 # ------------------------------------------------------------ serve tracing
@@ -559,7 +520,7 @@ def test_engine_eviction_emits_event_and_counter():
 def test_end_to_end_trace_compile_tune_serve(tmp_path):
     """The acceptance shape of examples/trace_compile.py: one tracer sees
     the tuned compile (>= 2 candidates), the serve batch, a PlanChosen with
-    a finite positive roofline fraction — and exports valid Chrome JSON."""
+    a measured time — and exports valid Chrome JSON."""
     p = pw_advection()
     fields, scalars, coeffs = data_for(p, GRID)
     tr = Tracer()
@@ -575,8 +536,35 @@ def test_end_to_end_trace_compile_tune_serve(tmp_path):
     assert tr.spans("compile")
     assert len(tr.spans("tune.candidate")) >= 2
     assert len(tr.spans("serve.batch")) >= 1
-    rfs = [e["args"]["roofline_fraction"] for e in tr.events("PlanChosen")]
-    assert any(rf is not None and 0 < rf < float("inf") for rf in rfs)
+    assert any(e["args"]["measured_us"] for e in tr.events("PlanChosen"))
     path = str(tmp_path / "e2e.json")
     tr.export_chrome(path)
     _validate_chrome(json.load(open(path)))
+
+
+# ------------------------------------------------------ profiler bridge
+
+def _host_event_names(trace_dir) -> set:
+    from jax._src.profiler import ProfileData
+    (path,) = list(trace_dir.rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    return {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["tracer", "null"])
+def test_span_shows_on_the_profiler_host_plane(tmp_path, enabled):
+    """A span of an enabled tracer is also a ``TraceAnnotation``: it lands
+    on the host plane of the profiler's trace, on the device ops' clock.
+    The disabled tracer writes nothing there."""
+    import jax
+    tr = Tracer() if enabled else NULL
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.bridge_probe"):
+            jax.block_until_ready(jax.numpy.arange(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    assert ("obs.bridge_probe" in _host_event_names(tmp_path)) == enabled
+    assert len(tr.spans("obs.bridge_probe")) == int(enabled)

@@ -3,8 +3,9 @@ attached: Mosaic refuses here what the Pallas interpreter never checks
 (block shapes off the (8, 128) tiling, scoped-VMEM overruns, operands that
 cannot batch under ``vmap``).
 
-Nothing runs: each test lowers and compiles one executable for the
-described chip and reads its HLO.  The kernels are steered to their
+Nothing runs: each test lowers and compiles for the described chip and
+reads the HLO, which also shows the kernels' names and the phase tags on
+the ops around them.  The kernels are steered to their
 compiled (Mosaic) branch by patching :func:`repro.hw.pallas_interpret`,
 which would otherwise pick the interpreter on this CPU backend.  The
 topology is described in a fixture, never at import, so every test worker
@@ -12,7 +13,9 @@ collects the same tests and only the one that runs this file loads the TPU
 compiler.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,15 +24,19 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro import hw
+from repro import hw, obs
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
 from repro.core import compile_program
+from repro.core.dataflow import lower_to_dataflow
 from repro.core.frontend import ProgramBuilder
 from repro.serve import bucket_for, serving_program, wrap_update
 
 GRID_8M = (256, 256, 128)
 GRID_32M = (512, 256, 256)
+#: the benchmark cells' own grids
+CELL_GRIDS = {"pw_advection": (1024, 512, 256),
+              "tracer_advection": (512, 256, 256)}
 PROGRAMS = {"pw_advection": (pw_advection, lambda: pw_advection_update(0.1)),
             "tracer_advection": (tracer_advection, tracer_advection_update)}
 
@@ -84,19 +91,35 @@ def compile_fused(name, grid, sharding, steps=4, **opts):
     return ex, ex.lower(*shapes(p, grid, sharding)).compile()
 
 
+@pytest.fixture(scope="module")
+def compiled_8m(one_chip):
+    """Each program's fused loop at 8M under a schedule (None: the
+    default), compiled once for Mosaic, as on a TPU backend."""
+    cache = {}
+
+    def get(name, schedule):
+        if (name, schedule) not in cache:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(hw, "pallas_interpret", lambda: False)
+                cache[name, schedule] = compile_fused(
+                    name, GRID_8M, one_chip, schedule=schedule)
+        return cache[name, schedule]
+    return get
+
+
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
-def test_block_kernel_compiles_at_8m(name, one_chip, mosaic):
+def test_block_kernel_compiles_at_8m(name, compiled_8m):
     """The default (block) schedule: overlapping windows whose last two
     axes span the padded array, as Mosaic's block rule requires."""
-    ex, compiled = compile_fused(name, GRID_8M, one_chip)
+    ex, compiled = compiled_8m(name, None)
     assert ex.plan.schedule == "block"
     assert tuple(ex.plan.block[1:]) == GRID_8M[1:]
     assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
-def test_stream_fused_loop_compiles_at_8m(name, one_chip, mosaic):
-    ex, compiled = compile_fused(name, GRID_8M, one_chip, schedule="stream")
+def test_stream_fused_loop_compiles_at_8m(name, compiled_8m):
+    ex, compiled = compiled_8m(name, "stream")
     assert ex.plan.schedule == "stream"
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -157,3 +180,96 @@ def test_mesh_stream_loop_compiles_on_2x2(topo, mosaic):
     text = compiled.as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------- kernel names, phase tags
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z0-9\-]*)\(")
+_PHASE = re.compile(r'repro_phase="(\w+)"')
+_KERNEL = 'custom_call_target="tpu_custom_call"'
+#: what carries no phase of its own: the loop's plumbing and the halves
+#: of the async prefetches memory-space assignment adds (slices of a
+#: buffer moved into VMEM, reassembled by a ``ConcatBitcast``)
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "constant", "bitcast",
+             "copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def computations(text: str) -> dict:
+    """Optimized HLO text -> {computation: [(name, shape, opcode, line)]}."""
+    out, cur = {}, None
+    for line in text.split("\n"):
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = out.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and line.strip():
+            name, shape, op = _INSTRUCTION.match(line.strip()).groups()
+            cur.append((name, shape, op, line))
+    return out
+
+
+def untagged_in_loop(text: str) -> list:
+    """Instructions of the fused loop's body that carry no ``repro_phase``
+    and are not plumbing: not a kernel, the loop counter, an async
+    prefetch half, or a constant XLA sank into the loop as a broadcast."""
+    comps = computations(text)
+    out = []
+    for body in set(re.findall(r"body=%([\w.\-]+)", text)):
+        consts = {n for n, _, op, _ in comps[body] if op == "constant"}
+        for name, shape, op, line in comps[body]:
+            if (op in _PLUMBING or _KERNEL in line or _PHASE.search(line)
+                    or 'custom_call_target="ConcatBitcast"' in line
+                    or (op == "add" and shape.startswith("s32[]"))
+                    or (op == "broadcast" and re.search(
+                        r"broadcast\(%([\w.\-]+)\)", line).group(1) in consts)):
+                continue
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("schedule", [None, "stream"], ids=["default", "stream"])
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_fused_loop_ops_carry_a_phase_and_kernels_a_name(name, schedule,
+                                                         compiled_8m):
+    """Every op of the loop body that does work around the kernels carries
+    a ``repro_phase`` tag, and every kernel is named by its fuse group or
+    stream region, uniquely."""
+    ex, compiled = compiled_8m(name, schedule)
+    text = compiled.as_text()
+    assert untagged_in_loop(text) == []
+    kernels = [n.rsplit(".", 1)[0] for c in computations(text).values()
+               for n, _, _, line in c if _KERNEL in line]
+    prefix = "blk_" if ex.plan.schedule == "block" else "str_"
+    assert kernels and all(k.startswith(prefix) for k in kernels)
+    assert len(set(kernels)) == len(kernels)
+    expected = (ex.plan.groups if prefix == "blk_" else lower_to_dataflow(
+        ex.program, ex.plan, GRID_8M).regions)
+    assert len(kernels) == len(expected)
+    tags = {m.group(1) for m in _PHASE.finditer(text)}
+    assert {"entry", "update", "carry_write", "exit"} <= tags
+
+
+def _instructions(text: str) -> list:
+    """(name, opcode, shape) of every instruction in order, each name
+    without the numeric suffixes XLA's uniquifier adds: a tagged
+    ``jnp.pad`` traces its inner jit once per phase, which shifts the
+    numbering but not the program."""
+    return [(re.sub(r"\.\d+", "", n), op, shape)
+            for c in computations(text).values() for n, shape, op, _ in c]
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_phase_tags_change_only_frontend_attributes(name, one_chip, mosaic,
+                                                    monkeypatch):
+    """At the benchmark cell's grid, the compiled fused loop with the tags
+    and without them has the same instructions, in the same order, with
+    the same opcodes and shapes."""
+    grid = CELL_GRIDS[name]
+    _, tagged = compile_fused(name, grid, one_chip)
+    monkeypatch.setattr(obs, "phase", lambda _: contextlib.nullcontext())
+    _, plain = compile_fused(name, grid, one_chip)
+    assert "repro_phase" in tagged.as_text()
+    assert "repro_phase" not in plain.as_text()
+    assert _instructions(tagged.as_text()) == _instructions(plain.as_text())
