@@ -7,22 +7,24 @@ flows through the generated kernel's VMEM windows (see kernels/stencil3d.py).
 
 The XLA ops around the kernels carry a ``repro_phase`` tag
 (:func:`repro.obs.phase`): ``entry`` and ``exit`` around the fused loop,
-``group_pad`` for the pads that feed a group, ``update`` and
-``carry_write`` in each step.
+``group_pad`` for the pads that feed a group, ``update`` (the rule's
+fields left on XLA) and ``carry_write`` in each step.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Mapping
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jcore
 
 from .. import obs
 from ..kernels.stencil3d import build_group_call
 from . import boundary as bc
-from .ir import Program
-from .schedule import DataflowPlan, TimeLoopSpec, adapt_update
+from .ir import FieldRole, Program
+from .schedule import DataflowPlan, TimeLoopSpec, adapt_update, plane_local
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float64": jnp.float64}
 
@@ -50,6 +52,8 @@ def _run_groups(p: Program, calls, svec, pc_per_call, resolve_input,
     (pad None) or an oversized persistent buffer with its actual padding,
     which the kernel slices its window out of via ``input_pad``.
     ``origin`` is the shard's global offset under a mesh (None locally).
+    Returns the program outputs, and the new persistent fields of any call
+    that computes them in an update epilogue.
     """
     env: dict = {}
     outputs: dict = {}
@@ -60,11 +64,236 @@ def _run_groups(p: Program, calls, svec, pc_per_call, resolve_input,
             if actual is not None:
                 ipad[f] = actual
         res = call(padded, svec, pc, input_pad=ipad or None, origin=origin)
-        env.update(res)
         for f, v in res.items():
-            if p.fields[f].role.value == "output":
+            role = p.fields[f].role
+            if role != FieldRole.INPUT:     # a new field is no group's input
+                env[f] = v
+            if role != FieldRole.TEMP:      # outputs, and new fields
                 outputs[f] = v
     return outputs
+
+
+def _sub_jaxprs(params):
+    """The jaxprs an op calls, each with its constants."""
+    for v in params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, jcore.ClosedJaxpr):
+                yield x.jaxpr, x.consts
+            elif isinstance(x, jcore.Jaxpr):
+                yield x, ()
+
+
+def _on_tile(eqn, shape) -> bool:
+    """Whether ``eqn`` can run on one tile of arrays traced at ``shape``:
+    every value it touches is a scalar or ``shape``-sized, it shrinks no
+    array to a scalar, the only shape it names is a broadcast's, and what
+    it calls (``jit``, custom derivatives) is one such jaxpr taking its
+    operands as they are and closing over nothing.  Whether the op is
+    element-wise is the rule's contract (:func:`~.schedule.plane_local`),
+    not checked here."""
+    ins = [v.aval.shape for v in eqn.invars]
+    outs = [v.aval.shape for v in eqn.outvars]
+    if any(s not in ((), shape) for s in ins + outs):
+        return False
+    if () in outs and shape in ins:
+        return False
+    subs = list(_sub_jaxprs(eqn.params))
+    if subs:
+        if len(subs) != 1:
+            return False
+        (sub, consts), = subs
+        return (not consts and not sub.constvars
+                and [v.aval for v in sub.invars]
+                == [v.aval for v in eqn.invars]
+                and all(_on_tile(e, shape) for e in sub.eqns))
+    return (eqn.primitive.name == "broadcast_in_dim"
+            or not any(isinstance(v, tuple) and tuple(v) == shape
+                       for v in eqn.params.values()))
+
+
+def _eval_on_tile(eqns, env, outvars, tile):
+    """Evaluate ``eqns`` (each :func:`_on_tile`) on tile-shaped values:
+    a broadcast goes to ``tile``, a call is evaluated inline."""
+    def read(v):
+        return v.val if isinstance(v, jcore.Literal) else env[v]
+
+    for e in eqns:
+        ins = [read(v) for v in e.invars]
+        subs = list(_sub_jaxprs(e.params))
+        if subs:
+            sub, _ = subs[0]
+            outs = _eval_on_tile(sub.eqns, dict(zip(sub.invars, ins)),
+                                 sub.outvars, tile)
+        elif e.primitive.name == "broadcast_in_dim":
+            outs = [jnp.broadcast_to(ins[0], tile)]
+        else:
+            outs = e.primitive.bind(*ins, **e.params)
+            outs = outs if e.primitive.multiple_results else [outs]
+        env.update(zip(e.outvars, outs))
+    return [read(v) for v in outvars]
+
+
+@dataclasses.dataclass(frozen=True)
+class FieldUpdate:
+    """What the update rule's new value of one persistent field reads."""
+    fields: frozenset       # persistent fields, at this step
+    outputs: frozenset      # program outputs of this step
+    alias: str | None       # the output the new value is, exactly
+    on_tile: bool           # a tile can compute it (every op _on_tile)
+
+
+class RuleTrace:
+    """One trace of an adapted update rule on interior-shaped stand-ins:
+    ``kept``, the persistent fields it returns as themselves (or not at
+    all), and ``changed``, a :class:`FieldUpdate` per other field.
+    :meth:`tile_rule` hands a kernel epilogue the ops of some of those."""
+
+    def __init__(self, update, persistent, outputs, scalars, shape, dtype):
+        persistent, outputs = list(persistent), list(outputs)
+        scalars = list(scalars)
+        shape = tuple(int(g) for g in shape)
+        unknown, returned = [], []
+
+        class Scalars(dict):
+            # a scalar that is not the program's rides in only at run
+            # time: no kernel has it, so every change then stays on XLA
+            def __missing__(self, name):
+                unknown.append(name)
+                return jnp.zeros((), jnp.float32)
+
+            def get(self, name, default=None):
+                return self[name]
+
+        def flat(f, o, s):
+            res = update(dict(zip(persistent, f)), dict(zip(outputs, o)),
+                         Scalars(zip(scalars, s)))
+            returned[:] = [k for k in persistent if k in res]
+            return [jnp.asarray(res[k]) for k in returned]
+
+        arr = jax.ShapeDtypeStruct(shape, dtype)
+        jaxpr = jax.make_jaxpr(flat)(
+            [arr] * len(persistent), [arr] * len(outputs),
+            [jax.ShapeDtypeStruct((), jnp.float32)] * len(scalars)).jaxpr
+        self._jaxpr = jaxpr
+        self._names = dict(zip(jaxpr.invars,
+                               [("field", f) for f in persistent]
+                               + [("output", o) for o in outputs]
+                               + [("scalar", s) for s in scalars]))
+        self._out = dict(zip(returned, jaxpr.outvars))
+        producer = {v: i for i, e in enumerate(jaxpr.eqns)
+                    for v in e.outvars}
+        self._ops, self.changed = {}, {}
+        for f, out in self._out.items():
+            kind, name = (self._names.get(out, (None, None))
+                          if isinstance(out, jcore.Var) else (None, None))
+            if (kind, name) == ("field", f):
+                continue                # returned as itself: kept
+            reads = {"field": set(), "output": set(), "scalar": set()}
+            ops, stack, seen = set(), [out], set()
+            ok = not unknown and out.aval.shape == shape
+            while stack:
+                v = stack.pop()
+                if not isinstance(v, jcore.Var) or v in seen:
+                    continue
+                seen.add(v)
+                if v in self._names:
+                    reads[self._names[v][0]].add(self._names[v][1])
+                elif v in producer:
+                    e = jaxpr.eqns[producer[v]]
+                    ok = ok and _on_tile(e, shape)
+                    ops.add(producer[v])
+                    stack.extend(e.invars)
+                else:                   # an array the rule closes over
+                    ok = False
+            self._ops[f] = ops
+            self.changed[f] = FieldUpdate(
+                fields=frozenset(reads["field"]),
+                outputs=frozenset(reads["output"]),
+                alias=name if kind == "output" else None, on_tile=ok)
+        self.kept = [f for f in persistent if f not in self.changed]
+
+    def tile_rule(self, fields):
+        """``rule(held, outputs, scalars, tile) -> {field: value}``: the
+        rule's ops for ``fields`` alone, on one tile.  ``rule.reads``
+        names the persistent fields, outputs and scalars they take, which
+        the caller hands in at the tile's own points."""
+        fields = list(fields)
+        ops = sorted(set().union(*(self._ops[f] for f in fields)))
+        eqns = [self._jaxpr.eqns[i] for i in ops]
+        outvars = [self._out[f] for f in fields]
+        used = {v for v in [*outvars, *(v for e in eqns for v in e.invars)]
+                if isinstance(v, jcore.Var)}
+        takes = {v: kn for v, kn in self._names.items() if v in used}
+
+        def rule(held, outputs, scalars, tile):
+            given = {"field": held, "output": outputs, "scalar": scalars}
+            env = {v: given[k][n] for v, (k, n) in takes.items()}
+            return dict(zip(fields, _eval_on_tile(eqns, env, outvars, tile)))
+
+        rule.reads = {k: sorted(n for kk, n in takes.values() if kk == k)
+                      for k in ("field", "output", "scalar")}
+        return rule
+
+
+def place_update(p: Program, calls, persistent, grid_shape, dtype, update,
+                 local: bool = True):
+    """Decide where the fused loop computes each persistent field's next
+    value, from one trace of the adapted ``update`` (:class:`RuleTrace`),
+    whether it keeps the element-wise contract (``local``, from
+    :func:`~.schedule.plane_local`) and the fuse groups.
+
+    Returns ``(placement, alias, calls)``.  ``placement[f]`` is ``"kept"``
+    for a field the rule returns unchanged (it stays in the carry,
+    untouched); ``"kernel"`` for one a kernel computes — the output it
+    equals (``alias[f]``), or an update epilogue in the first block group
+    that produces every output and holds every field its new value reads
+    (that call is rebuilt ``with_update``, running only the rule's ops for
+    it); and ``"xla"`` for the rest, which the loop body computes by
+    calling the rule on the whole interiors.  Where no field stays on XLA
+    the rule reads no output in the loop body, so an output that only
+    hosted updates read is no longer stored.  A rule that is not
+    element-wise keeps every change on XLA.
+    """
+    produced = [o for c in calls for o in c.group_outputs
+                if p.fields[o].role == FieldRole.OUTPUT]
+    try:
+        trace = RuleTrace(update, persistent, produced, p.scalars,
+                          grid_shape, dtype)
+    except (jax.errors.ConcretizationTypeError,
+            jax.errors.TracerArrayConversionError,
+            jax.errors.TracerIntegerConversionError):
+        # a rule that needs concrete values (a caller's jit=False loop
+        # handed it Python scalars) is traced into the loop body alone
+        return {f: "xla" for f in persistent}, {}, list(calls)
+    placement = {f: "kept" for f in trace.kept}
+    alias, hosted = {}, {}
+    for f, u in trace.changed.items():
+        placement[f] = "xla"
+        if not local:
+            continue
+        if u.alias is not None:
+            alias[f], placement[f] = u.alias, "kernel"
+        elif u.on_tile:
+            host = next((i for i, c in enumerate(calls)
+                         if hasattr(c, "with_update")
+                         and u.outputs <= set(c.group_outputs)
+                         and u.fields <= set(c.group_inputs)), None)
+            if host is not None:
+                hosted.setdefault(host, []).append(f)
+                placement[f] = "kernel"
+    # an output stays stored while a later group, an alias or the rule on
+    # XLA (which reads every output) needs it
+    needed = {f for c in calls for f in c.group_inputs} | set(alias.values())
+    if "xla" in placement.values():
+        needed |= set(produced)
+    calls = list(calls)
+    for i, fields in hosted.items():
+        drop = [o for o in calls[i].group_outputs
+                if p.fields[o].role == FieldRole.OUTPUT and o not in needed]
+        calls[i] = calls[i].with_update(update=trace.tile_rule(fields),
+                                        update_fields=fields,
+                                        drop_outputs=drop)
+    return {f: placement[f] for f in persistent}, alias, calls
 
 
 def _scalar_vec(p: Program, scalars):
@@ -116,7 +345,9 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     The carry of a ``lax.fori_loop`` holds one *pre-padded* persistent buffer
     per program input field, sized by ``spec.field_pad`` so every consuming
     fuse group can slice its window geometry straight out of it (the kernel's
-    ``input_pad`` path).  Halo slabs follow each field's boundary: zero
+    ``input_pad`` path).  The update rule runs in the kernels' epilogues
+    where it can (see :func:`time_loop_from_calls`).  Halo slabs follow
+    each field's boundary: zero
     slabs never change, so writing the back buffer each step touches only
     the interior — either scattered in place (``carry_write="inplace"``) or
     rebuilt as one fused interior-plus-constant-halo write (``"repad"``,
@@ -147,8 +378,22 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
     list advancing ``spec.steps % chain`` steps — runs once after it,
     slicing its (shallower) windows out of the same carry via
     ``input_pad``.
+
+    With plain kernels the update rule runs where :func:`place_update`
+    puts it, decided once at build time from one trace of the rule: a
+    field the rule returns unchanged stays in the carry untouched (no
+    interior slice, no re-pad); a field whose new value is a kernel's
+    output, or which a block kernel can compute in its epilogue from what
+    it holds (the rule's own ops for that field, on the kernel's tile),
+    comes out of that kernel and is only written back; only the rest is
+    computed by XLA on the whole interiors.  Computing on a tile is exact
+    because the rule is element-wise (the contract of
+    :func:`adapt_update`); a rule marked ``_plane_local = False`` (the
+    serving layer's bucket refresh) breaks it, and :func:`plane_local`
+    keeps all its changes on XLA.  Where each field went is exposed as
+    ``update_placement`` on the returned function.
     """
-    update = adapt_update(update)
+    raw_update, update = update, adapt_update(update)
     ndim = p.ndim
     fpad = spec.field_pad
     bnd = p.boundaries()
@@ -163,6 +408,14 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                                int(fpad[f][a, 0]) + grid_shape[a])
                          for a in range(ndim))
                 for f in spec.persistent}
+    if getattr(calls[0], "returns_fields", False):
+        # a temporally-blocked chain applies the whole rule in-kernel
+        placement, alias = {f: "kernel" for f in spec.persistent}, {}
+    else:
+        placement, alias, calls = place_update(
+            p, calls, spec.persistent, grid_shape, dtype, update,
+            local=plane_local(raw_update))
+    on_xla = [f for f in spec.persistent if placement[f] == "xla"]
 
     def refill(f, x):
         # halo slabs per the field's boundary; the lane-alignment slab
@@ -204,14 +457,22 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                            input_pad={f: fpad[f] for f in call.group_inputs})
             else:
                 outputs = _run_groups(p, calls_, svec, pc_, resolve)
-                with obs.phase("update"):
-                    cur = {f: carry[f][interior[f]] for f in spec.persistent}
-                    new = dict(cur)
-                    new.update(update(cur, outputs, scalars))
+                # kernel-computed fields come back among the outputs
+                new = {f: outputs[alias.get(f, f)] for f in spec.persistent
+                       if placement[f] == "kernel"}
+                if on_xla:
+                    with obs.phase("update"):
+                        cur = {f: carry[f][interior[f]]
+                               for f in spec.persistent}
+                        merged = dict(cur)
+                        merged.update(update(cur, outputs, scalars))
+                        new.update({f: merged[f] for f in on_xla})
             out = {}
             with obs.phase("carry_write"):
                 for f in spec.persistent:
-                    if spec.carry_write == "inplace" and bnd[f] == "zero":
+                    if placement[f] == "kept":
+                        out[f] = carry[f]   # unchanged: stays as it is
+                    elif spec.carry_write == "inplace" and bnd[f] == "zero":
                         # zero halos never change: scatter the interior only
                         out[f] = carry[f].at[interior[f]].set(
                             jnp.asarray(new[f], dtype=dtype))
@@ -230,4 +491,5 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
         with obs.phase("exit"):
             return {f: carry[f][interior[f]] for f in spec.persistent}
 
+    run.update_placement = placement
     return run

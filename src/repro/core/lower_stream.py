@@ -587,7 +587,8 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     """Fused ``lax.fori_loop`` time loop over streamed sweeps: the carry
     holds pre-padded persistent fields (no alignment slab — streams never
     tile), each step runs every region's shift-register sweep, and the
-    update rule is traced once.
+    update rule is traced into the loop once; fields it never changes stay
+    in the carry untouched (:func:`~repro.core.lower_pallas.place_update`).
 
     With an effective ``time_tile = T > 1`` on the graph, each loop
     iteration runs ONE chained sweep that advances T full steps (all T

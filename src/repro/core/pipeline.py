@@ -34,7 +34,7 @@ from .ir import Program
 from .passes import infer_halo
 from .schedule import (DataflowPlan, ShardSpec, TimeLoopSpec, auto_plan,
                        make_shard_spec, normalize_mesh_axes, plan_time_loop,
-                       shard_local_grid)
+                       plane_local, shard_local_grid)
 
 _BACKENDS = ("pallas", "jnp_fused", "jnp_naive")
 
@@ -371,7 +371,7 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
         _check_schedule(backend, plan.schedule)
         metrics.counter("compile.stream_lowerings").inc()
         update_demote = None
-        if plan.time_tile > 1 and not getattr(update, "_plane_local", True):
+        if plan.time_tile > 1 and not plane_local(update):
             # chained stages run the update inside the kernel on resident
             # planes; an update that reads the whole grid (e.g. the serving
             # layer's bucket refresh) has no plane-local form, so the chain
@@ -462,6 +462,14 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
     fn = jax.jit(raw) if jit else raw
     if steps is not None:
         metrics.counter("compile.fused_loops").inc()
+        placement = getattr(raw, "update_placement", None)
+        if placement is not None:
+            # where the loop computes each field's next value (the sharded
+            # and jnp lowerings update every field on XLA and do not say)
+            time_spec = dataclasses.replace(time_spec,
+                                            update_placement=placement)
+            for where, n in time_spec.update_counts().items():
+                metrics.counter(f"compile.update_fields.{where}").inc(n)
     if tracer.enabled:
         eff_tt = (plan.stream.time_tile if plan.stream is not None
                   else plan.time_tile)

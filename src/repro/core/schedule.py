@@ -377,6 +377,13 @@ UPDATE_SIGNATURES = ("update(fields, outputs)",
                      "update(fields, outputs, scalars)")
 
 
+def plane_local(update) -> bool:
+    """Whether ``update`` keeps the element-wise contract of
+    :func:`adapt_update`, so a kernel may apply it to the part of the grid
+    it holds: true unless the rule says ``_plane_local = False``."""
+    return getattr(update, "_plane_local", True)
+
+
 def adapt_update(update):
     """Normalise a time-loop update rule to ``fn(fields, outputs, scalars)``.
 
@@ -390,6 +397,15 @@ def adapt_update(update):
     * ``update(fields, outputs, scalars) -> fields`` — additionally receives
       the runtime scalars mapping, for rules that need traced values inside
       the loop (a traced ``dt``, the serving layer's bucket-size scalars).
+
+    The rule is *element-wise*: a field's new value at a point depends on
+    the fields and outputs at that point alone (and on scalars).  That
+    contract lets the stream chain apply it plane by plane and the Pallas
+    fused loop apply it in a block kernel's epilogue, tile by tile.  A rule
+    that breaks it says so with ``_plane_local = False`` (the serving
+    layer's bucket refresh, which gathers across whole axes), which
+    :func:`plane_local` reads for both: such a rule is always traced into
+    the loop body on XLA, over the whole interiors.
 
     Every time-loop lowering routes the rule through here, so both
     signatures work everywhere.  Idempotent: adapting an already-adapted
@@ -563,12 +579,19 @@ class TimeLoopSpec:
 
     The loop carry holds one persistent, halo-padded buffer per program
     input field; each step reads stencil windows straight out of the carry
-    (no per-step ``jnp.pad``), and the traced update rule writes the new
-    interior back in place.  Per fuse group, ``double_buffer`` assigns a
-    front/back slot pair per persistent field: the group reads the front
-    slot, the update writes the back slot, and parity swaps every step —
-    the functional lowering realises the swap through XLA buffer donation
-    on the loop carry.
+    (no per-step ``jnp.pad``), and the new interior of each field the
+    update rule changes is written back in place.  Where that rule runs is
+    the lowering's choice, recorded in ``update_placement``: the Pallas
+    loop leaves a field the rule returns unchanged in the carry, and has a
+    block kernel compute a changed one in its epilogue wherever the rule
+    keeps the element-wise contract of :func:`adapt_update` (as
+    :func:`plane_local` reads its ``_plane_local`` flag) and one group
+    holds what the field reads, tracing the rest into the loop body on
+    XLA.  Per fuse
+    group, ``double_buffer`` assigns a front/back slot pair per persistent
+    field: the group reads the front slot, the update writes the back
+    slot, and parity swaps every step — the functional lowering realises
+    the swap through XLA buffer donation on the loop carry.
     """
 
     steps: int
@@ -595,13 +618,27 @@ class TimeLoopSpec:
     # distributed layout when the loop runs under shard_map; None = local.
     # With a shard, every extent in this spec is per-shard (local_grid).
     shard: ShardSpec | None = None
+    # where the lowering computes each field's next value, as it reports
+    # it (None where it does not): "kernel" (a kernel's epilogue or output),
+    # "kept" (unchanged, left in the carry) or "xla" (the loop body)
+    update_placement: dict | None = None
+
+    def update_counts(self) -> dict:
+        """How many persistent fields each place updates (all zero where
+        the lowering reports no placement)."""
+        where = list((self.update_placement or {}).values())
+        return {k: where.count(k) for k in ("kernel", "kept", "xla")}
 
     def describe(self) -> str:
         bufs = ", ".join(f"{f}:{a}/{b}" for f, (a, b)
                          in self.double_buffer.items())
+        placed = ""
+        if self.update_placement is not None:
+            placed = ", update=[" + ", ".join(
+                f"{k}:{n}" for k, n in self.update_counts().items()) + "]"
         return (f"time_loop(steps={self.steps}, "
                 f"persistent=[{','.join(self.persistent)}], "
-                f"double_buffer=[{bufs}])")
+                f"double_buffer=[{bufs}]{placed})")
 
 
 def plan_time_loop(p: Program, plan: DataflowPlan, grid: Sequence[int],

@@ -31,6 +31,7 @@ elsewhere (:func:`repro.hw.pallas_interpret` decides).
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
@@ -57,7 +58,9 @@ def _window_spec(shape, index_map):
 
 def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                      grid_shape: Sequence[int], dtype=jnp.float32,
-                     global_extent: Sequence[int] | None = None):
+                     global_extent: Sequence[int] | None = None,
+                     update=None, update_fields: Sequence[str] = (),
+                     drop_outputs: Sequence[str] = ()):
     """Build a callable(padded_inputs, scalars, coeffs, origin) -> outputs.
 
     ``padded_inputs`` must be padded by ``pad_lo``/``pad_hi`` (exposed on the
@@ -65,6 +68,17 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     (defaults to zeros); ``global_extent`` the global domain size (defaults
     to ``grid_shape``) — together they define the out-of-domain mask for
     margin-extended recompute.
+
+    With ``update`` (the fused loop's rule cut to ``update_fields``:
+    :meth:`~repro.core.lower_pallas.RuleTrace.tile_rule`) the kernel ends
+    in an epilogue that applies it to its tile — the centres of its input
+    windows, its outputs at margin 0 and the scalars in SMEM, as
+    ``update.reads`` names them — and stores the new values of those
+    persistent fields beside its outputs, returned under the fields'
+    names.  The fused loop asks for that only where the group holds
+    everything they read (``with_update`` on the returned callable
+    rebuilds the same group with an epilogue).  ``drop_outputs`` names
+    outputs only the rule reads, which the kernel then never stores.
     """
     ndim = p.ndim
     gh: GroupHalo = infer_halo(p, group)
@@ -87,6 +101,10 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     n_scalars = len(p.scalars)
     scalar_index = {s: i for i, s in enumerate(p.scalars)}
     out_names = [op.out for op in ops if op.out in set(gh.group_outputs)]
+    # what the kernel stores: its outputs, less those only the update rule
+    # read, then the fields the rule's epilogue computes
+    store_names = ([f for f in out_names if f not in set(drop_outputs)]
+                   + list(update_fields))
     coeff_axis = {c: p.coeffs[c] for c in gh.group_coeffs}
     # a coefficient vector lies along its own axis (extent 1 on the others),
     # resident whole: the kernel slices it without a relayout, and a tile's
@@ -102,6 +120,11 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                        and p.fields[op.out].boundary != "periodic")
               for op in ops}
 
+    def centre(m):
+        """The tile's own points in a value computed at margin ``m``."""
+        return tuple(slice(int(m[ax, 0]), int(m[ax, 0]) + block[ax])
+                     for ax in range(ndim))
+
     def kernel(*refs):
         i = 0
         s_ref = refs[i]; i += 1                      # (1, n) scalars, SMEM
@@ -110,7 +133,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         i += len(gh.group_inputs)
         coeff_refs = {c: refs[i + k] for k, c in enumerate(gh.group_coeffs)}
         i += len(gh.group_coeffs)
-        out_refs = {f: refs[i + k] for k, f in enumerate(out_names)}
+        out_refs = {f: refs[i + k] for k, f in enumerate(store_names)}
 
         # single load_data stage: every window loads exactly once
         windows = {f: r[...] for f, r in in_refs.items()}
@@ -171,9 +194,21 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 res = jnp.where(mask, res, jnp.asarray(0, dtype=dtype))
             results[op.out] = res
             if op.out in out_refs:
-                center = tuple(slice(int(m[ax, 0]), int(m[ax, 0]) + block[ax])
-                               for ax in range(ndim))
-                out_refs[op.out][...] = res[center]
+                out_refs[op.out][...] = res[centre(m)]
+
+        if update_fields:
+            # the update rule on this tile: every value it reads for these
+            # fields is here, at the tile's own points
+            reads = update.reads
+            held = {f: windows[f][centre(gh.input_halo)]
+                    for f in reads["field"]}
+            outs = {f: results[f][centre(margins[f])]
+                    for f in reads["output"]}
+            new = update(held, outs, {s: scalar(s) for s in reads["scalar"]},
+                         block)
+            for f in update_fields:
+                out_refs[f][...] = jnp.broadcast_to(
+                    jnp.asarray(new[f], dtype=dtype), block)
 
     def window_map(*idx):
         return tuple(idx[a] * block[a] for a in range(ndim))
@@ -187,15 +222,16 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         in_specs.append(pl.BlockSpec(coeff_shape[c],
                                      lambda *idx: (0,) * ndim))
     out_specs = tuple(pl.BlockSpec(block, lambda *idx: tuple(idx))
-                      for _ in out_names)
-    out_shape = tuple(jax.ShapeDtypeStruct(padded_out, dtype) for _ in out_names)
+                      for _ in store_names)
+    out_shape = tuple(jax.ShapeDtypeStruct(padded_out, dtype)
+                      for _ in store_names)
 
     call = pl.pallas_call(
         kernel,
         grid=tiles,
         in_specs=in_specs,
-        out_specs=out_specs if len(out_names) > 1 else out_specs[0],
-        out_shape=out_shape if len(out_names) > 1 else out_shape[0],
+        out_specs=out_specs if len(store_names) > 1 else out_specs[0],
+        out_shape=out_shape if len(store_names) > 1 else out_shape[0],
         compiler_params=hw.pallas_compiler_params(("parallel",) * ndim),
         interpret=hw.pallas_interpret(),
         # the group's outputs name the kernel in the HLO and the trace
@@ -236,15 +272,19 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
             for c in gh.group_coeffs:
                 args.append(padded_coeffs[c].reshape(coeff_shape[c]))
         res = call(*args)
-        if len(out_names) == 1:
+        if len(store_names) == 1:
             res = (res,)
         with obs.phase("window"):
-            return {f: r[crop] for f, r in zip(out_names, res)}
+            return {f: r[crop] for f, r in zip(store_names, res)}
 
     # geometry for orchestrators (lower_pallas pads with zeros; distribute
     # pads via halo exchange)
     run.group_inputs = gh.group_inputs
     run.group_outputs = out_names
+    run.update_fields = tuple(update_fields)
+    run.with_update = functools.partial(
+        build_group_call, p, group, block, grid_shape, dtype=dtype,
+        global_extent=global_extent)
     run.group_coeffs = gh.group_coeffs
     run.coeff_axis = coeff_axis
     run.block = block

@@ -5,9 +5,15 @@ Invariants:
   iterations to 1e-5 on every backend (pallas interpret, jnp_fused,
   jnp_naive), including programs with scalars and per-level coefficients.
 * The whole loop is one compiled program: the user's update rule is traced
-  exactly once regardless of N, and repeated calls hit the jit cache.
+  into it exactly once regardless of N, and repeated calls hit the jit
+  cache.
+* The pallas loop leaves a field the rule never changes in the carry, and
+  computes a changed one in the kernel that holds what it reads, where the
+  rule is plane-local; the rest stays on XLA, with the same numbers.
 * Both carry-write styles ("repad" rebuild and "inplace" scatter) agree.
 """
+
+import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +22,10 @@ import pytest
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
 from repro.core import compile_program, plan_time_loop, run_time_loop
-from repro.core.schedule import auto_plan
+from repro.core.schedule import adapt_update, auto_plan
+from repro.obs.metrics import global_metrics
+from repro.serve import bucket_for, serving_program, wrap_update
+from repro.serve.bucket import embed_request
 
 BACKENDS = ["jnp_naive", "jnp_fused", "pallas"]
 
@@ -125,8 +134,10 @@ def test_steps_one_equals_single_step_plus_update():
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_update_traced_once_per_compile(backend):
     """The loop lowers into ONE jitted program: the update rule is traced
-    exactly once for steps=5 (a host-driven loop traces/dispatches it per
-    step), and a second executable call hits the jit cache (no retrace)."""
+    into it exactly once for steps=5 (a host-driven loop traces/dispatches
+    it per step), and a second executable call hits the jit cache (no
+    retrace).  On pallas that one trace is the build-time read that
+    places the updates: the kernels then run the rule's traced ops."""
     grid = (6, 6, 64)
     p = pw_advection()
     fields, scalars, coeffs = pw_data(grid)
@@ -154,6 +165,113 @@ def test_partial_update_keeps_untouched_fields():
     for f in ("un", "vn", "wn", "e3t", "msk"):
         np.testing.assert_array_equal(np.asarray(got[f]),
                                       np.asarray(fields[f]))
+
+
+# ------------------------------------------------- where the update runs
+
+def on_xla(rule):
+    """``rule`` marked as not plane-local: every change it makes is
+    computed by XLA in the loop body, as before kernels hosted updates."""
+    def xla_rule(fields, outputs, scalars):
+        return adapt_update(rule)(fields, outputs, scalars)
+    xla_rule._takes_scalars = True
+    xla_rule._plane_local = False
+    return xla_rule
+
+
+def pw_rolled_w(dt=0.1):
+    """PW's rule with ``w`` read through a roll, which is not point-wise."""
+    def update(fl, out):
+        return {"u": fl["u"] + dt * out["su"], "v": fl["v"] + dt * out["sv"],
+                "w": fl["w"] + dt * jnp.roll(out["sw"], 1, axis=0)}
+    return update
+
+
+def pw_masked_w(grid, dt=0.1):
+    """PW's rule with ``w`` scaled by a grid-shaped array the rule closes
+    over, which no kernel holds; ``u`` and ``v`` as PW's."""
+    mask = (np.arange(np.prod(grid)).reshape(grid) % 3 > 0).astype(np.float32)
+
+    def update(fl, out):
+        return {"u": fl["u"] + dt * out["su"], "v": fl["v"] + dt * out["sv"],
+                "w": fl["w"] + dt * mask * out["sw"]}
+    return update
+
+
+def tiled(p, grid, block):
+    """Options for ``p``'s default plan at ``grid`` cut into ``block``
+    tiles, so an epilogue sees less than the whole interior."""
+    plan = auto_plan(p, grid, backend="pallas")
+    return {"plan": dataclasses.replace(plan, block=block)}
+
+
+def tracer_unheld(fl, out):
+    """``un`` reads ``ta``, whose group does not hold ``un`` once the plan
+    splits per field; ``msk`` reads only what that group holds."""
+    return {"t": out["ta"], "un": fl["un"] + 0.01 * out["ta"],
+            "msk": fl["msk"] + 0.01 * out["ta"]}
+
+
+def serve_case():
+    sp = serving_program(pw_advection())
+    spec = bucket_for(sp, (6, 6, 20))
+    fields, scalars, coeffs = pw_data(spec.grid)
+    data = embed_request(sp, spec, fields, scalars, coeffs)
+    return (sp, spec.bucket, data,
+            wrap_update(sp, spec, pw_advection_update(0.1)), {})
+
+
+G_PW, G_TR = (8, 8, 128), (6, 8, 64)
+#: case -> (program, grid, data, rule, compile options), and the fields
+#: updated (in a kernel, kept in the carry, on XLA)
+PLACEMENT_CASES = {
+    "pw-zero": (lambda: (pw_advection("zero"), G_PW, pw_data(G_PW),
+                         pw_advection_update(0.1), {}), (3, 0, 0)),
+    "pw-periodic": (lambda: (pw_advection("periodic"), G_PW, pw_data(G_PW),
+                             pw_advection_update(0.1), {}), (3, 0, 0)),
+    "tracer-zero": (lambda: (tracer_advection("zero"), G_TR,
+                             tracer_data(G_TR), tracer_advection_update(),
+                             {}), (1, 5, 0)),
+    "tracer-periodic": (lambda: (tracer_advection("periodic"), G_TR,
+                                 tracer_data(G_TR),
+                                 tracer_advection_update(), {}), (1, 5, 0)),
+    "pw-not-plane-local": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
+                                    on_xla(pw_advection_update(0.1)), {}),
+                           (0, 0, 3)),
+    "serve-wrapped": (serve_case, (0, 0, 3)),
+    "pw-not-pointwise": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
+                                  pw_rolled_w(), {}), (2, 0, 1)),
+    "pw-closed-over-array": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
+                                      pw_masked_w(G_PW),
+                                      tiled(pw_advection(), G_PW,
+                                            (4, 4, 128))), (2, 0, 1)),
+    "tracer-unheld-field": (lambda: (tracer_advection(), G_TR,
+                                     tracer_data(G_TR), tracer_unheld,
+                                     {"strategy": "per_field"}), (2, 3, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLACEMENT_CASES))
+def test_update_placement_matches_xla_update(case):
+    """The fused block loop puts each field's update in a kernel, in the
+    carry or on XLA, as the rule's trace, its ``_plane_local`` flag and
+    the fuse groups allow; the time spec and the compile counters say so,
+    and the answer matches the loop that updates every changed field on
+    XLA within float32 rounding."""
+    make, want = PLACEMENT_CASES[case]
+    p, grid, (fields, scalars, coeffs), rule, opts = make()
+    names = [f"compile.update_fields.{k}" for k in ("kernel", "kept", "xla")]
+    before = [global_metrics().counter(n).value for n in names]
+    ex = compile_program(p, grid, steps=3, update=rule, **opts)
+    after = [global_metrics().counter(n).value for n in names]
+    assert tuple(ex.time_spec.update_counts().values()) == want
+    assert tuple(a - b for a, b in zip(after, before)) == want
+    got = ex(fields, scalars, coeffs)
+    ref = compile_program(p, grid, steps=3, update=on_xla(rule),
+                          **opts)(fields, scalars, coeffs)
+    for f in ref:
+        np.testing.assert_allclose(np.asarray(got[f]), np.asarray(ref[f]),
+                                   rtol=1e-6, atol=1e-6, err_msg=f)
 
 
 # ------------------------------------------------------------ plan layer

@@ -107,6 +107,21 @@ def compiled_8m(one_chip):
     return get
 
 
+@pytest.fixture(scope="module")
+def compiled_cells(one_chip):
+    """Each program's fused loop at its benchmark cell's grid, under the
+    default options, compiled once for Mosaic, as on a TPU backend."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(hw, "pallas_interpret", lambda: False)
+                cache[name] = compile_fused(name, CELL_GRIDS[name], one_chip)
+        return cache[name]
+    return get
+
+
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
 def test_block_kernel_compiles_at_8m(name, compiled_8m):
     """The default (block) schedule: overlapping windows whose last two
@@ -248,7 +263,9 @@ def test_fused_loop_ops_carry_a_phase_and_kernels_a_name(name, schedule,
         ex.program, ex.plan, GRID_8M).regions)
     assert len(kernels) == len(expected)
     tags = {m.group(1) for m in _PHASE.finditer(text)}
-    assert {"entry", "update", "carry_write", "exit"} <= tags
+    assert {"entry", "carry_write", "exit"} <= tags
+    # the loop body traces the rule only for fields it updates on XLA
+    assert ("update" in tags) == (ex.time_spec.update_counts()["xla"] > 0)
 
 
 def _instructions(text: str) -> list:
@@ -262,14 +279,81 @@ def _instructions(text: str) -> list:
 
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
 def test_phase_tags_change_only_frontend_attributes(name, one_chip, mosaic,
-                                                    monkeypatch):
+                                                    monkeypatch,
+                                                    compiled_cells):
     """At the benchmark cell's grid, the compiled fused loop with the tags
     and without them has the same instructions, in the same order, with
     the same opcodes and shapes."""
     grid = CELL_GRIDS[name]
-    _, tagged = compile_fused(name, grid, one_chip)
+    _, tagged = compiled_cells(name)
     monkeypatch.setattr(obs, "phase", lambda _: contextlib.nullcontext())
     _, plain = compile_fused(name, grid, one_chip)
     assert "repro_phase" in tagged.as_text()
     assert "repro_phase" not in plain.as_text()
     assert _instructions(tagged.as_text()) == _instructions(plain.as_text())
+
+
+def loop_ops(text: str, phase: str) -> list:
+    """(opcode, dims) of the fused loop body's instructions tagged
+    ``phase``."""
+    comps = computations(text)
+    return [(op, tuple(int(d) for d in re.match(r"\w+\[([\d,]*)\]", shape)
+                       .group(1).split(",") if d))
+            for body in set(re.findall(r"body=%([\w.\-]+)", text))
+            for _, shape, op, line in comps[body]
+            if f'repro_phase="{phase}"' in line]
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_cell_loop_updates_in_kernel_and_keeps_steady_fields(name,
+                                                             compiled_cells):
+    """At each benchmark cell's grid: PW's three updates run in its one
+    kernel's epilogue, so no op of the loop body is tagged ``update`` and
+    the kernels are still one per fuse group; tracer's rule only renames
+    ``ta`` to ``t``, so the five steady fields are neither sliced for the
+    update nor re-padded — the one carry write is ``t``'s.  Every op of
+    the loop body still carries a phase."""
+    ex, compiled = compiled_cells(name)
+    text = compiled.as_text()
+    assert untagged_in_loop(text) == []
+    assert loop_ops(text, "update") == []
+    kernels = [n for c in computations(text).values()
+               for n, _, _, line in c if _KERNEL in line]
+    assert len(kernels) == len(ex.plan.groups)
+    spec = ex.time_spec
+    carry = {f: tuple(g + int(spec.field_pad[f][a].sum())
+                      for a, g in enumerate(CELL_GRIDS[name]))
+             for f in spec.persistent}
+    changed = [f for f, w in spec.update_placement.items() if w != "kept"]
+    if name == "pw_advection":
+        assert spec.update_counts() == {"kernel": 3, "kept": 0, "xla": 0}
+    else:
+        assert spec.update_counts() == {"kernel": 1, "kept": 5, "xla": 0}
+        steady = {carry[f] for f in ("un", "vn", "wn", "e3t", "msk")}
+        assert carry["t"] not in steady     # shapes tell the fields apart
+        writes = loop_ops(text, "carry_write")
+        assert writes and all(dims == carry["t"] for _, dims in writes)
+    assert {dims for _, dims in loop_ops(text, "carry_write")} == {
+        carry[f] for f in changed}
+
+
+def test_epilogue_beside_a_field_left_on_xla_compiles(one_chip, mosaic):
+    """At 8M, a PW rule whose ``w`` reads a grid-shaped array it closes
+    over: ``u`` and ``v`` are still updated in the kernel's epilogue, which
+    Mosaic accepts because it holds only their ops, and ``w`` alone is
+    updated by XLA in the loop body."""
+    p = pw_advection()
+    mask = (np.arange(np.prod(GRID_8M)).reshape(GRID_8M) % 3 > 0).astype(
+        np.float32)
+
+    def update(fl, out):
+        return {"u": fl["u"] + 0.1 * out["su"], "v": fl["v"] + 0.1 * out["sv"],
+                "w": fl["w"] + 0.1 * mask * out["sw"]}
+    ex = compile_program(p, GRID_8M, steps=4, update=update)
+    assert ex.plan.block[0] < GRID_8M[0]        # an epilogue sees a tile
+    assert ex.time_spec.update_placement == {"u": "kernel", "v": "kernel",
+                                             "w": "xla"}
+    text = ex.lower(*shapes(p, GRID_8M, one_chip)).compile().as_text()
+    assert untagged_in_loop(text) == []
+    assert {dims for _, dims in loop_ops(text, "update")} <= {GRID_8M, ()}
+    assert loop_ops(text, "update")
