@@ -33,8 +33,9 @@ streaming cannot honour a dependency in one sweep:
 
 * a temp read at a **positive** stream offset would need a plane the
   pipeline has not produced yet (would require skewing) — split;
-* a **periodic** temp read at a negative stream offset would need the end
-  of the sweep at its beginning (wraparound is not yet resident) — split.
+* a temp **periodic along the stream axis** read at a negative stream
+  offset would need the end of the sweep at its beginning (wraparound is
+  not yet resident) — split.
 
 Split intermediates are materialised in HBM between regions, exactly like
 the paper's inter-stage streams; external inputs never force a split (the
@@ -51,10 +52,10 @@ fetched from HBM once per T steps.  The chain legalises like regions do —
 :func:`chain_split_reason` demotes the *effective* tile (carried on
 ``StreamSpec.time_tile``) to 1 wherever one sweep cannot honour the chain:
 multi-region programs (step intermediates materialise in HBM between
-sweeps), periodic persistent fields (the updated field's wraparound planes
-are not resident mid-sweep — the same rule that splits periodic temp
-back-references), or regions that do not see every persistent field (the
-update rule consumes them all).
+sweeps), persistent fields periodic along any axis (the updated field's
+wraparound planes are not resident mid-sweep — the same rule that splits
+periodic temp back-references), or regions that do not see every
+persistent field (the update rule consumes them all).
 
 **Spatial unrolling** (``plan.plane_tile = P > 1``, the paper's parallel
 processing elements consuming multiple contiguous points per cycle): one
@@ -227,6 +228,7 @@ def stream_split_reason(p: Program, produced: set, op_index: int
     """Why op ``op_index`` cannot join a region that produced ``produced``
     (None = it can)."""
     op = p.ops[op_index]
+    kinds = p.axis_boundaries()
     for a in op.accesses():
         if a.field not in produced:
             continue
@@ -234,10 +236,10 @@ def stream_split_reason(p: Program, produced: set, op_index: int
         if o0 > 0:
             return (f"op {op.name or op.out!r} reads {a.field!r} at stream "
                     f"offset +{o0} (future plane)")
-        if o0 < 0 and p.fields[a.field].boundary == "periodic":
-            return (f"op {op.name or op.out!r} reads periodic temp "
-                    f"{a.field!r} at stream offset {o0} (wraparound not "
-                    "resident)")
+        if o0 < 0 and kinds[a.field][STREAM_AXIS] == "periodic":
+            return (f"op {op.name or op.out!r} reads temp {a.field!r}, "
+                    f"periodic along the stream axis, at stream offset "
+                    f"{o0} (wraparound not resident)")
     return None
 
 
@@ -285,8 +287,9 @@ def chain_split_reason(p: Program, regions: Sequence) -> str | None:
         return (f"program streams as {len(regions)} regions; chained steps "
                 "would need inter-region intermediates resident mid-sweep")
     persistent = p.input_fields()
+    kinds = p.axis_boundaries()
     for f in persistent:
-        if p.fields[f].boundary == "periodic":
+        if "periodic" in kinds[f]:
             return (f"persistent field {f!r} is periodic: the updated "
                     "field's wraparound planes are not resident mid-sweep")
     region = regions[0]

@@ -26,13 +26,15 @@ drives the local backends drives the SPMD ones:
   ``ShardSpec.field_halo`` records the same per-field halos for the
   plan-time single-hop validation).
 
-Boundaries follow each field's IR declaration (:mod:`repro.core.boundary`):
-``"zero"`` uses partial ``ppermute`` rings whose unreceiving edge shards
-stay zero-filled — the zero-halo convention with no special code — while
-``"periodic"`` closes the ring (and wraps locally on unsharded axes), so
-the same program runs a torus across any mesh.  XLA schedules the per-axis
-permutes of different fields independently, so halo traffic overlaps with
-the compute of earlier groups (dataflow concurrency at cluster scale).
+Boundaries follow each field's IR declaration (:mod:`repro.core.boundary`),
+axis by axis: along a ``"zero"`` axis partial ``ppermute`` rings leave the
+unreceiving edge shards zero-filled — the zero-halo convention with no
+special code — while along a ``"periodic"`` axis the ring closes (or the
+block wraps locally where the axis is unsharded), so the same program
+runs a torus, or a domain cyclic along some axes only, across any mesh.
+XLA schedules the per-axis permutes of different fields independently,
+so halo traffic overlaps with the compute of earlier groups (dataflow
+concurrency at cluster scale).
 
 All three backends lower here: ``pallas`` runs the generated group kernels
 on local blocks; the jnp backends route temp accesses through
@@ -110,18 +112,21 @@ def _exchange_axis(x: jnp.ndarray, ax: int, lo: int, hi: int, align: int,
 def halo_exchange_pad(x: jnp.ndarray, lo: Sequence[int], hi: Sequence[int],
                       align_hi: Sequence[int], mesh_axes: Sequence,
                       axis_sizes: Mapping | None = None,
-                      periodic: bool = False) -> jnp.ndarray:
+                      boundary="zero") -> jnp.ndarray:
     """Pad a local block with neighbour halos (sharded axes), wraparound
-    (periodic unsharded axes), or zeros.
+    (periodic unsharded axes), or zeros, axis by axis per ``boundary`` (a
+    kind, or a per-axis sequence of kinds).
 
     ``axis_sizes`` maps mesh-axis name -> size (static, from the mesh); the
     trace environment has no portable size query across jax versions."""
     axis_sizes = axis_sizes or {}
+    kinds = bc.per_axis(boundary, x.ndim)
     for ax in range(x.ndim):
         a = mesh_axes[ax] if ax < len(mesh_axes) else None
         n = 1 if a is None else int(axis_sizes[a])
         al = int(align_hi[ax]) if ax < len(align_hi) else 0
-        x = _exchange_axis(x, ax, int(lo[ax]), int(hi[ax]), al, a, n, periodic)
+        x = _exchange_axis(x, ax, int(lo[ax]), int(hi[ax]), al, a, n,
+                           kinds[ax] == "periodic")
     return x
 
 
@@ -191,7 +196,8 @@ def _jnp_step_hooks(p: Program, shard: ShardSpec, origin, reach: dict):
         return None, None
     ndim = p.ndim
 
-    def shift(x, offset, kind):
+    def shift(x, offset, boundary):
+        kinds = bc.per_axis(boundary, ndim)
         for ax in range(ndim):
             o = int(offset[ax])
             if o == 0:
@@ -203,7 +209,8 @@ def _jnp_step_hooks(p: Program, shard: ShardSpec, origin, reach: dict):
                     f"{n_loc} (halo exchange is single-hop)")
             lo, hi = max(0, -o), max(0, o)
             xp = _exchange_axis(x, ax, lo, hi, 0, shard.mesh_axes[ax],
-                                shard.axis_size(ax), kind == "periodic")
+                                shard.axis_size(ax),
+                                kinds[ax] == "periodic")
             x = jax.lax.slice_in_dim(xp, lo + o, lo + o + n_loc, axis=ax)
         return x
 
@@ -251,9 +258,9 @@ def _scalar_io(p: Program, backend: str):
 def _host_coeffs(p: Program, coeffs: Mapping, jdtype, reach: dict) -> dict:
     """Replicated coefficient arrays, pre-extended by ``reach`` so any shard
     can slice its piece ('small data' lives on every chip, paper step 8)."""
-    cmode = bc.coeff_mode(p)
     return {c: bc.pad_coeff(jnp.asarray(coeffs[c], dtype=jdtype),
-                            reach[c][0], reach[c][1], cmode)
+                            reach[c][0], reach[c][1],
+                            bc.coeff_mode(p, p.coeffs[c]))
             for c in p.coeffs}
 
 
@@ -372,7 +379,7 @@ def lower_sharded(p: Program, plan: DataflowPlan, global_grid,
                     return halo_exchange_pad(
                         x, call.halo_lo, call.halo_hi, call.align_hi,
                         mesh_axes, axis_sizes,
-                        periodic=bnd[f] == "periodic"), None
+                        boundary=bnd[f]), None
 
             outputs = _run_groups(p, calls, svec, pc_per_call, resolve,
                                   origin=origin)
@@ -460,9 +467,11 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
                            for a in range(ndim))
                   for f in spec.persistent}
 
+    kinds = p.axis_boundaries()
+
     def _needs_refresh(f) -> bool:
         # a field's carry halos go stale each step only if they hold
-        # wraparound values (periodic) or neighbour data (sharded axis);
+        # wraparound values (periodic axis) or neighbour data (sharded axis);
         # zero halos on unsharded axes are invariant — skipping their
         # rebuild also lets a degenerate 1x..x1 mesh fold to the exact
         # single-device graph
@@ -471,7 +480,7 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
             hi = int(fpad[f][a, 1]) - int(align[a])
             if lo == 0 and hi == 0:
                 continue
-            if bnd[f] == "periodic" or shard.axis_size(a) > 1:
+            if kinds[f][a] == "periodic" or shard.axis_size(a) > 1:
                 return True
         return False
 
@@ -486,7 +495,7 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
             return halo_exchange_pad(
                 carry_f[interior[f]], fpad[f][:, 0],
                 [int(fpad[f][a, 1]) - int(align[a]) for a in range(ndim)],
-                align, mesh_axes, axis_sizes, periodic=bnd[f] == "periodic")
+                align, mesh_axes, axis_sizes, boundary=bnd[f])
 
     origin_arrs, origin_specs = _origin_inputs(shard)
     scal_spec, pack_scalars = _scalar_io(p, backend)
@@ -551,7 +560,7 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
                         return halo_exchange_pad(
                             env[f], call.halo_lo, call.halo_hi,
                             call.align_hi, mesh_axes, axis_sizes,
-                            periodic=bnd[f] == "periodic"), None
+                            boundary=bnd[f]), None
 
                 return _run_groups(p, calls_, svec, pc_per_call, resolve,
                                    origin=origin)

@@ -129,33 +129,35 @@ def where(p, t, f):
 
 
 class ProgramBuilder:
-    def __init__(self, name: str, ndim: int, boundary: str = "zero"):
+    def __init__(self, name: str, ndim: int, boundary="zero"):
         if ndim not in (1, 2, 3):
             raise ValueError("ndim must be 1..3")
         self.name = name
         self.ndim = ndim
-        self.boundary = boundary      # default for every declared field
+        # default for every declared field: a kind for every axis, or a
+        # per-axis sequence of kinds (see repro.core.boundary)
+        self.boundary = boundary
         self._fields: dict = {}
         self._scalars: list = []
         self._coeffs: dict = {}
         self._ops: list = []
 
     # -- declarations ---------------------------------------------------
-    def input(self, name: str, boundary: str | None = None) -> FieldHandle:
+    def input(self, name: str, boundary=None) -> FieldHandle:
         self._declare(name, FieldRole.INPUT, boundary)
         return FieldHandle(name, self.ndim, self)
 
     def inputs(self, *names: str):
         return tuple(self.input(n) for n in names)
 
-    def output(self, name: str, boundary: str | None = None) -> FieldHandle:
+    def output(self, name: str, boundary=None) -> FieldHandle:
         self._declare(name, FieldRole.OUTPUT, boundary)
         return FieldHandle(name, self.ndim, self)
 
     def outputs(self, *names: str):
         return tuple(self.output(n) for n in names)
 
-    def temp(self, name: str, boundary: str | None = None) -> FieldHandle:
+    def temp(self, name: str, boundary=None) -> FieldHandle:
         """Field produced and consumed inside the program, never stored."""
         self._declare(name, FieldRole.TEMP, boundary)
         return FieldHandle(name, self.ndim, self)
@@ -178,11 +180,13 @@ class ProgramBuilder:
         self._coeffs[name] = axis
         return CoeffHandle(name, axis)
 
-    def _declare(self, name: str, role: FieldRole, boundary: str | None = None):
+    def _declare(self, name: str, role: FieldRole, boundary=None):
         if name in self._fields:
             raise ValueError(f"duplicate field {name!r}")
+        if boundary is None:
+            boundary = self.boundary
         self._fields[name] = FieldDecl(name=name, role=role,
-                                       boundary=boundary or self.boundary)
+                                       boundary=boundary)
 
     # -- op definition ----------------------------------------------------
     def define(self, out: FieldHandle, expr, name: str = "") -> None:

@@ -24,6 +24,8 @@ import dataclasses
 import enum
 from typing import Callable
 
+from .boundary import compact, per_axis, validate_boundaries
+
 # --------------------------------------------------------------------------
 # Expressions
 # --------------------------------------------------------------------------
@@ -154,9 +156,14 @@ class FieldDecl:
     name: str
     role: FieldRole
     dtype: str = "float32"
-    # how reads outside the domain resolve: "zero" (historical convention)
-    # or "periodic" (torus wraparound) — see repro.core.boundary
-    boundary: str = "zero"
+    # how reads outside the domain resolve, per axis: "zero" (historical
+    # convention) or "periodic" (wraparound); a bare kind stands for every
+    # axis, and a per-axis sequence is kept as a tuple, or as its bare kind
+    # where every axis has the same — see repro.core.boundary
+    boundary: object = "zero"
+
+    def __post_init__(self):
+        self.boundary = compact(self.boundary)
 
 
 @dataclasses.dataclass
@@ -231,25 +238,35 @@ class Program:
         for n, f in self.fields.items():
             if f.role in (FieldRole.OUTPUT, FieldRole.TEMP) and n not in produced:
                 raise ValueError(f"declared output {n!r} never produced")
-        from .boundary import validate_boundaries
         validate_boundaries(self)
 
     def boundaries(self) -> dict:
-        """field name -> boundary kind ("zero" | "periodic")."""
+        """field name -> its declared boundary: a bare kind ("zero" |
+        "periodic") where it is the same on every axis, else a per-axis
+        tuple of kinds."""
         return {n: f.boundary for n, f in self.fields.items()}
 
+    def axis_boundaries(self) -> dict:
+        """field name -> per-axis tuple of kinds (the form the lowerings
+        decide by, axis by axis)."""
+        return {n: per_axis(f.boundary, self.ndim)
+                for n, f in self.fields.items()}
+
     def is_torus(self) -> bool:
-        """True when every field is periodic (the whole domain wraps)."""
-        return all(f.boundary == "periodic" for f in self.fields.values())
+        """True when every field is periodic along every axis."""
+        return all(set(k) == {"periodic"}
+                   for k in self.axis_boundaries().values())
 
     def with_boundary(self, spec) -> "Program":
         """A copy of this program with boundaries replaced.
 
-        ``spec`` is either a single kind applied to every field (the usual
-        torus/zero toggle) or a mapping ``{field: kind}`` overriding only
-        the named fields.  The copy is re-validated.
+        ``spec`` is a single kind applied to every field and axis (the
+        usual torus/zero toggle), a per-axis sequence of kinds applied to
+        every field (``["periodic", "zero", "zero"]``: cyclic along axis 0
+        only), or a mapping ``{field: kind or per-axis sequence}``
+        overriding only the named fields.  The copy is re-validated.
         """
-        if isinstance(spec, str):
+        if not isinstance(spec, dict):
             spec = {n: spec for n in self.fields}
         unknown = set(spec) - set(self.fields)
         if unknown:

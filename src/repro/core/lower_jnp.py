@@ -48,7 +48,6 @@ def lower(p: Program, mode: str = "fused", prepad: Mapping | None = None,
         raise ValueError(mode)
     prepadded = set(prepad or {})
     bnd = p.boundaries()
-    cmode = bc.coeff_mode(p)
     shift = shift_fn or bc.shift_field
 
     def run(fields: Mapping[str, jnp.ndarray],
@@ -73,7 +72,8 @@ def lower(p: Program, mode: str = "fused", prepad: Mapping | None = None,
             if coeff_fn is not None:
                 return coeff_fn(c, coeffs)
             ax = p.coeffs[c.coeff]
-            v = bc.shift_field(coeffs[c.coeff], (c.offset,), cmode)
+            v = bc.shift_field(coeffs[c.coeff], (c.offset,),
+                               bc.coeff_mode(p, ax))
             shape = [1] * p.ndim
             shape[ax] = v.shape[0]
             return v.reshape(shape)
@@ -111,8 +111,8 @@ def lower_time_loop(p: Program, mode: str, spec, update):
     step body reads windows out of the carry (static slices, no ``jnp.pad``)
     and the traced ``update(fields, outputs)`` writes the new interiors back
     in place.  Halo slabs follow each field's boundary: zero slabs stay
-    zero throughout; periodic slabs are rebuilt from the new interior every
-    step (the wraparound values change with it).
+    zero throughout; a field periodic along any axis is re-padded from the
+    new interior every step (the wraparound values change with it).
     """
     import jax
 
@@ -148,7 +148,8 @@ def lower_time_loop(p: Program, mode: str, spec, update):
             out = {}
             for f in spec.persistent:
                 if spec.carry_write == "inplace" and bnd[f] == "zero":
-                    # zero halos never change: scatter the interior only
+                    # zero halos on every axis never change: scatter the
+                    # interior only
                     out[f] = carry[f].at[interior[f]].set(new[f])
                 else:
                     # one fused interior write + constant (zero) or

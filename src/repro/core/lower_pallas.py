@@ -31,14 +31,14 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float64": jnp.floa
 
 def _pad_coeffs(p: Program, calls, coeffs, dtype):
     """Per-call padded coefficient windows ('small data', paper step 8)."""
-    cmode = bc.coeff_mode(p)
     out = []
     for call in calls:
         pc = {}
         for c in call.group_coeffs:
             ax = call.coeff_axis[c]
             pc[c] = bc.pad_coeff(jnp.asarray(coeffs[c], dtype=dtype),
-                                 call.pad_lo[ax], call.pad_hi[ax], cmode)
+                                 call.pad_lo[ax], call.pad_hi[ax],
+                                 bc.coeff_mode(p, ax))
         out.append(pc)
     return out
 
@@ -351,8 +351,9 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     slabs never change, so writing the back buffer each step touches only
     the interior — either scattered in place (``carry_write="inplace"``) or
     rebuilt as one fused interior-plus-constant-halo write (``"repad"``,
-    the default; see :class:`TimeLoopSpec`); periodic slabs are rebuilt
-    from the new interior (the wraparound values change with it).  XLA
+    the default; see :class:`TimeLoopSpec`); a field periodic along any
+    axis has its carry rebuilt from the new interior (the wraparound
+    values change with it).  XLA
     donates the loop carry,
     giving the front/back buffer swap ``spec.double_buffer`` assigns.
     Coefficients are loop-invariant and padded once, outside the loop.
@@ -473,7 +474,8 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                     if placement[f] == "kept":
                         out[f] = carry[f]   # unchanged: stays as it is
                     elif spec.carry_write == "inplace" and bnd[f] == "zero":
-                        # zero halos never change: scatter the interior only
+                        # zero halos on every axis never change: scatter the
+                        # interior only
                         out[f] = carry[f].at[interior[f]].set(
                             jnp.asarray(new[f], dtype=dtype))
                     else:

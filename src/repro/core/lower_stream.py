@@ -21,9 +21,9 @@ over the outer (stream) axis**, one step per plane:
   from a full window.
 
 Boundary handling mirrors the block schedule: the orchestrator pre-pads the
-stream axis (zero slabs or torus wraparound planes), non-stream margins are
-masked against the global domain for zero-boundary fields, and ring-buffered
-temps store zeros for out-of-domain planes.
+stream axis (zero slabs or wraparound planes), non-stream margins are
+masked against the global domain along each field's zero axes, and
+ring-buffered temps store zeros for out-of-domain planes.
 
 The produced callables expose the same geometry attributes as
 ``kernels.stencil3d.build_group_call`` (``group_inputs``/``pad_lo``/
@@ -156,9 +156,10 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
     # stage s evaluates every op at its base margin plus (T-1-s) accumulated
     # halo steps (chained stages shrink back toward the grid); masking of a
     # stage's results follows the *stage* margins — non-stream recompute
-    # needs the zero-halo mask unless the field is periodic (wrapped planes
-    # are exact); the stream axis itself is handled by input padding + ring-
-    # store masking, never here
+    # needs the zero-halo mask along the axes where the field is zero
+    # (wrapped planes are exact along its periodic axes); the stream axis
+    # itself is handled by input padding + ring-store masking, never here
+    kinds = p.axis_boundaries()
     stage_margins = [{out: m + (T - 1 - s) * stage_add
                       for out, m in margins.items()} for s in range(T)]
     # per-(stage, field) ring-plane extents: stage s reads updated fields
@@ -315,11 +316,11 @@ def build_stream_call(p: Program, region: StreamRegion, grid_shape,
                                    coeff=coeff)
                     res = jnp.broadcast_to(jnp.asarray(res, dtype=dtype),
                                            ext)
-                    if m[1:].any() \
-                            and p.fields[op.out].boundary != "periodic":
+                    if m[1:].any():
                         mask = None
                         for ax in range(1, ndim):
-                            if not m[ax].any():
+                            if not m[ax].any() \
+                                    or kinds[op.out][ax] == "periodic":
                                 continue
                             g0 = org_ref[0, ax] - int(m[ax, 0])
                             coord = g0 + jax.lax.broadcasted_iota(

@@ -29,6 +29,7 @@ import jax
 from ..obs.events import PlanChosen
 from ..obs.metrics import global_metrics
 from ..obs.trace import resolve_tracer
+from . import boundary as bc
 from . import dataflow, distribute, lower_jnp, lower_pallas, lower_stream
 from .ir import Program
 from .passes import infer_halo
@@ -221,7 +222,10 @@ def compile_program(p: Program, grid, *,
 
     ``boundary=`` overrides the program's per-field boundary declarations
     before compiling: a single kind (``"zero"`` / ``"periodic"`` for a
-    torus) or a ``{field: kind}`` mapping (see ``Program.with_boundary``).
+    torus), a per-axis list (``["periodic", "zero", "zero"]``: cyclic along
+    axis 0 only) or a ``{field: kind}`` mapping (see
+    ``Program.with_boundary``).  A program declared with per-axis
+    boundaries needs no override: the boundary is part of the program.
 
     ``schedule=`` selects the Pallas iteration schedule: ``"block"``
     (tiled output, overlapping VMEM windows per tile) or ``"stream"`` (the
@@ -305,6 +309,10 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
                 f"plane_tile must be >= 1, got {plane_tile}")
     if boundary is not None:
         p = p.with_boundary(boundary)
+    # the compile's (field, axis) pairs by boundary kind
+    kinds = [k for ks in p.axis_boundaries().values() for k in ks]
+    for kind in bc.BOUNDARIES:
+        metrics.counter(f"compile.halo_axes.{kind}").inc(kinds.count(kind))
 
     ndim = p.ndim
     if mesh is not None:

@@ -112,12 +112,15 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     coeff_shape = {c: tuple(halo_lo[a] + padded_out[a] + halo_hi[a]
                             if a == ax else 1 for a in range(ndim))
                    for c, ax in coeff_axis.items()}
-    # which ops need the zero-halo mask on margin-extended recompute: a
-    # periodic op's wraparound windows make the recomputed values exact at
-    # every position, so masking them to zero would be wrong; a zero-BC
-    # op's out-of-domain values must read as 0 downstream
-    masked = {op.out: (margins[op.out].any()
-                       and p.fields[op.out].boundary != "periodic")
+    # the axes along which each op needs the zero-halo mask on
+    # margin-extended recompute: along a periodic axis the op's wraparound
+    # windows make the recomputed values exact, so masking them to zero
+    # would be wrong; along a zero axis its out-of-domain values must read
+    # as 0 downstream
+    kinds = p.axis_boundaries()
+    masked = {op.out: (tuple(ax for ax in range(ndim)
+                             if kinds[op.out][ax] == "zero")
+                       if margins[op.out].any() else ())
               for op in ops}
 
     def centre(m):
@@ -183,9 +186,10 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
             res = jnp.broadcast_to(jnp.asarray(res, dtype=dtype), ext)
             if masked[op.out]:
                 # zero-halo semantics: recomputed values OUTSIDE the global
-                # domain must read as 0 to downstream consumers.
+                # domain along a zero axis must read as 0 to downstream
+                # consumers.
                 mask = None
-                for ax in range(ndim):
+                for ax in masked[op.out]:
                     g0 = (org_ref[0, ax] + pl.program_id(ax) * block[ax]
                           - int(m[ax, 0]))
                     coord = g0 + jax.lax.broadcasted_iota(jnp.int32, ext, ax)

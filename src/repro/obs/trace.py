@@ -382,7 +382,12 @@ def phase(name: str):
     its outputs cropped), ``group_pad`` (an intermediate padded for the
     next fuse group), ``update`` (the update rule), ``carry_write`` (the
     new state written into the carry), ``exit`` (the interior sliced out
-    after the loop) and ``halo`` (the halo refresh around ``ppermute``).
+    after the loop), ``halo`` (the halo refresh around ``ppermute``) and
+    ``wrap`` (the slices and concatenation that fill a periodic axis's
+    halo, :func:`repro.core.boundary.pad_field`). Tags nest and the
+    innermost wins: a wraparound filled for the carry, a group's pad or
+    the entry reads ``wrap``, not ``carry_write``, ``group_pad`` or
+    ``entry``; a program periodic along no axis has no ``wrap`` op.
     Kernels are never tagged: their ``pallas_call`` name says what they
     are. A tag is set at trace time and changes nothing else in the
     compiled program."""

@@ -13,7 +13,8 @@ fused step — but from an invariant maintained jointly by three pieces:
    handling at bucket edges is therefore never observed by real cells.
 2. **Embedding** (:func:`embed_field` / :func:`embed_coeff`): on request
    ingress every bucket cell — not just the reach ring — is filled with the
-   value the real boundary dictates (0, or the torus wrap of the interior).
+   value the real boundary dictates along each axis (0, or the wrap of the
+   interior).
 3. **Refresh** (:func:`make_refresh`, installed by :func:`wrap_update`):
    after every fused step the out-of-domain cells are rewritten from the
    new interior, restoring the embedding before the next step reads it.
@@ -72,18 +73,22 @@ def size_scalars(spec: BucketSpec) -> dict:
 # --------------------------------------------------------------------------
 
 
-def embed_field(x, spec: BucketSpec, boundary: str) -> np.ndarray:
+def embed_field(x, spec: BucketSpec, boundary) -> np.ndarray:
     """Place a real-grid array into its bucket, filling every out-of-domain
-    cell per the field's boundary (zeros, or the torus wrap of ``x``)."""
+    cell per the field's boundary, axis by axis: the wrap of ``x`` along
+    its periodic axes first, then zeros along its zero axes."""
     x = np.asarray(x)
     if tuple(x.shape) != tuple(spec.grid):
         raise ValueError(f"field shape {x.shape} != request grid {spec.grid}")
-    if boundary == "periodic":
-        idxs = [(np.arange(b) - o) % g
-                for g, b, o in zip(spec.grid, spec.bucket, spec.offset)]
-        return x[np.ix_(*idxs)]
+    kinds = bc.per_axis(boundary, x.ndim)
+    for a, (g, b, o) in enumerate(zip(spec.grid, spec.bucket, spec.offset)):
+        if kinds[a] == "periodic":
+            x = np.take(x, (np.arange(b) - o) % g, axis=a)
+    if "zero" not in kinds:
+        return x
     out = np.zeros(spec.bucket, dtype=x.dtype)
-    out[spec.interior()] = x
+    out[tuple(slice(None) if k == "periodic" else s
+              for k, s in zip(kinds, spec.interior()))] = x
     return out
 
 
@@ -91,8 +96,8 @@ def embed_coeff(c, axis: int, spec: BucketSpec, mode: str) -> np.ndarray:
     """Extend a per-axis coefficient array to bucket length.
 
     ``mode`` must match :func:`repro.core.boundary.coeff_mode` for the
-    program so the embedded values agree with what the exact-grid compile
-    would read through its shifted-coefficient path.
+    program and ``axis`` so the embedded values agree with what the
+    exact-grid compile would read through its shifted-coefficient path.
     """
     c = np.asarray(c)
     g, b, o = spec.grid[axis], spec.bucket[axis], spec.offset[axis]
@@ -117,11 +122,11 @@ def embed_request(p: Program, spec: BucketSpec, fields, scalars=None,
     Returns (fields, scalars, coeffs) dicts shaped for the bucket compile.
     """
     bnd = p.boundaries()
-    cmode = bc.coeff_mode(p)
     efields = {f: embed_field(x, spec, bnd[f]) for f, x in fields.items()}
     escalars = dict(scalars or {})
     escalars.update(size_scalars(spec))
-    ecoeffs = {c: embed_coeff(x, p.coeffs[c], spec, cmode)
+    ecoeffs = {c: embed_coeff(x, p.coeffs[c], spec,
+                              bc.coeff_mode(p, p.coeffs[c]))
                for c, x in (coeffs or {}).items()}
     return efields, escalars, ecoeffs
 
@@ -135,18 +140,20 @@ def make_refresh(p: Program, spec: BucketSpec):
     """Build ``refresh(fields, scalars) -> fields`` rewriting out-of-domain
     bucket cells from the (possibly traced, per-request) grid sizes.
 
-    Periodic fields gather ``x[off + (i - off) mod n]`` along each axis;
-    zero fields mask cells outside ``[off, off + n)``.  Sizes come from the
-    ``_srv_n*`` scalars so the gather/mask shapes are static (bucket-sized)
-    while the wrap length is traced — one trace covers every grid in the
-    bucket, and ``vmap`` batches requests with different sizes.
+    Along a field's periodic axes the refresh gathers
+    ``x[off + (i - off) mod n]``; along its zero axes it masks cells
+    outside ``[off, off + n)``.  Sizes come from the ``_srv_n*`` scalars so
+    the gather/mask shapes are static (bucket-sized) while the wrap length
+    is traced — one trace covers every grid in the bucket, and ``vmap``
+    batches requests with different sizes.
 
     Under ``shard_map`` the refresh sees *local* shards; ``origin`` (the
-    shard's global offset vector) shifts the zero-boundary masks into
-    global coordinates.  The periodic gather is a whole-axis permutation
-    with no shard-local form, so periodic fields reject a non-None origin.
+    shard's global offset vector) shifts the zero-axis masks into global
+    coordinates.  The periodic gather is a whole-axis permutation with no
+    shard-local form, so a field periodic along a sharded axis (one whose
+    local extent is less than the bucket's) is rejected.
     """
-    bnd = p.boundaries()
+    kinds = p.axis_boundaries()
     names = size_scalar_names(p.ndim)
     offs = tuple(int(o) for o in spec.offset)
     bucket = tuple(int(b) for b in spec.bucket)
@@ -155,17 +162,19 @@ def make_refresh(p: Program, spec: BucketSpec):
         ns = [jnp.asarray(scalars[nm]).astype(jnp.int32) for nm in names]
         out = {}
         for f, x in fields.items():
-            if bnd.get(f) == "periodic":
-                if origin is not None:
-                    raise NotImplementedError(
-                        f"periodic field {f!r}: the bucket refresh is a "
-                        "global torus gather with no shard-local form; "
-                        "serve periodic fused loops unsharded")
-                for a in range(p.ndim):
+            kind = kinds.get(f, ("zero",) * p.ndim)
+            for a in range(p.ndim):
+                if kind[a] == "periodic":
+                    if x.shape[a] != bucket[a]:
+                        raise NotImplementedError(
+                            f"field {f!r} is periodic along sharded axis "
+                            f"{a}: the bucket refresh is a whole-axis torus "
+                            "gather with no shard-local form; serve "
+                            "periodic fused loops unsharded along it")
                     idx = offs[a] + (jnp.arange(bucket[a]) - offs[a]) % ns[a]
                     x = jnp.take(x, idx, axis=a)
-            else:
-                for a in range(p.ndim):
+            for a in range(p.ndim):
+                if kind[a] == "zero":
                     i = jnp.arange(x.shape[a])
                     if origin is not None:
                         i = i + origin[a]

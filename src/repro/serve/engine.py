@@ -280,18 +280,20 @@ class StencilEngine:
         p = req.program
         if req.boundary is not None:
             p = p.with_boundary(req.boundary)
-        if req.steps is not None and self.mesh is not None and any(
-                self.mesh_axes[a] is not None
-                and int(self.mesh.shape[self.mesh_axes[a]]) > 1
-                for a in range(p.ndim)):
+        if req.steps is not None and self.mesh is not None:
+            sharded = [a for a in range(p.ndim)
+                       if self.mesh_axes[a] is not None
+                       and int(self.mesh.shape[self.mesh_axes[a]]) > 1]
+            kinds = p.axis_boundaries()
             per = sorted(f for f in p.input_fields()
-                         if p.boundaries().get(f) == "periodic")
+                         if any(kinds[f][a] == "periodic" for a in sharded))
             if per:
                 raise ValueError(
-                    f"fused serving of periodic fields {per} under mesh= is "
-                    "not supported: the bucket refresh is a global torus "
-                    "gather with no shard-local form; serve them unsharded "
-                    "or use boundary='zero'")
+                    f"fused serving of fields {per}, periodic along a "
+                    "sharded axis, under mesh= is not supported: the bucket "
+                    "refresh is a whole-axis torus gather with no "
+                    "shard-local form; serve them unsharded along it or "
+                    "use boundary='zero' there")
         sp = serving_program(p)
         missing = set(sp.input_fields()) - set(req.fields)
         if missing:
