@@ -8,7 +8,8 @@ flows through the generated kernel's VMEM windows (see kernels/stencil3d.py).
 The XLA ops around the kernels carry a ``repro_phase`` tag
 (:func:`repro.obs.phase`): ``entry`` and ``exit`` around the fused loop,
 ``group_pad`` for the pads that feed a group, ``update`` (the rule's
-fields left on XLA) and ``carry_write`` in each step.
+fields left on XLA) and ``carry_write`` (the new values XLA, not a
+kernel, writes into the carry) in each step.
 """
 
 from __future__ import annotations
@@ -44,7 +45,7 @@ def _pad_coeffs(p: Program, calls, coeffs, dtype):
 
 
 def _run_groups(p: Program, calls, svec, pc_per_call, resolve_input,
-                origin=None):
+                origin=None, back=None):
     """Run the fuse groups in order, materialising inter-group fields.
 
     ``resolve_input(call, f, env) -> (array, actual_pad | None)`` supplies
@@ -52,6 +53,8 @@ def _run_groups(p: Program, calls, svec, pc_per_call, resolve_input,
     (pad None) or an oversized persistent buffer with its actual padding,
     which the kernel slices its window out of via ``input_pad``.
     ``origin`` is the shard's global offset under a mesh (None locally).
+    ``back`` holds the buffer of each value a call stores in a carry
+    layout (its ``carry_names``).
     Returns the program outputs, and the new persistent fields of any call
     that computes them in an update epilogue.
     """
@@ -63,7 +66,11 @@ def _run_groups(p: Program, calls, svec, pc_per_call, resolve_input,
             padded[f], actual = resolve_input(call, f, env)
             if actual is not None:
                 ipad[f] = actual
-        res = call(padded, svec, pc, input_pad=ipad or None, origin=origin)
+        kw = {}
+        if getattr(call, "carry_names", ()):
+            kw["back"] = {f: back[f] for f in call.carry_names}
+        res = call(padded, svec, pc, input_pad=ipad or None, origin=origin,
+                   **kw)
         for f, v in res.items():
             role = p.fields[f].role
             if role != FieldRole.INPUT:     # a new field is no group's input
@@ -247,8 +254,8 @@ def place_update(p: Program, calls, persistent, grid_shape, dtype, update,
     untouched); ``"kernel"`` for one a kernel computes — the output it
     equals (``alias[f]``), or an update epilogue in the first block group
     that produces every output and holds every field its new value reads
-    (that call is rebuilt ``with_update``, running only the rule's ops for
-    it); and ``"xla"`` for the rest, which the loop body computes by
+    (that call is rebuilt with the update, running only the rule's ops
+    for it); and ``"xla"`` for the rest, which the loop body computes by
     calling the rule on the whole interiors.  Where no field stays on XLA
     the rule reads no output in the loop body, so an output that only
     hosted updates read is no longer stored.  A rule that is not
@@ -275,7 +282,7 @@ def place_update(p: Program, calls, persistent, grid_shape, dtype, update,
             alias[f], placement[f] = u.alias, "kernel"
         elif u.on_tile:
             host = next((i for i, c in enumerate(calls)
-                         if hasattr(c, "with_update")
+                         if hasattr(c, "rebuild")
                          and u.outputs <= set(c.group_outputs)
                          and u.fields <= set(c.group_inputs)), None)
             if host is not None:
@@ -290,10 +297,57 @@ def place_update(p: Program, calls, persistent, grid_shape, dtype, update,
     for i, fields in hosted.items():
         drop = [o for o in calls[i].group_outputs
                 if p.fields[o].role == FieldRole.OUTPUT and o not in needed]
-        calls[i] = calls[i].with_update(update=trace.tile_rule(fields),
-                                        update_fields=fields,
-                                        drop_outputs=drop)
+        calls[i] = calls[i].rebuild(update=trace.tile_rule(fields),
+                                    update_fields=fields, drop_outputs=drop)
     return {f: placement[f] for f in persistent}, alias, calls
+
+
+def place_carry(p: Program, calls, spec: TimeLoopSpec, placement, alias):
+    """Decide how the fused loop writes each persistent field's new value
+    into its carry buffer, from where :func:`place_update` put the update.
+
+    Returns ``(carry_placement, calls)``.  ``carry_placement[f]`` is
+    ``"kept"`` for a field that stays as it is; ``"kernel"`` for one the
+    block kernel that computes it (an output it aliases, or its update
+    epilogue) stores straight into the carry's padded layout, in the back
+    buffer — that call is rebuilt with its ``carry_pad``; otherwise XLA
+    writes the new interior: ``"inplace"`` (scattered, as
+    ``spec.carry_write`` asks, for a field zero on every axis) or
+    ``"refill"`` (padded anew).  A kernel writes the carry where the field
+    is zero along every axis (its halo never changes, so the planes the
+    kernel does not write stay as the entry left them), the kernel can
+    (``writes_carry``: block calls tiled along axis 0 alone), and, for an
+    output the field aliases, no later group, no other field and no rule
+    on XLA reads that output.  Later groups read a field's current value
+    from the front buffer, so an epilogue's new value is free to go.
+    """
+    kinds = p.axis_boundaries()
+    rule_on_xla = "xla" in placement.values()
+    targets = list(alias.values())
+    carry_pad: dict = {}                # call index -> {stored name: pad}
+    where = {}
+    for f in spec.persistent:
+        where[f] = ("kept" if placement[f] == "kept" else
+                    "inplace" if (spec.carry_write == "inplace"
+                                  and set(kinds[f]) == {"zero"})
+                    else "refill")
+        name = alias.get(f, f)
+        i = next((i for i, c in enumerate(calls)
+                  if name in c.group_outputs
+                  or name in getattr(c, "update_fields", ())), None)
+        if (placement[f] != "kernel" or i is None
+                or not getattr(calls[i], "writes_carry", False)
+                or set(kinds[f]) != {"zero"}
+                or (f in alias and (
+                    rule_on_xla or targets.count(name) > 1
+                    or any(name in c.group_inputs for c in calls[i + 1:])))):
+            continue
+        carry_pad.setdefault(i, {})[name] = spec.field_pad[f]
+        where[f] = "kernel"
+    calls = list(calls)
+    for i, pads in carry_pad.items():
+        calls[i] = calls[i].rebuild(carry_pad=pads)
+    return where, calls
 
 
 def _scalar_vec(p: Program, scalars):
@@ -346,16 +400,14 @@ def lower_time_loop(p: Program, plan: DataflowPlan, grid_shape,
     per program input field, sized by ``spec.field_pad`` so every consuming
     fuse group can slice its window geometry straight out of it (the kernel's
     ``input_pad`` path).  The update rule runs in the kernels' epilogues
-    where it can (see :func:`time_loop_from_calls`).  Halo slabs follow
-    each field's boundary: zero
-    slabs never change, so writing the back buffer each step touches only
-    the interior — either scattered in place (``carry_write="inplace"``) or
-    rebuilt as one fused interior-plus-constant-halo write (``"repad"``,
-    the default; see :class:`TimeLoopSpec`); a field periodic along any
-    axis has its carry rebuilt from the new interior (the wraparound
-    values change with it).  XLA
-    donates the loop carry,
-    giving the front/back buffer swap ``spec.double_buffer`` assigns.
+    where it can, and those kernels store a field zero on every axis
+    straight into the padded back buffer (see :func:`time_loop_from_calls`
+    and :func:`place_carry`).  XLA writes the rest: zero slabs never
+    change, so it either scatters the interior in place
+    (``carry_write="inplace"``) or rebuilds it as one fused
+    interior-plus-constant-halo write (``"repad"``, the default; see
+    :class:`TimeLoopSpec`); a field periodic along any axis has its carry
+    rebuilt from the new interior (the wraparound values change with it).
     Coefficients are loop-invariant and padded once, outside the loop.
     """
     dtype = _DTYPES[plan.dtype]
@@ -393,6 +445,18 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
     serving layer's bucket refresh) breaks it, and :func:`plane_local`
     keeps all its changes on XLA.  Where each field went is exposed as
     ``update_placement`` on the returned function.
+
+    How each new value reaches the carry is :func:`place_carry`'s choice,
+    exposed as ``carry_placement``.  A field a block kernel stores in the
+    carry's padded layout (``"kernel"``) holds two buffers, front and back
+    (``spec.double_buffer``), made once on entry: the front padded from
+    the field, the back all zeros.  The kernel reads its windows from the
+    front and writes the back, which it never reads, through an aliased
+    operand; the back's axis-0 halo planes are never written and stay
+    zero.  XLA keeps each loop-carry slot in one
+    buffer, so the body runs two steps, front to back and back to front,
+    and each buffer stays in its own slot; an odd step runs once after
+    the loop, and its result is read from the back.
     """
     raw_update, update = update, adapt_update(update)
     ndim = p.ndim
@@ -417,6 +481,10 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
             p, calls, spec.persistent, grid_shape, dtype, update,
             local=plane_local(raw_update))
     on_xla = [f for f in spec.persistent if placement[f] == "xla"]
+    carry_place, calls = place_carry(p, calls, spec, placement, alias)
+    # fields with a back buffer the kernels write: two steps per iteration
+    paired = [f for f in spec.persistent if carry_place[f] == "kernel"]
+    per_iter = 2 if paired else 1
 
     def refill(f, x):
         # halo slabs per the field's boundary; the lane-alignment slab
@@ -437,11 +505,16 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
             pc_per_call = _pad_coeffs(p, calls, coeffs, dtype)
             pc_epilogue = (_pad_coeffs(p, epilogue, coeffs, dtype)
                            if epilogue is not None else None)
-            # pad the persistent carry buffers exactly once
+            # pad the persistent carry buffers exactly once; a back
+            # buffer's interior is written before it is read, and its
+            # halo is zero
             carry = {f: refill(f, jnp.asarray(fields[f], dtype=dtype))
                      for f in spec.persistent}
+            back = {f: jnp.zeros_like(carry[f]) for f in paired}
 
-        def advance(carry, calls_, pc_):
+        def advance(state, calls_, pc_):
+            carry, back = state
+
             def resolve(call, f, env):
                 if f in carry:              # persistent: window from carry
                     return carry[f], fpad[f]
@@ -457,7 +530,9 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                 new = call(padded, svec, pc_[0],
                            input_pad={f: fpad[f] for f in call.group_inputs})
             else:
-                outputs = _run_groups(p, calls_, svec, pc_, resolve)
+                outputs = _run_groups(
+                    p, calls_, svec, pc_, resolve,
+                    back={alias.get(f, f): back[f] for f in paired})
                 # kernel-computed fields come back among the outputs
                 new = {f: outputs[alias.get(f, f)] for f in spec.persistent
                        if placement[f] == "kernel"}
@@ -471,9 +546,12 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
             out = {}
             with obs.phase("carry_write"):
                 for f in spec.persistent:
-                    if placement[f] == "kept":
+                    how = carry_place[f]
+                    if how == "kept":
                         out[f] = carry[f]   # unchanged: stays as it is
-                    elif spec.carry_write == "inplace" and bnd[f] == "zero":
+                    elif how == "kernel":
+                        out[f] = new[f]     # the back buffer, written
+                    elif how == "inplace":
                         # zero halos on every axis never change: scatter the
                         # interior only
                         out[f] = carry[f].at[interior[f]].set(
@@ -482,16 +560,23 @@ def time_loop_from_calls(p: Program, dtype, grid_shape, spec: TimeLoopSpec,
                         # one fused interior write + constant (zero) or
                         # refreshed (wraparound) halo slabs — no carry RMW
                         out[f] = refill(f, jnp.asarray(new[f], dtype=dtype))
-            return out
+            # the old front is the next step's back buffer
+            return out, {f: carry[f] for f in paired}
 
-        def body(_, carry):
-            return advance(carry, calls, pc_per_call)
+        def body(_, state):
+            for _ in range(per_iter):
+                state = advance(state, calls, pc_per_call)
+            return state
 
-        carry = jax.lax.fori_loop(0, outer, body, carry)
+        state = jax.lax.fori_loop(0, outer // per_iter, body, (carry, back))
+        for _ in range(outer % per_iter):
+            state = advance(state, calls, pc_per_call)
         if epilogue is not None and int(spec.steps) % chain:
-            carry = advance(carry, epilogue, pc_epilogue)
+            state = advance(state, epilogue, pc_epilogue)
+        carry, _ = state
         with obs.phase("exit"):
             return {f: carry[f][interior[f]] for f in spec.persistent}
 
     run.update_placement = placement
+    run.carry_placement = carry_place
     return run

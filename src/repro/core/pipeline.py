@@ -472,12 +472,16 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
         metrics.counter("compile.fused_loops").inc()
         placement = getattr(raw, "update_placement", None)
         if placement is not None:
-            # where the loop computes each field's next value (the sharded
-            # and jnp lowerings update every field on XLA and do not say)
-            time_spec = dataclasses.replace(time_spec,
-                                            update_placement=placement)
+            # where the loop computes each field's next value, and how it
+            # writes it into the carry (the sharded and jnp lowerings
+            # update and refill every field on XLA and do not say)
+            time_spec = dataclasses.replace(
+                time_spec, update_placement=placement,
+                carry_placement=raw.carry_placement)
             for where, n in time_spec.update_counts().items():
                 metrics.counter(f"compile.update_fields.{where}").inc(n)
+            for how, n in time_spec.carry_counts().items():
+                metrics.counter(f"compile.carry_write.{how}").inc(n)
     if tracer.enabled:
         eff_tt = (plan.stream.time_tile if plan.stream is not None
                   else plan.time_tile)

@@ -25,7 +25,7 @@ from typing import Sequence
 import numpy as np
 
 from .. import hw
-from .ir import Program
+from .ir import FieldRole, Program
 from .passes import _zeros, infer_halo, stage_split
 
 
@@ -580,18 +580,23 @@ class TimeLoopSpec:
     The loop carry holds one persistent, halo-padded buffer per program
     input field; each step reads stencil windows straight out of the carry
     (no per-step ``jnp.pad``), and the new interior of each field the
-    update rule changes is written back in place.  Where that rule runs is
-    the lowering's choice, recorded in ``update_placement``: the Pallas
-    loop leaves a field the rule returns unchanged in the carry, and has a
+    update rule changes is written back.  Where that rule runs is the
+    lowering's choice, recorded in ``update_placement``: the Pallas loop
+    leaves a field the rule returns unchanged in the carry, and has a
     block kernel compute a changed one in its epilogue wherever the rule
     keeps the element-wise contract of :func:`adapt_update` (as
     :func:`plane_local` reads its ``_plane_local`` flag) and one group
     holds what the field reads, tracing the rest into the loop body on
-    XLA.  Per fuse
-    group, ``double_buffer`` assigns a front/back slot pair per persistent
-    field: the group reads the front slot, the update writes the back
-    slot, and parity swaps every step — the functional lowering realises
-    the swap through XLA buffer donation on the loop carry.
+    XLA.  How each new value reaches the carry is recorded in
+    ``carry_placement``: the Pallas loop has the block kernel that
+    computes a field zero on every axis store it straight into the
+    padded layout (``"kernel"``), and XLA writes the rest
+    (``carry_write``).  ``double_buffer`` assigns a front/back slot pair
+    per persistent field; a kernel-written field uses both, since a kernel
+    cannot write the buffer it reads its windows from: it reads the front
+    and writes the back, and the loop body runs two steps, so the two
+    swap back and each stays in its own slot.  A field XLA writes keeps
+    one slot, its buffer handed on by donation of the loop carry.
     """
 
     steps: int
@@ -604,7 +609,7 @@ class TimeLoopSpec:
     # per fuse group: {field: (ndim,) int start offsets of the group's
     # expected window inside the carry buffer} (0 for transient inputs)
     group_offsets: list
-    # how the loop body writes the back buffer:
+    # how XLA writes a new value the kernels do not store in the carry:
     #   "repad"   — rebuild interior + constant zero halo in one fused write
     #               (zero-halo slabs are constants; fastest on XLA:CPU, which
     #               lowers the in-place form to a full read-modify-write)
@@ -622,12 +627,24 @@ class TimeLoopSpec:
     # it (None where it does not): "kernel" (a kernel's epilogue or output),
     # "kept" (unchanged, left in the carry) or "xla" (the loop body)
     update_placement: dict | None = None
+    # how the lowering writes each field's new value into its carry, as
+    # it reports it: "kernel" (the producing block kernel stores it in the
+    # padded back buffer), "refill" or "inplace" (XLA, as ``carry_write``
+    # asks; a field periodic on any axis always refills) or "kept"
+    carry_placement: dict | None = None
 
     def update_counts(self) -> dict:
         """How many persistent fields each place updates (all zero where
         the lowering reports no placement)."""
         where = list((self.update_placement or {}).values())
         return {k: where.count(k) for k in ("kernel", "kept", "xla")}
+
+    def carry_counts(self) -> dict:
+        """How many persistent fields each carry write takes (all zero
+        where the lowering reports none)."""
+        how = list((self.carry_placement or {}).values())
+        return {k: how.count(k)
+                for k in ("kernel", "refill", "inplace", "kept")}
 
     def describe(self) -> str:
         bufs = ", ".join(f"{f}:{a}/{b}" for f, (a, b)
@@ -636,6 +653,9 @@ class TimeLoopSpec:
         if self.update_placement is not None:
             placed = ", update=[" + ", ".join(
                 f"{k}:{n}" for k, n in self.update_counts().items()) + "]"
+        if self.carry_placement is not None:
+            placed += ", carry_write=[" + ", ".join(
+                f"{k}:{n}" for k, n in self.carry_counts().items()) + "]"
         return (f"time_loop(steps={self.steps}, "
                 f"persistent=[{','.join(self.persistent)}], "
                 f"double_buffer=[{bufs}]{placed})")
@@ -751,7 +771,12 @@ def vmem_cost(p: Program, plan: DataflowPlan, grid: Sequence[int],
     :func:`plan_time_loop` — can exceed this group's own halo, enlarging the
     window the ``input_pad`` path claims.  A plan that fits the budget
     single-step can therefore exceed it under ``steps=N``; the tuner prunes
-    with this corrected cost.
+    with this corrected cost.  The loop also has a kernel store a field
+    zero on every axis straight into its carry, whole planes of the
+    untiled axes, ring included, where only axis 0 is tiled
+    (:func:`~repro.core.lower_pallas.place_carry`, which reads the update
+    rule the plan never sees): each output no later group reads is priced
+    at the largest such plane.
     """
     bs = _dtype_bytes(plan.dtype)
     grid = tuple(int(g) for g in grid)
@@ -761,9 +786,16 @@ def vmem_cost(p: Program, plan: DataflowPlan, grid: Sequence[int],
     carry_pad = (plan_time_loop(p, plan, grid, steps,
                                 group_halos=group_halos).field_pad
                  if steps is not None else {})
+    blk = np.minimum(np.asarray(plan.block[:p.ndim]), np.asarray(grid))
+    kinds = p.axis_boundaries()
+    planes = [int(np.prod(np.asarray(grid[1:]) + pad[1:].sum(axis=1)))
+              for f, pad in carry_pad.items() if set(kinds[f]) == {"zero"}]
+    ring = 0
+    if planes and p.ndim >= 3 and (blk[1:] == np.asarray(grid[1:])).all():
+        ring = int(blk[0]) * max(planes) - int(np.prod(blk))
     worst = 0
-    for grp, gh in zip(plan.groups, group_halos):
-        blk = np.minimum(np.asarray(plan.block[:p.ndim]), np.asarray(grid))
+    for k, (grp, gh) in enumerate(zip(plan.groups, group_halos)):
+        later = {f for h in group_halos[k + 1:] for f in h.group_inputs}
         total = 0
         for f in gh.group_inputs:
             pad = gh.input_halo
@@ -775,6 +807,10 @@ def vmem_cost(p: Program, plan: DataflowPlan, grid: Sequence[int],
             m = gh.margins[i]
             ext = blk + m[:, 0] + m[:, 1]
             total += int(np.prod(ext)) * bs
+            out = p.ops[i].out
+            if (out in gh.group_outputs and out not in later
+                    and p.fields[out].role == FieldRole.OUTPUT):
+                total += ring * bs
         worst = max(worst, total)
     return 2 * worst  # double buffering
 

@@ -31,7 +31,6 @@ elsewhere (:func:`repro.hw.pallas_interpret` decides).
 
 from __future__ import annotations
 
-import functools
 from typing import Sequence
 
 import jax
@@ -60,7 +59,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                      grid_shape: Sequence[int], dtype=jnp.float32,
                      global_extent: Sequence[int] | None = None,
                      update=None, update_fields: Sequence[str] = (),
-                     drop_outputs: Sequence[str] = ()):
+                     drop_outputs: Sequence[str] = (), carry_pad=None):
     """Build a callable(padded_inputs, scalars, coeffs, origin) -> outputs.
 
     ``padded_inputs`` must be padded by ``pad_lo``/``pad_hi`` (exposed on the
@@ -76,9 +75,21 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     ``update.reads`` names them — and stores the new values of those
     persistent fields beside its outputs, returned under the fields'
     names.  The fused loop asks for that only where the group holds
-    everything they read (``with_update`` on the returned callable
-    rebuilds the same group with an epilogue).  ``drop_outputs`` names
+    everything they read (``rebuild`` on the returned callable builds the
+    same group again with other options).  ``drop_outputs`` names
     outputs only the rule reads, which the kernel then never stores.
+
+    ``carry_pad`` maps stored names to the ``(ndim, 2)`` padding of a
+    fused-loop carry buffer (``TimeLoopSpec.field_pad``, alignment slab
+    included).  The kernel stores each such value straight into that
+    layout: its tile's planes at their place along axis 0, and along every
+    other axis the whole padded extent, a zero ring around the interior;
+    planes past the grid on the last tile (hi halo and alignment slab) are
+    stored as zeros.  The call then takes the buffer to write as
+    ``back[name]``, aliased to the output and never read, and returns it
+    whole; the planes it does not write, the axis-0 halo, keep what the
+    buffer held.  That needs a leading axis 0 that alone is tiled
+    (``writes_carry`` on the returned callable).
     """
     ndim = p.ndim
     gh: GroupHalo = infer_halo(p, group)
@@ -105,6 +116,23 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     # read, then the fields the rule's epilogue computes
     store_names = ([f for f in out_names if f not in set(drop_outputs)]
                    + list(update_fields))
+    # the carry's planes are a leading axis, the only one tiled: a tile
+    # then holds whole planes of the untiled axes, ring included
+    writes_carry = ndim >= 3 and all(t == 1 for t in tiles[1:])
+    carry_pad = {f: np.asarray(v, dtype=np.int64)
+                 for f, v in (carry_pad or {}).items()}
+    carry_names = [f for f in store_names if f in carry_pad]
+    if len(carry_names) != len(carry_pad) or (carry_pad and not writes_carry):
+        raise ValueError(
+            f"cannot store {sorted(carry_pad)} in a carry layout: the "
+            f"group stores {store_names} on tiles {tiles}")
+    carry_shape = {}
+    for f, pad in carry_pad.items():
+        if (pad < 0).any() or pad[0, 1] < align_hi[0]:
+            raise ValueError(f"carry padding {pad.tolist()} of {f!r} holds "
+                             f"no alignment slab of {align_hi[0]} planes")
+        carry_shape[f] = tuple(grid_shape[a] + int(pad[a].sum())
+                               for a in range(ndim))
     coeff_axis = {c: p.coeffs[c] for c in gh.group_coeffs}
     # a coefficient vector lies along its own axis (extent 1 on the others),
     # resident whole: the kernel slices it without a relayout, and a tile's
@@ -135,8 +163,34 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         in_refs = {f: refs[i + k] for k, f in enumerate(gh.group_inputs)}
         i += len(gh.group_inputs)
         coeff_refs = {c: refs[i + k] for k, c in enumerate(gh.group_coeffs)}
-        i += len(gh.group_coeffs)
+        i += len(gh.group_coeffs) + len(carry_names)  # back buffers: unread
         out_refs = {f: refs[i + k] for k, f in enumerate(store_names)}
+
+        def store(f, value):
+            """Store the tile's value of ``f``, in its carry layout where
+            ``carry_pad`` names it: a zero ring along the untiled axes, and
+            zeros on the planes past the grid."""
+            ref = out_refs[f]
+            if f not in carry_pad:
+                ref[...] = value
+                return
+            if align_hi[0]:
+                plane = (pl.program_id(0) * block[0]
+                         + jax.lax.broadcasted_iota(jnp.int32, block, 0))
+                value = jnp.where(plane < grid_shape[0], value,
+                                  jnp.asarray(0, dtype=dtype))
+            lo = [int(carry_pad[f][a, 0]) for a in range(ndim)]
+            ext = ref.shape
+            for a in range(1, ndim):
+                for start, stop in ((0, lo[a]), (lo[a] + block[a], ext[a])):
+                    if stop > start:
+                        ring = tuple(slice(start, stop) if b == a
+                                     else slice(None) for b in range(ndim))
+                        ref[ring] = jnp.zeros(
+                            tuple(stop - start if b == a else ext[b]
+                                  for b in range(ndim)), dtype)
+            ref[(slice(None),) + tuple(slice(lo[a], lo[a] + block[a])
+                                       for a in range(1, ndim))] = value
 
         # single load_data stage: every window loads exactly once
         windows = {f: r[...] for f, r in in_refs.items()}
@@ -198,7 +252,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 res = jnp.where(mask, res, jnp.asarray(0, dtype=dtype))
             results[op.out] = res
             if op.out in out_refs:
-                out_refs[op.out][...] = res[centre(m)]
+                store(op.out, res[centre(m)])
 
         if update_fields:
             # the update rule on this tile: every value it reads for these
@@ -211,8 +265,8 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
             new = update(held, outs, {s: scalar(s) for s in reads["scalar"]},
                          block)
             for f in update_fields:
-                out_refs[f][...] = jnp.broadcast_to(
-                    jnp.asarray(new[f], dtype=dtype), block)
+                store(f, jnp.broadcast_to(jnp.asarray(new[f], dtype=dtype),
+                                          block))
 
     def window_map(*idx):
         return tuple(idx[a] * block[a] for a in range(ndim))
@@ -225,10 +279,26 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
     for c in gh.group_coeffs:
         in_specs.append(pl.BlockSpec(coeff_shape[c],
                                      lambda *idx: (0,) * ndim))
-    out_specs = tuple(pl.BlockSpec(block, lambda *idx: tuple(idx))
-                      for _ in store_names)
-    out_shape = tuple(jax.ShapeDtypeStruct(padded_out, dtype)
-                      for _ in store_names)
+    # the back buffers a carry layout is stored in, left in HBM
+    aliases = {len(in_specs) + k: store_names.index(f)
+               for k, f in enumerate(carry_names)}
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY) for _ in carry_names]
+
+    def out_spec(f):
+        if f not in carry_pad:
+            return pl.BlockSpec(block, lambda *idx: tuple(idx))
+        # the tile's planes at their carry offset, each whole; Mosaic
+        # takes element offsets only on every axis of a block at once
+        lo0 = int(carry_pad[f][0, 0])
+        return pl.BlockSpec(
+            (pl.Element(block[0]),) + tuple(pl.Element(s)
+                                            for s in carry_shape[f][1:]),
+            lambda *idx: (lo0 + idx[0] * block[0],) + (0,) * (ndim - 1))
+
+    out_specs = tuple(out_spec(f) for f in store_names)
+    out_shape = tuple(jax.ShapeDtypeStruct(carry_shape.get(f, padded_out),
+                                           dtype)
+                      for f in store_names)
 
     call = pl.pallas_call(
         kernel,
@@ -236,6 +306,7 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
         in_specs=in_specs,
         out_specs=out_specs if len(store_names) > 1 else out_specs[0],
         out_shape=out_shape if len(store_names) > 1 else out_shape[0],
+        input_output_aliases=aliases,
         compiler_params=hw.pallas_compiler_params(("parallel",) * ndim),
         interpret=hw.pallas_interpret(),
         # the group's outputs name the kernel in the HLO and the trace
@@ -249,12 +320,13 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
 
     def run(padded_inputs: dict, scalars_vec=None,
             padded_coeffs: dict | None = None, origin=None,
-            input_pad: dict | None = None):
+            input_pad: dict | None = None, back: dict | None = None):
         """``input_pad[f]`` gives the (ndim, 2) padding the provided array
         actually carries when it exceeds this group's window geometry —
         e.g. a fused time loop's carry-resident persistent buffer sized for
         the worst consuming group.  The window is sliced out statically; no
-        reallocation or copy of the halo slabs happens here."""
+        reallocation or copy of the halo slabs happens here.  ``back``
+        holds the buffer each ``carry_pad`` name is stored into."""
         with obs.phase("window"):
             svec = (scalars_vec if scalars_vec is not None
                     else jnp.zeros((max(n_scalars, 1),), jnp.float32))
@@ -275,20 +347,26 @@ def build_group_call(p: Program, group: Sequence[int], block: Sequence[int],
                 args.append(x)
             for c in gh.group_coeffs:
                 args.append(padded_coeffs[c].reshape(coeff_shape[c]))
+            args += [back[f] for f in carry_names]
         res = call(*args)
         if len(store_names) == 1:
             res = (res,)
         with obs.phase("window"):
-            return {f: r[crop] for f, r in zip(store_names, res)}
+            return {f: r if f in carry_pad else r[crop]
+                    for f, r in zip(store_names, res)}
 
     # geometry for orchestrators (lower_pallas pads with zeros; distribute
     # pads via halo exchange)
     run.group_inputs = gh.group_inputs
     run.group_outputs = out_names
     run.update_fields = tuple(update_fields)
-    run.with_update = functools.partial(
-        build_group_call, p, group, block, grid_shape, dtype=dtype,
-        global_extent=global_extent)
+    run.writes_carry = writes_carry
+    run.carry_names = tuple(carry_names)
+    options = dict(update=update, update_fields=update_fields,
+                   drop_outputs=drop_outputs, carry_pad=carry_pad)
+    run.rebuild = lambda **changes: build_group_call(
+        p, group, block, grid_shape, dtype=dtype,
+        global_extent=global_extent, **{**options, **changes})
     run.group_coeffs = gh.group_coeffs
     run.coeff_axis = coeff_axis
     run.block = block

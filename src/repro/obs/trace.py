@@ -381,7 +381,8 @@ def phase(name: str):
     ``window`` (a kernel's operands sliced out of an oversized carry and
     its outputs cropped), ``group_pad`` (an intermediate padded for the
     next fuse group), ``update`` (the update rule), ``carry_write`` (the
-    new state written into the carry), ``exit`` (the interior sliced out
+    new state XLA writes into the carry; none where a kernel stores it
+    there), ``exit`` (the interior sliced out
     after the loop), ``halo`` (the halo refresh around ``ppermute``) and
     ``wrap`` (the slices and concatenation that fill a periodic axis's
     halo, :func:`repro.core.boundary.pad_field`). Tags nest and the

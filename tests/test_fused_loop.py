@@ -10,19 +10,27 @@ Invariants:
 * The pallas loop leaves a field the rule never changes in the carry, and
   computes a changed one in the kernel that holds what it reads, where the
   rule is plane-local; the rest stays on XLA, with the same numbers.
-* Both carry-write styles ("repad" rebuild and "inplace" scatter) agree.
+* A block kernel that computes a field zero on every axis stores it
+  straight into the padded back buffer, bit for bit what XLA's refill
+  writes, for any step count; other fields keep XLA's carry write, and
+  both of its styles ("repad" rebuild and "inplace" scatter) agree.
 """
 
+import contextlib
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
-from repro.core import compile_program, plan_time_loop, run_time_loop
+from repro.core import (compile_program, lower_pallas, plan_time_loop,
+                        run_time_loop)
+from repro.core import boundary as bc
 from repro.core.schedule import adapt_update, auto_plan
+from repro.kernels import stencil3d
 from repro.obs.metrics import global_metrics
 from repro.serve import bucket_for, serving_program, wrap_update
 from repro.serve.bucket import embed_request
@@ -222,32 +230,48 @@ def serve_case():
 
 
 G_PW, G_TR = (8, 8, 128), (6, 8, 64)
-#: case -> (program, grid, data, rule, compile options), and the fields
-#: updated (in a kernel, kept in the carry, on XLA)
+#: case -> (program, grid, data, rule, compile options), the fields
+#: updated (in a kernel, kept in the carry, on XLA), and how their new
+#: values reach the carry (stored by a kernel, refilled or scattered by
+#: XLA, kept)
 PLACEMENT_CASES = {
     "pw-zero": (lambda: (pw_advection("zero"), G_PW, pw_data(G_PW),
-                         pw_advection_update(0.1), {}), (3, 0, 0)),
+                         pw_advection_update(0.1), {}), (3, 0, 0),
+                (3, 0, 0, 0)),
     "pw-periodic": (lambda: (pw_advection("periodic"), G_PW, pw_data(G_PW),
-                             pw_advection_update(0.1), {}), (3, 0, 0)),
+                             pw_advection_update(0.1), {}), (3, 0, 0),
+                    (0, 3, 0, 0)),
     "tracer-zero": (lambda: (tracer_advection("zero"), G_TR,
                              tracer_data(G_TR), tracer_advection_update(),
-                             {}), (1, 5, 0)),
+                             {}), (1, 5, 0), (1, 0, 0, 5)),
     "tracer-periodic": (lambda: (tracer_advection("periodic"), G_TR,
                                  tracer_data(G_TR),
-                                 tracer_advection_update(), {}), (1, 5, 0)),
+                                 tracer_advection_update(), {}), (1, 5, 0),
+                        (0, 1, 0, 5)),
+    "tracer-cyclic": (lambda: (tracer_advection(["periodic", "zero",
+                                                 "zero"]), G_TR,
+                               tracer_data(G_TR), tracer_advection_update(),
+                               {}), (1, 5, 0), (0, 1, 0, 5)),
     "pw-not-plane-local": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
                                     on_xla(pw_advection_update(0.1)), {}),
-                           (0, 0, 3)),
-    "serve-wrapped": (serve_case, (0, 0, 3)),
+                           (0, 0, 3), (0, 3, 0, 0)),
+    "serve-wrapped": (serve_case, (0, 0, 3), (0, 3, 0, 0)),
     "pw-not-pointwise": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
-                                  pw_rolled_w(), {}), (2, 0, 1)),
+                                  pw_rolled_w(), {}), (2, 0, 1),
+                         (2, 1, 0, 0)),
+    "pw-not-pointwise-inplace": (lambda: (pw_advection(), G_PW,
+                                          pw_data(G_PW), pw_rolled_w(),
+                                          {"carry_write": "inplace"}),
+                                 (2, 0, 1), (2, 0, 1, 0)),
     "pw-closed-over-array": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
                                       pw_masked_w(G_PW),
                                       tiled(pw_advection(), G_PW,
-                                            (4, 4, 128))), (2, 0, 1)),
+                                            (4, 4, 128))), (2, 0, 1),
+                             (0, 3, 0, 0)),
     "tracer-unheld-field": (lambda: (tracer_advection(), G_TR,
                                      tracer_data(G_TR), tracer_unheld,
-                                     {"strategy": "per_field"}), (2, 3, 1)),
+                                     {"strategy": "per_field"}), (2, 3, 1),
+                            (1, 2, 0, 3)),
 }
 
 
@@ -255,23 +279,124 @@ PLACEMENT_CASES = {
 def test_update_placement_matches_xla_update(case):
     """The fused block loop puts each field's update in a kernel, in the
     carry or on XLA, as the rule's trace, its ``_plane_local`` flag and
-    the fuse groups allow; the time spec and the compile counters say so,
-    and the answer matches the loop that updates every changed field on
-    XLA within float32 rounding."""
-    make, want = PLACEMENT_CASES[case]
+    the fuse groups allow, and has the kernel store a new value zero on
+    every axis in the carry where its tiles allow; the time spec and the
+    compile counters say so, and the answer matches the loop that
+    updates every changed field on XLA within float32 rounding."""
+    make, want, want_carry = PLACEMENT_CASES[case]
     p, grid, (fields, scalars, coeffs), rule, opts = make()
-    names = [f"compile.update_fields.{k}" for k in ("kernel", "kept", "xla")]
+    names = ([f"compile.update_fields.{k}" for k in ("kernel", "kept", "xla")]
+             + [f"compile.carry_write.{k}"
+                for k in ("kernel", "refill", "inplace", "kept")])
     before = [global_metrics().counter(n).value for n in names]
     ex = compile_program(p, grid, steps=3, update=rule, **opts)
     after = [global_metrics().counter(n).value for n in names]
     assert tuple(ex.time_spec.update_counts().values()) == want
-    assert tuple(a - b for a, b in zip(after, before)) == want
+    assert tuple(ex.time_spec.carry_counts().values()) == want_carry
+    assert tuple(a - b for a, b in zip(after, before)) == want + want_carry
     got = ex(fields, scalars, coeffs)
     ref = compile_program(p, grid, steps=3, update=on_xla(rule),
                           **opts)(fields, scalars, coeffs)
     for f in ref:
         np.testing.assert_allclose(np.asarray(got[f]), np.asarray(ref[f]),
                                    rtol=1e-6, atol=1e-6, err_msg=f)
+
+
+# ------------------------------------------ the kernel writes the carry
+
+@contextlib.contextmanager
+def refill_only():
+    """Block kernels that cannot store a carry layout, so XLA refills
+    every changed field, as before kernels wrote the back buffer."""
+    build = stencil3d.build_group_call
+
+    def without(*args, **kwargs):
+        call = build(*args, **kwargs)
+        call.writes_carry = False
+        return call
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(stencil3d, "build_group_call", without)
+        mp.setattr(lower_pallas, "build_group_call", without)
+        yield
+
+
+#: case -> (program, grid, data, rule, compile options), and the carry
+#: writes (stored by a kernel, refilled, scattered, kept)
+CARRY_CASES = {
+    "pw": (lambda: (pw_advection(), G_PW, pw_data(G_PW),
+                    pw_advection_update(0.1), {}), (3, 0, 0, 0)),
+    "tracer": (lambda: (tracer_advection(), G_TR, tracer_data(G_TR),
+                        tracer_advection_update(), {}), (1, 0, 0, 5)),
+    # three tiles of two planes over five: the last tile's second plane
+    # lies in the alignment slab
+    "pw-odd-aligned": (lambda: (pw_advection(), (5, 7, 130),
+                                pw_data((5, 7, 130)),
+                                pw_advection_update(0.1),
+                                tiled(pw_advection(), (5, 7, 130),
+                                      (2, 7, 130))), (3, 0, 0, 0)),
+    "tracer-per-field": (lambda: (tracer_advection(), G_TR,
+                                  tracer_data(G_TR),
+                                  tracer_advection_update(),
+                                  {"strategy": "per_field"}), (1, 0, 0, 5)),
+}
+
+
+@pytest.mark.parametrize("case,steps", [
+    ("pw", 1), ("pw", 3), ("pw", 4), ("pw", 7), ("pw-odd-aligned", 3),
+    ("tracer", 4), ("tracer-per-field", 1)])
+def test_kernel_carry_write_matches_refill(case, steps):
+    """A kernel that stores its new field straight into the padded back
+    buffer gives the loop's answer bit for bit, whatever the step count's
+    parity (an odd one runs its last step after the two-step body).  Both
+    loops run op by op, each kernel on its own as Mosaic runs it: under
+    one jit the CPU backend fuses the interpreted kernels' bodies across
+    steps, and may round a long chain differently at a point."""
+    make, want = CARRY_CASES[case]
+    p, grid, data, rule, opts = make()
+    with jax.disable_jit():
+        ex = compile_program(p, grid, steps=steps, update=rule, **opts)
+        got = ex(*data)
+        with refill_only():
+            ref_ex = compile_program(p, grid, steps=steps, update=rule,
+                                     **opts)
+            ref = ref_ex(*data)
+    assert tuple(ex.time_spec.carry_counts().values()) == want
+    assert ref_ex.time_spec.carry_counts()["kernel"] == 0
+    for f in ref:
+        np.testing.assert_array_equal(np.asarray(got[f]), np.asarray(ref[f]),
+                                      err_msg=f)
+
+
+def test_carry_layout_store_keeps_unwritten_planes():
+    """The block call stores its value in the back buffer's padded layout:
+    the interior, a zero ring on the untiled axes, zeros on the planes
+    past the grid; the axis-0 halo planes it never writes keep what the
+    buffer held."""
+    p = pw_advection()
+    grid, block = (5, 7, 130), (2, 7, 130)
+    plan = tiled(p, grid, block)["plan"]
+    call = stencil3d.build_group_call(p, plan.groups[0], block, grid)
+    pad = np.array([[1, 2], [1, 1], [1, 1]])    # axis 0: halo 1, slab 1
+    fields, scalars, coeffs = pw_data(grid)
+    padded = {f: bc.pad_field(fields[f], call.halo_lo, call.halo_hi, "zero",
+                              align_hi=call.align_hi)
+              for f in call.group_inputs}
+    svec = jnp.asarray([scalars[s] for s in p.scalars])
+    pcs = {c: bc.pad_coeff(coeffs[c], call.pad_lo[2], call.pad_hi[2], "zero")
+           for c in call.group_coeffs}
+    want = call(padded, svec, pcs)["su"]
+    writer = call.rebuild(carry_pad={"su": pad})
+    assert call.writes_carry and writer.carry_names == ("su",)
+    shape = tuple(g + int(pad[a].sum()) for a, g in enumerate(grid))
+    back = jnp.full(shape, 7.0, jnp.float32)
+    got = np.asarray(writer(padded, svec, pcs, back={"su": back})["su"])
+    expect = np.pad(np.asarray(want), [(1, 2), (1, 1), (1, 1)])
+    expect[0] = 7.0                 # below the first tile: never written
+    expect[-1] = 7.0                # past the last tile: never written
+    np.testing.assert_array_equal(got, expect)
+    with pytest.raises(ValueError, match="carry layout"):
+        stencil3d.build_group_call(p, plan.groups[0], (2, 4, 130), grid,
+                                   carry_pad={"su": pad})
 
 
 # ------------------------------------------------------------ plan layer

@@ -258,13 +258,20 @@ def test_fused_loop_ops_carry_a_phase_and_kernels_a_name(name, schedule,
                for n, _, _, line in c if _KERNEL in line]
     prefix = "blk_" if ex.plan.schedule == "block" else "str_"
     assert kernels and all(k.startswith(prefix) for k in kernels)
-    assert len(set(kernels)) == len(kernels)
+    # a loop whose kernels write the back buffer runs two steps per
+    # iteration: each kernel once per half
+    counts = ex.time_spec.carry_counts()
+    halves = 2 if counts["kernel"] else 1
+    assert sorted(kernels) == sorted(list(set(kernels)) * halves)
     expected = (ex.plan.groups if prefix == "blk_" else lower_to_dataflow(
         ex.program, ex.plan, GRID_8M).regions)
-    assert len(kernels) == len(expected)
+    assert len(set(kernels)) == len(expected)
     tags = {m.group(1) for m in _PHASE.finditer(text)}
-    assert {"entry", "carry_write", "exit"} <= tags
-    # the loop body traces the rule only for fields it updates on XLA
+    assert {"entry", "exit"} <= tags
+    # XLA writes the carry only for fields no kernel stores there, and
+    # traces the rule only for fields it updates
+    assert ("carry_write" in tags) == (counts["refill"] + counts["inplace"]
+                                       > 0)
     assert ("update" in tags) == (ex.time_spec.update_counts()["xla"] > 0)
 
 
@@ -293,33 +300,54 @@ def test_phase_tags_change_only_frontend_attributes(name, one_chip, mosaic,
     assert _instructions(tagged.as_text()) == _instructions(plain.as_text())
 
 
+def _dims(shape: str) -> tuple:
+    m = re.match(r"\w+\[([\d,]*)\]", shape)
+    return tuple(int(d) for d in m.group(1).split(",") if d) if m else None
+
+
 def loop_ops(text: str, phase: str) -> list:
     """(opcode, dims) of the fused loop body's instructions tagged
     ``phase``."""
     comps = computations(text)
-    return [(op, tuple(int(d) for d in re.match(r"\w+\[([\d,]*)\]", shape)
-                       .group(1).split(",") if d))
+    return [(op, _dims(shape))
             for body in set(re.findall(r"body=%([\w.\-]+)", text))
             for _, shape, op, line in comps[body]
             if f'repro_phase="{phase}"' in line]
+
+
+def loop_writes(text: str, dims: tuple) -> list:
+    """Opcodes of the fused loop body's instructions that produce an
+    array of ``dims`` and are neither a kernel nor the loop's plumbing:
+    a pad, a copy (async halves included) or a fusion writing it."""
+    comps = computations(text)
+    return [op for body in set(re.findall(r"body=%([\w.\-]+)", text))
+            for _, shape, op, line in comps[body]
+            if _dims(shape) == dims and _KERNEL not in line
+            and op not in ("parameter", "get-tuple-element", "tuple",
+                           "bitcast", "constant")]
 
 
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
 def test_cell_loop_updates_in_kernel_and_keeps_steady_fields(name,
                                                              compiled_cells):
     """At each benchmark cell's grid: PW's three updates run in its one
-    kernel's epilogue, so no op of the loop body is tagged ``update`` and
-    the kernels are still one per fuse group; tracer's rule only renames
-    ``ta`` to ``t``, so the five steady fields are neither sliced for the
-    update nor re-padded — the one carry write is ``t``'s.  Every op of
-    the loop body still carries a phase."""
+    kernel's epilogue, so no op of the loop body is tagged ``update``;
+    tracer's rule only renames ``ta`` to ``t``, so the five steady fields
+    are neither sliced for the update nor re-padded.  The kernel that
+    computes each changed field stores it straight into its padded back
+    buffer, so no op of the body is tagged ``carry_write``, and none pads
+    or copies an array of a changed field's carry shape: the two-step
+    body hands the buffers back without a copy.  The kernels are one per
+    fuse group in each half of the body, and every op of the loop body
+    still carries a phase."""
     ex, compiled = compiled_cells(name)
     text = compiled.as_text()
     assert untagged_in_loop(text) == []
     assert loop_ops(text, "update") == []
+    assert loop_ops(text, "carry_write") == []
     kernels = [n for c in computations(text).values()
                for n, _, _, line in c if _KERNEL in line]
-    assert len(kernels) == len(ex.plan.groups)
+    assert len(kernels) == 2 * len(ex.plan.groups)
     spec = ex.time_spec
     carry = {f: tuple(g + int(spec.field_pad[f][a].sum())
                       for a, g in enumerate(CELL_GRIDS[name]))
@@ -327,14 +355,16 @@ def test_cell_loop_updates_in_kernel_and_keeps_steady_fields(name,
     changed = [f for f, w in spec.update_placement.items() if w != "kept"]
     if name == "pw_advection":
         assert spec.update_counts() == {"kernel": 3, "kept": 0, "xla": 0}
+        assert spec.carry_counts() == {"kernel": 3, "refill": 0,
+                                       "inplace": 0, "kept": 0}
     else:
         assert spec.update_counts() == {"kernel": 1, "kept": 5, "xla": 0}
+        assert spec.carry_counts() == {"kernel": 1, "refill": 0,
+                                       "inplace": 0, "kept": 5}
         steady = {carry[f] for f in ("un", "vn", "wn", "e3t", "msk")}
         assert carry["t"] not in steady     # shapes tell the fields apart
-        writes = loop_ops(text, "carry_write")
-        assert writes and all(dims == carry["t"] for _, dims in writes)
-    assert {dims for _, dims in loop_ops(text, "carry_write")} == {
-        carry[f] for f in changed}
+    for f in changed:
+        assert loop_writes(text, carry[f]) == [], f
 
 
 def test_epilogue_beside_a_field_left_on_xla_compiles(one_chip, mosaic):
