@@ -6,11 +6,14 @@ FPGA resources map to TPU budgets as:
     AXI ports / HBM banks -> field inputs per fuse group (memory streams)
     BRAM growth with problem size -> coefficient ('small data') bytes
 
-Derived from the actual compiled plans, per problem size.
+Derived from the actual compiled plans, per problem size.  Prints
+``name,us_per_call,derived`` CSV rows (``us_per_call`` is 0: these are
+counts, not timings):
+
+    PYTHONPATH=src python benchmarks/tab1_2_resources.py
 """
 
 from __future__ import annotations
-
 
 from repro import hw
 from repro.apps import pw_advection, tracer_advection
@@ -46,3 +49,7 @@ def run(emit):
             emit(f"tab1_2/{p.name}/{size}/small_data_bytes", 0.0,
                  f"{coeff_bytes} B coeff arrays (grows with nz, "
                  f"paper: BRAM grows with size)")
+
+
+if __name__ == "__main__":
+    run(lambda name, us, derived: print(f"{name},{us:.2f},{derived}"))

@@ -24,8 +24,8 @@ import jax                                              # noqa: E402
 import numpy as np                                      # noqa: E402
 
 from repro.apps import pw_advection, pw_advection_update  # noqa: E402
-from repro.core import compile_program, run_time_loop   # noqa: E402
-from repro.dist.sharding import make_auto_mesh          # noqa: E402
+from repro.core import (compile_program, make_auto_mesh,  # noqa: E402
+                        run_time_loop)
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 

@@ -1,2 +1,1 @@
-from .roofline import (parse_collectives, roofline_report, analytic_flops,
-                       RooflineTerms)
+"""Modeled performance of stencil plans (:mod:`.stencil_roofline`)."""

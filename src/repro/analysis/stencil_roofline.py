@@ -220,9 +220,3 @@ def model_plan(p: Program, plan, grid) -> float:
                   * STREAM_STEP_OVERHEAD_S / T)
     return max(t_mem, t_cmp) + t_step
 
-
-def modeled_energy_j(points: float, mpts: float,
-                     watts: float = hw.TPU_V5E.busy_watts) -> float:
-    """Paper Fig. 5/6 analogue: energy = execution time x busy power."""
-    seconds = points / (mpts * 1e6)
-    return seconds * watts

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the entry points.
 
-Scripts (``chip_smoke.py``, ``benchmarks/run.py``, the examples) call
+Scripts (``chip_smoke.py``, the examples) call
 :func:`enable_compile_cache` first thing, so a second run of the same
 program on the same device reuses its compiled kernels.  The library never
 sets a cache on import: the tests run without one.

@@ -7,6 +7,7 @@ from .boundary import BOUNDARIES
 from .dataflow import (StreamGraph, StreamRegion, chain_split_reason,
                        effective_plane_tile, effective_time_tile,
                        lower_to_dataflow, plane_split_reason)
+from .distribute import make_auto_mesh
 from .ir import Program
 from .pipeline import (CompiledStencil, CompileOptions, TileDemotionWarning,
                        compile_program, run_time_loop)

@@ -654,6 +654,12 @@ def lower_sharded_time_loop(p: Program, plan: DataflowPlan, global_grid,
     return run
 
 
+def make_auto_mesh(shape, axes, **kwargs):
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+
+
 # --------------------------------------------------------------------------
 # deprecated standalone entry point
 # --------------------------------------------------------------------------

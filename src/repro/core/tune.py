@@ -15,9 +15,8 @@ auto_plan` is the one-shot heuristic seed; this module closes the loop:
    :func:`~repro.analysis.stencil_roofline.model_plan` ranks the rest so
    only the most promising ``max_measured`` candidates pay for a run;
 3. **measure** the survivors on-device (warm-up + best-of-k with
-   ``block_until_ready``, the same discipline as
-   ``benchmarks/fig4_throughput.py``), in both single-step and fused
-   ``steps=N`` modes when an update rule is available;
+   ``block_until_ready``), in both single-step and fused ``steps=N``
+   modes when an update rule is available;
 4. **persist** the winner in a JSON plan cache keyed by (program
    fingerprint, grid, backend, jax version, interpreted or compiled
    kernels), so

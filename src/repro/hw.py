@@ -2,7 +2,7 @@
 
 These numbers describe the *target* the plans, kernels and rooflines are
 derived for, whatever backend the process runs on.  Sources: public TPU v5e
-datasheet figures (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI).
+datasheet figure (819 GB/s HBM).
 """
 
 from __future__ import annotations
@@ -15,32 +15,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
-    name: str
-    peak_bf16_flops: float      # FLOP/s per chip
     hbm_bandwidth: float        # bytes/s per chip
-    hbm_bytes: int              # HBM capacity per chip
-    ici_link_bandwidth: float   # bytes/s per link, per direction
-    ici_links: int              # links per chip (2D torus on v5e: 4)
     vmem_bytes: int             # per-core VMEM
-    smem_bytes: int             # scalar memory (approximate)
-    mxu_shape: tuple = (128, 128)
     sublanes: int = 8
     lanes: int = 128
-    # crude power envelope for the paper's Fig.5/6 energy *model* (W per chip)
-    busy_watts: float = 200.0
-    idle_watts: float = 60.0
 
 
-TPU_V5E = ChipSpec(
-    name="tpu-v5e",
-    peak_bf16_flops=197e12,
-    hbm_bandwidth=819e9,
-    hbm_bytes=16 * 1024**3,
-    ici_link_bandwidth=50e9,
-    ici_links=4,
-    vmem_bytes=128 * 1024**2,
-    smem_bytes=1024**2,
-)
+TPU_V5E = ChipSpec(hbm_bandwidth=819e9, vmem_bytes=128 * 1024**2)
 
 # Scoped-VMEM limit every generated Pallas kernel is compiled with (Mosaic's
 # own default is 16 MiB, below what the planner's plans claim).

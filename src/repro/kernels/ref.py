@@ -1,16 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracle for the Pallas kernels in this package.
 
 ``stencil3d`` kernels are validated against ``repro.core.lower_jnp``
-(the Von-Neumann reference executes the same IR); this module adds the
-attention oracle and re-exports the stencil one for the per-kernel tests.
+(the Von-Neumann reference executes the same IR); this module re-exports
+it for the per-kernel tests.
 """
 
 from __future__ import annotations
-
-import math
-
-import jax
-import jax.numpy as jnp
 
 from ..core import lower_jnp
 
@@ -19,21 +14,3 @@ def stencil_reference(program, fields, scalars=None, coeffs=None):
     """Oracle for kernels built by stencil3d.build_group_call."""
     return lower_jnp.lower(program, mode="naive")(fields, scalars or {},
                                                   coeffs or {})
-
-
-def swa_reference(q, k, v, *, window: int):
-    """Dense masked causal sliding-window attention, f32 accumulation.
-
-    q, k, v: (B, S, H, D) with H already GQA-repeated.
-    """
-    B, S, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    qpos = jnp.arange(S)[:, None]
-    kpos = jnp.arange(S)[None, :]
-    ok = (kpos <= qpos) & (kpos > qpos - window)
-    logits = jnp.where(ok[None, None], logits, -1e30)
-    wgt = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", wgt, v.astype(jnp.float32))
-    return out.astype(q.dtype)

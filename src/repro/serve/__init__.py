@@ -1,7 +1,4 @@
-"""repro.serve — the stencil serving subsystem.
-
-(The LM serving engine formerly here lives in ``repro.models.lm_serve``.)
-"""
+"""repro.serve — the stencil serving subsystem."""
 
 from ..core.schedule import BucketSpec, bucket_fingerprint, bucket_for
 from .bucket import (crop, embed_coeff, embed_field, embed_request,

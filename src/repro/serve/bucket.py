@@ -33,7 +33,7 @@ import numpy as np
 
 from ..core import boundary as bc
 from ..core.ir import Program
-from ..core.schedule import BucketSpec, adapt_update, bucket_for  # noqa: F401
+from ..core.schedule import BucketSpec, adapt_update
 
 SIZE_SCALAR_PREFIX = "_srv_n"
 

@@ -241,8 +241,7 @@ def test_cyclic_fused_loop_matches_plain_reference():
 MESH_SCRIPT = r"""
 import numpy as np, jax
 from repro.apps import tracer_advection, tracer_advection_update
-from repro.core import compile_program
-from repro.dist.sharding import make_auto_mesh
+from repro.core import compile_program, make_auto_mesh
 assert jax.device_count() == 4
 from test_boundary import tracer_data
 grid = (8, 8, 64)

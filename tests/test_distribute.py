@@ -18,10 +18,9 @@ SCRIPT = r"""
 import warnings
 import numpy as np, jax, jax.numpy as jnp
 from repro.apps import pw_advection, tracer_advection
-from repro.core import compile_program
+from repro.core import compile_program, make_auto_mesh
 from repro.core.frontend import ProgramBuilder
 from repro.core.distribute import make_sharded_executor
-from repro.dist.sharding import make_auto_mesh
 
 rng = np.random.default_rng(7)
 
@@ -117,9 +116,8 @@ DEPRECATION_SCRIPT = r"""
 import warnings
 import numpy as np, jax
 from repro.apps import pw_advection
-from repro.core import compile_program
+from repro.core import compile_program, make_auto_mesh
 from repro.core.distribute import make_sharded_executor
-from repro.dist.sharding import make_auto_mesh
 
 assert jax.device_count() == 2
 rng = np.random.default_rng(11)

@@ -26,8 +26,8 @@ SCRIPT = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
-from repro.core import compile_program, run_time_loop, PlanCache, TuneConfig
-from repro.dist.sharding import make_auto_mesh
+from repro.core import (compile_program, make_auto_mesh, run_time_loop,
+                        PlanCache, TuneConfig)
 
 rng = np.random.default_rng(7)
 assert jax.device_count() == 8

@@ -1,14 +1,12 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (deliverable c)."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.apps import pw_advection, tracer_advection
 from repro.core import compile_program
-from repro.kernels.ops import sliding_window_attention, stencil_apply
-from repro.kernels.ref import stencil_reference, swa_reference
+from repro.kernels.ops import stencil_apply
+from repro.kernels.ref import stencil_reference
 
 from strategies import make_data
 
@@ -39,48 +37,3 @@ def test_stencil_apply_wrapper():
     np.testing.assert_allclose(np.asarray(got["ta"]), np.asarray(ref["ta"]),
                                atol=1e-3, rtol=1e-3)
 
-
-# ------------------------------------------------------------------ swa
-
-@pytest.mark.parametrize("S,w,Bq", [(256, 64, 128), (256, 128, 64),
-                                    (512, 256, 128), (128, 32, 128)])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)])
-def test_swa_kernel_sweep(S, w, Bq, dtype, tol):
-    B, H, D = 2, 4, 64
-    key = jax.random.PRNGKey(S + w)
-    q, k, v = (jax.random.normal(kk, (B, S, H, D), dtype=dtype)
-               for kk in jax.random.split(key, 3))
-    got = sliding_window_attention(q, k, v, window=w, q_block=Bq)
-    ref = swa_reference(q, k, v, window=w)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=tol, rtol=tol)
-
-
-def test_swa_kernel_gqa():
-    B, S, H, KV, D, w = 2, 256, 8, 2, 64, 64
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, S, H, D))
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, D))
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, D))
-    got = sliding_window_attention(q, k, v, window=w)
-    kr = jnp.repeat(k, H // KV, axis=2)
-    vr = jnp.repeat(v, H // KV, axis=2)
-    ref = swa_reference(q, kr, vr, window=w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_swa_matches_model_layer_path():
-    """Kernel agrees with the jnp swa_attention used inside the models."""
-    from repro.models.layers import AttnSpec, swa_attention
-    B, S, H, D, w = 2, 256, 4, 64, 64
-    key = jax.random.PRNGKey(3)
-    q, k, v = (jax.random.normal(kk, (B, S, H, D))
-               for kk in jax.random.split(key, 3))
-    spec = AttnSpec(n_heads=H, n_kv_heads=H, d_head=D, window=w, chunk=256)
-    a = swa_attention(q, k, v, spec)
-    b = sliding_window_attention(q, k, v, window=w)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               atol=2e-5, rtol=2e-5)

@@ -256,8 +256,7 @@ MESH_SCRIPT = r"""
 import numpy as np, jax
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
-from repro.core import CompileOptions, compile_program
-from repro.dist.sharding import make_auto_mesh
+from repro.core import CompileOptions, compile_program, make_auto_mesh
 
 rng = np.random.default_rng(11)
 assert jax.device_count() == 2

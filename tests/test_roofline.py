@@ -1,102 +1,13 @@
-"""Unit tests for the roofline analysis machinery (deliverable g)."""
+"""Unit tests for the stencil plan model (``repro.analysis.stencil_roofline``):
+reuse-aware bytes per point."""
 
-import numpy as np
-import pytest
+import dataclasses
 
-from repro.analysis.roofline import (analytic_flops, analytic_traffic,
-                                     parse_collectives, roofline_report,
-                                     RooflineTerms)
-from repro.configs import SHAPES, get_config
+from repro.analysis.stencil_roofline import (model_plan, model_program,
+                                             plan_bytes_per_point)
+from repro.apps import pw_advection
+from repro.core.schedule import auto_plan
 
-HLO = """
-HloModule jit_step
-
-%region_0.1 (arg.1: f32[128,256]) -> f32[128,256] {
-  %ar = f32[128,256]{1,0} all-reduce(%x), replica_groups=[16,16]<=[256], to_apply=%add
-  ROOT %r = f32[128,256]{1,0} add(%ar, %ar)
-}
-
-ENTRY %main (p0: f32[128,256]) -> f32[128,256] {
-  %ag = bf16[64,512]{1,0} all-gather(%p1), replica_groups=[2,128]<=[256], dimensions={0}
-  %w = f32[128,256]{1,0} while(%init), condition=%region_1.2, body=%region_0.1
-  %cp = f32[32]{0} collective-permute(%y), source_target_pairs={{0,1},{1,2}}
-  ROOT %out = f32[128,256]{1,0} add(%w, %w)
-}
-"""
-
-
-def test_parse_collectives_shapes_and_groups():
-    coll = parse_collectives(HLO)
-    ops = {c["op"]: c for c in coll}
-    assert set(ops) == {"all-reduce", "all-gather", "collective-permute"}
-    ar = ops["all-reduce"]
-    assert ar["bytes"] == 128 * 256 * 4
-    assert ar["group"] == 16
-    assert ar["wire"] == 2 * ar["bytes"] * 15 // 16
-    assert ar["in_loop"]        # region_0.1 is the while body
-    ag = ops["all-gather"]
-    assert ag["bytes"] == 64 * 512 * 2
-    assert ag["group"] == 128
-    assert not ag["in_loop"]
-    assert ops["collective-permute"]["wire"] == 32 * 4
-
-
-def test_loop_correction_applies_only_inside_while():
-    rep = roofline_report(chips=256, cost={"flops": 1e9,
-                                           "bytes accessed": 1e9},
-                          hlo_text=HLO, scan_correction=10.0)
-    coll = parse_collectives(HLO)
-    base = sum(c["wire"] for c in coll)
-    loop = sum(c["wire"] for c in coll if c["in_loop"])
-    assert rep["wire_per_dev_loop_corrected"] == pytest.approx(
-        base - loop + 10.0 * loop)
-
-
-def test_dominant_term():
-    t = RooflineTerms(compute_s=1.0, memory_s=0.5, collective_s=2.0)
-    assert t.dominant == "collective"
-
-
-def test_analytic_flops_moe_counts_active_only():
-    mix = get_config("mixtral_8x7b")
-    dense_equiv = mix.num_params()
-    active = mix.num_active_params()
-    assert active < 0.4 * dense_equiv          # top-2 of 8 experts
-    af = analytic_flops(mix, SHAPES["train_4k"])
-    tokens = 4096 * 256
-    assert af["model_flops"] >= 6.0 * active * tokens
-    assert af["model_flops"] < 6.5 * active * tokens + 1e18
-
-
-def test_analytic_flops_decode_linear_in_batch():
-    cfg = get_config("h2o_danube_1_8b")
-    a = analytic_flops(cfg, SHAPES["decode_32k"])
-    # decode flops ~ 2*N*B (+ window attention); far below a train step
-    b = analytic_flops(cfg, SHAPES["train_4k"])
-    assert a["total"] < b["total"] / 100
-
-
-def test_analytic_traffic_decode_memory_floor():
-    """Decode HBM floor >= one pass over the TP-sharded active params."""
-    cfg = get_config("h2o_danube_1_8b")
-    tr = analytic_traffic(cfg, SHAPES["decode_32k"], chips=256, tp=16,
-                          fsdp=1, dp_total=16)
-    assert tr["bytes_per_dev"] >= 2 * cfg.num_active_params() / 16
-
-
-def test_traffic_train_fsdp_wire_scales_with_params():
-    small = get_config("h2o_danube_1_8b")
-    big = get_config("nemotron_4_340b")
-    ws = analytic_traffic(small, SHAPES["train_4k"], chips=256, tp=16,
-                          fsdp=16, dp_total=16)["wire_per_dev"]
-    wb = analytic_traffic(big, SHAPES["train_4k"], chips=256, tp=16,
-                          fsdp=16, dp_total=16)["wire_per_dev"]
-    assert wb > ws
-
-
-# ----------------------------------------------------------------------
-# Stencil plan model (repro.analysis.stencil_roofline): reuse-aware bytes
-# ----------------------------------------------------------------------
 
 def test_stream_schedule_models_fewer_bytes_than_block():
     """The stream schedule charges each input cell once per sweep; the
@@ -104,13 +15,6 @@ def test_stream_schedule_models_fewer_bytes_than_block():
     advection kernel with a deliberately small block, the modeled
     bytes/point must separate — and the stream number must sit at the
     read-once floor (inputs + outputs, plus only the halo-ring fraction)."""
-    import dataclasses
-
-    from repro.analysis.stencil_roofline import (model_plan,
-                                                 plan_bytes_per_point)
-    from repro.apps import pw_advection
-    from repro.core.schedule import auto_plan
-
     p = pw_advection()
     grid = (32, 32, 128)
     block = auto_plan(p, grid)
@@ -136,13 +40,6 @@ def test_stream_schedule_models_fewer_bytes_than_block():
 def test_model_plan_block_schedule_unchanged_for_jnp_backends():
     """Non-pallas candidates still collapse to the backend-level model —
     the schedule axis is a pallas-only dimension."""
-    import dataclasses
-
-    from repro.analysis.stencil_roofline import (model_program,
-                                                 plan_bytes_per_point)
-    from repro.apps import pw_advection
-    from repro.core.schedule import auto_plan
-
     p = pw_advection()
     grid = (16, 16, 128)
     plan = dataclasses.replace(auto_plan(p, grid, backend="jnp_naive"),

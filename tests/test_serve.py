@@ -406,7 +406,7 @@ def test_engine_accepts_compile_options():
                       options=CompileOptions(dtype="float64"),
                       autostart=False)
     # mesh= without mesh_axes= is rejected up front
-    from repro.dist.sharding import make_auto_mesh
+    from repro.core import make_auto_mesh
     with pytest.raises(ValueError, match="mesh_axes"):
         StencilEngine(mesh=make_auto_mesh((1,), ("X",)), autostart=False)
 
@@ -415,7 +415,7 @@ def test_engine_mesh_topology_keys_executors():
     """The same request served under a mesh and locally must occupy
     distinct executor-table entries (and the sharded answer must agree
     with the local one — a 1x1 mesh runs on the single default device)."""
-    from repro.dist.sharding import make_auto_mesh
+    from repro.core import make_auto_mesh
     p = pw_advection()
     req = make_request(p, (8, 8, 16), seed=0)
     mesh = make_auto_mesh((1,), ("X",))
@@ -440,7 +440,7 @@ def test_engine_rejects_periodic_fused_under_sharded_mesh():
     script = r"""
 import numpy as np
 from repro.apps.advection import pw_advection, pw_advection_update
-from repro.dist.sharding import make_auto_mesh
+from repro.core import make_auto_mesh
 from repro.serve import StencilEngine, StencilRequest
 p = pw_advection(boundary="periodic")
 grid = (8, 8, 16)

@@ -178,7 +178,7 @@ def test_stream_requires_pallas_backend():
 def test_stream_composes_with_degenerate_mesh():
     # stream + mesh= is first-class; a 1x1 mesh must bit-match the local
     # stream lowering (the sharded path constant-folds to the same graph)
-    from repro.dist.sharding import make_auto_mesh
+    from repro.core import make_auto_mesh
     p = pw_advection()
     grid = (8, 8, 32)
     plan = auto_plan(p, grid, schedule="stream")

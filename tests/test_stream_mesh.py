@@ -26,9 +26,9 @@ import tempfile
 import numpy as np, jax, jax.numpy as jnp
 from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
                         tracer_advection_update)
-from repro.core import PlanCache, TuneConfig, auto_plan, compile_program
+from repro.core import (PlanCache, TuneConfig, auto_plan, compile_program,
+                        make_auto_mesh)
 from repro.core.tune import plan_from_dict, plan_to_dict, tune_plan
-from repro.dist.sharding import make_auto_mesh
 
 rng = np.random.default_rng(11)
 assert jax.device_count() == 8

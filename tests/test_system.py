@@ -1,22 +1,14 @@
-"""End-to-end system behaviour: the paper's full pipeline, both domains.
-
-Stencil side: frontend -> IR -> auto-plan -> Pallas dataflow kernels ->
-time-stepped solve (PW advection, the paper's benchmark 1).
-LM side: data pipeline -> training with checkpoints -> serving with
-ring-buffer caches, all through the public APIs.
+"""End-to-end system behaviour: the paper's full pipeline, frontend -> IR
+-> auto-plan -> Pallas dataflow kernels -> time-stepped solve (PW
+advection, the paper's benchmark 1), through the public APIs.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.apps import pw_advection, tracer_advection
 from repro.core import compile_program, run_time_loop
 from repro.core.schedule import vmem_cost
-from repro.configs import get_smoke
-from repro.data import BatchSpec, SyntheticLM
-from repro.models import ServeEngine
-from repro.train import OptConfig, TrainConfig, Trainer
 
 
 def _pw_data(grid, seed=0):
@@ -61,19 +53,3 @@ def test_plan_respects_vmem_budget_on_both_apps():
         ex = compile_program(prog, grid, backend="jnp_fused")  # plan only
         assert vmem_cost(prog, ex.plan, grid) <= hw.VMEM_PLAN_BUDGET
 
-
-def test_full_lm_system_train_then_serve(tmp_path):
-    """Train a smoke model through the Trainer (with a checkpoint), then
-    serve from the trained weights — the whole substrate in one path."""
-    cfg = get_smoke("gemma3_1b")
-    spec = BatchSpec(global_batch=4, seq_len=24, vocab=cfg.vocab)
-    tcfg = TrainConfig(opt=OptConfig(lr=1e-3, total_steps=20),
-                       ckpt_every=4, ckpt_dir=str(tmp_path / "ck"),
-                       log_every=1000)
-    tr = Trainer(cfg, tcfg, SyntheticLM(spec, seed=0))
-    hist = tr.run(6)
-    assert all(np.isfinite(h["loss"]) for h in hist)
-    eng = ServeEngine(cfg, tr.state["params"], batch=2, max_len=64)
-    out = eng.generate(np.zeros((2, 6), np.int32), max_new_tokens=4)
-    assert out.shape == (2, 4)
-    assert (out >= 0).all() and (out < cfg.vocab).all()

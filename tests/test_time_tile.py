@@ -80,28 +80,73 @@ def test_chained_stream_remainder_epilogue(steps, tile):
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_chained_update_traces_once_per_stage():
-    """The update rule is baked into the kernel between chain stages: it
-    traces exactly T times at compile (once per stage), never per step or
-    per call."""
-    prog_fn, _update, data_fn, grid = KERNELS["pw_advection"]
+#: The eight fused-loop rows the CPU speed floors once timed (schedule,
+#: time_tile T, plane_tile P), each with the number of times one compile
+#: traces PW's update rule; tracer's is traced once in every row.  No
+#: count grows with the step count:
+#:
+#: * block: 1, the build-time read that places the updates; PW's three
+#:   then run in its kernel's epilogue, tracer's ``t`` is the kernel's
+#:   ``ta`` and its other five fields stay in the carry, so the loop body
+#:   never calls the rule.
+#: * stream at T = 1 (any P): PW 2, the build-time read and one call into
+#:   the loop body, where its updates stay on XLA (a stream kernel takes
+#:   no epilogue); tracer 1, its rule only renames ``ta``.
+#: * a chain (T = 4): the chain kernel calls the rule once per stage and
+#:   makes no build-time read.  Seven stages at P = 1: four in the loop's
+#:   chain (traced at 3 steps too, where the loop never runs) and three in
+#:   the chain after it that runs the ``steps % 4 = 3`` left over (3 and 7
+#:   steps leave the same).  P = 4 runs every stage on each of the four
+#:   planes of a sweep step: 28.
+#: * tracer legalises to four stream regions, so every chain demotes to
+#:   T = 1, and its count stays 1.
+TRACE_ROWS = {
+    "block": ({"schedule": "block"}, 1),
+    "stream": ({"schedule": "stream"}, 2),
+    "stream-T1": ({"schedule": "stream", "time_tile": 1}, 2),
+    "stream-T4": ({"schedule": "stream", "time_tile": 4}, 7),
+    "P1T1": ({"schedule": "stream", "plane_tile": 1, "time_tile": 1}, 2),
+    "P1T4": ({"schedule": "stream", "plane_tile": 1, "time_tile": 4}, 7),
+    "P4T1": ({"schedule": "stream", "plane_tile": 4, "time_tile": 1}, 2),
+    "P4T4": ({"schedule": "stream", "plane_tile": 4, "time_tile": 4}, 28),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+@pytest.mark.parametrize("row", list(TRACE_ROWS))
+def test_chained_update_traces_once_per_stage(row, kernel):
+    """The update rule is traced at compile only (``TRACE_ROWS`` says how
+    often), never per step or per call: a second call traces nothing, and
+    3 steps trace as often as 7.  So the whole loop stays one dispatch
+    that a warm request does not rebuild."""
+    opts, pw_traces = TRACE_ROWS[row]
+    prog_fn, _update, data_fn, _grid = KERNELS[kernel]
+    grid = (16, 16, 16)
     p = prog_fn()
     fields, scalars, coeffs = data_fn(grid)
-    traces = {"n": 0}
+    pw = kernel == "pw_advection"
+    want = pw_traces if pw else 1
+    for steps in (3, 7):
+        traces = {"n": 0}
 
-    def counting_update(flds, out):
-        traces["n"] += 1
-        return {"u": flds["u"] + 0.1 * out["su"],
-                "v": flds["v"] + 0.1 * out["sv"],
-                "w": flds["w"] + 0.1 * out["sw"]}
+        def counting_update(flds, out):
+            traces["n"] += 1
+            if pw:
+                return {"u": flds["u"] + 0.1 * out["su"],
+                        "v": flds["v"] + 0.1 * out["sv"],
+                        "w": flds["w"] + 0.1 * out["sw"]}
+            return dict(flds, t=out["ta"])
 
-    ex = compile_program(p, grid, schedule="stream", steps=8,
-                         update=counting_update, time_tile=4)
-    assert ex.plan.stream.time_tile == 4
-    ex(fields, scalars, coeffs)
-    assert traces["n"] == 4                   # one per chain stage
-    ex(fields, scalars, coeffs)               # second call: jit cache hit
-    assert traces["n"] == 4
+        ex = compile_program(p, grid, options=CompileOptions(
+            steps=steps, update=counting_update, **opts))
+        if opts["schedule"] == "stream":
+            tile = opts.get("time_tile", 1) if pw else 1
+            assert (ex.plan.stream.time_tile, ex.plan.stream.plane_tile) \
+                == (tile, opts.get("plane_tile", 1))
+        ex(fields, scalars, coeffs)
+        assert traces["n"] == want, steps
+        ex(fields, scalars, coeffs)           # second call: jit cache hit
+        assert traces["n"] == want, steps
 
 
 # ------------------------------------------------------------ legalisation

@@ -36,9 +36,14 @@ GRID_8M = (256, 256, 128)
 GRID_32M = (512, 256, 256)
 #: the benchmark cells' own grids
 CELL_GRIDS = {"pw_advection": (1024, 512, 256),
-              "tracer_advection": (512, 256, 256)}
+              "tracer_advection": (512, 256, 256),
+              "tracer_advection_cyclic": (512, 256, 256)}
 PROGRAMS = {"pw_advection": (pw_advection, lambda: pw_advection_update(0.1)),
-            "tracer_advection": (tracer_advection, tracer_advection_update)}
+            "tracer_advection": (tracer_advection, tracer_advection_update),
+            # NEMO's east-west cyclic domain, as the benchmark states it
+            "tracer_advection_cyclic": (
+                lambda: tracer_advection(boundary=["periodic", "zero", "zero"]),
+                tracer_advection_update)}
 
 
 @pytest.fixture(scope="module")
@@ -327,6 +332,19 @@ def loop_writes(text: str, dims: tuple) -> list:
                            "bitcast", "constant")]
 
 
+#: where each cell's fused loop updates each field, and how the new value
+#: reaches the carry (``update_counts()``, ``carry_counts()``)
+CELL_PLACEMENT = {
+    "pw_advection": ({"kernel": 3, "kept": 0, "xla": 0},
+                     {"kernel": 3, "refill": 0, "inplace": 0, "kept": 0}),
+    "tracer_advection": ({"kernel": 1, "kept": 5, "xla": 0},
+                         {"kernel": 1, "refill": 0, "inplace": 0, "kept": 5}),
+    "tracer_advection_cyclic": ({"kernel": 1, "kept": 5, "xla": 0},
+                                {"kernel": 0, "refill": 1, "inplace": 0,
+                                 "kept": 5}),
+}
+
+
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
 def test_cell_loop_updates_in_kernel_and_keeps_steady_fields(name,
                                                              compiled_cells):
@@ -334,37 +352,37 @@ def test_cell_loop_updates_in_kernel_and_keeps_steady_fields(name,
     kernel's epilogue, so no op of the loop body is tagged ``update``;
     tracer's rule only renames ``ta`` to ``t``, so the five steady fields
     are neither sliced for the update nor re-padded.  The kernel that
-    computes each changed field stores it straight into its padded back
-    buffer, so no op of the body is tagged ``carry_write``, and none pads
-    or copies an array of a changed field's carry shape: the two-step
-    body hands the buffers back without a copy.  The kernels are one per
-    fuse group in each half of the body, and every op of the loop body
-    still carries a phase."""
+    computes each changed field zero on every axis stores it straight into
+    its padded back buffer, so no op of the body is tagged
+    ``carry_write``, and none pads or copies an array of that field's
+    carry shape: the two-step body hands the buffers back without a copy.
+    The cyclic ``t`` wraps along axis 0, so XLA refills its carry, with
+    one fusion tagged ``wrap``, and the body runs one step.  The kernels
+    are one per fuse group in each step of the body, and every op of the
+    loop body still carries a phase."""
     ex, compiled = compiled_cells(name)
     text = compiled.as_text()
     assert untagged_in_loop(text) == []
     assert loop_ops(text, "update") == []
     assert loop_ops(text, "carry_write") == []
+    spec = ex.time_spec
+    assert (spec.update_counts(), spec.carry_counts()) == CELL_PLACEMENT[name]
     kernels = [n for c in computations(text).values()
                for n, _, _, line in c if _KERNEL in line]
-    assert len(kernels) == 2 * len(ex.plan.groups)
-    spec = ex.time_spec
+    halves = 2 if spec.carry_counts()["kernel"] else 1
+    assert len(kernels) == halves * len(ex.plan.groups)
     carry = {f: tuple(g + int(spec.field_pad[f][a].sum())
                       for a, g in enumerate(CELL_GRIDS[name]))
              for f in spec.persistent}
-    changed = [f for f, w in spec.update_placement.items() if w != "kept"]
-    if name == "pw_advection":
-        assert spec.update_counts() == {"kernel": 3, "kept": 0, "xla": 0}
-        assert spec.carry_counts() == {"kernel": 3, "refill": 0,
-                                       "inplace": 0, "kept": 0}
-    else:
-        assert spec.update_counts() == {"kernel": 1, "kept": 5, "xla": 0}
-        assert spec.carry_counts() == {"kernel": 1, "refill": 0,
-                                       "inplace": 0, "kept": 5}
+    if name != "pw_advection":
         steady = {carry[f] for f in ("un", "vn", "wn", "e3t", "msk")}
         assert carry["t"] not in steady     # shapes tell the fields apart
-    for f in changed:
-        assert loop_writes(text, carry[f]) == [], f
+    for f, how in spec.carry_placement.items():
+        if how == "kernel":
+            assert loop_writes(text, carry[f]) == [], f
+        elif how == "refill":
+            assert loop_writes(text, carry[f]) == ["fusion"], f
+            assert ("fusion", carry[f]) in loop_ops(text, "wrap"), f
 
 
 def test_epilogue_beside_a_field_left_on_xla_compiles(one_chip, mosaic):
