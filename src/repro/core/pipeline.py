@@ -34,8 +34,8 @@ from . import dataflow, distribute, lower_jnp, lower_pallas, lower_stream
 from .ir import Program
 from .passes import infer_halo
 from .schedule import (DataflowPlan, ShardSpec, TimeLoopSpec, auto_plan,
-                       make_shard_spec, normalize_mesh_axes, plan_time_loop,
-                       plane_local, shard_local_grid)
+                       fuse_split, make_shard_spec, normalize_mesh_axes,
+                       plan_time_loop, plane_local, shard_local_grid)
 
 _BACKENDS = ("pallas", "jnp_fused", "jnp_naive")
 
@@ -343,6 +343,10 @@ def _compile(p: Program, grid, o: CompileOptions, tracer,
                              schedule=schedule or "block",
                              time_tile=time_tile or 1,
                              plane_tile=plane_tile or 1)
+            # how the planner cut the strategy's fuse groups to fit VMEM
+            metrics.counter(
+                f"compile.fuse_split.{fuse_split(p, plan, strategy)}").inc()
+            metrics.counter("compile.fuse_groups").inc(len(plan.groups))
     # plans can be shared (PlanCache entries, caller-held objects): the
     # compiled executable always gets its own deep copy, retargeted to the
     # requested backend/mesh, so no compile ever mutates another's plan

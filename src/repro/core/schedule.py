@@ -919,6 +919,9 @@ def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
     uses the carry-aware :func:`vmem_cost`, so blocks whose loop-carry
     padding enlarges the kernel windows past the budget are shrunk here
     rather than discovered over budget at run time.
+
+    Where the groups do not fit even at the smallest tile,
+    :func:`budget_split` cuts them into contiguous runs that do.
     """
     grid = tuple(int(g) for g in grid)
     ndim = p.ndim
@@ -941,29 +944,56 @@ def auto_plan(p: Program, grid: Sequence[int], *, backend: str = "pallas",
     n_tiled = max(0, ndim - 2)
     blk = list(cap_tile([32 if ndim == 3 else 256] * n_tiled, grid))
 
-    def fits(b):
-        plan = DataflowPlan(groups=groups, block=tuple(b), dtype=dtype,
+    def fits(gs):
+        plan = DataflowPlan(groups=gs, block=tuple(blk), dtype=dtype,
                             backend=backend, mesh_axes=(None,) * ndim)
         return vmem_cost(p, plan, grid, steps=steps) <= vmem_budget
 
     # halve the leading axes, outermost first
-    guard = 0
-    while not fits(blk) and guard < 64:
-        guard += 1
-        shrunk = False
-        for ax in range(n_tiled):
-            if blk[ax] > 1:
-                blk[ax] //= 2
-                shrunk = True
-                break
-        if not shrunk:
-            # cannot shrink further: split groups per field instead
-            if any(len(g) > 1 for g in groups):
-                groups = stage_split(p, "per_field")
-            else:
-                break
+    for ax in range(n_tiled):
+        while blk[ax] > 1 and not fits(groups):
+            blk[ax] //= 2
+    if not fits(groups):
+        groups = budget_split(groups, fits)
     return DataflowPlan(groups=groups, block=tuple(blk), dtype=dtype,
                         backend=backend, mesh_axes=(None,) * ndim)
+
+
+def budget_split(groups: list, fits) -> list:
+    """Cut each fuse group into contiguous runs, in program order, that
+    ``fits``.
+
+    A run grows op by op while the whole plan, the runs so far plus every
+    op not yet placed as a group of its own, still ``fits``; the next op
+    that would break it starts a new run.  The plan priced at each step
+    holds every group, so a later group's deeper halo, which widens the
+    loop carry and with it an earlier group's windows, is priced too.
+    Where no two ops fit together the result is one op per group, as
+    ``stage_split(p, "per_field")``; no run crosses a cut of ``groups``.
+    """
+    ops = [i for g in groups for i in g]
+    starts = {g[0] for g in groups if g}
+    out: list = []
+    cur: list = []
+    for k, i in enumerate(ops):
+        if cur and (i in starts or not fits(
+                out + [cur + [i]] + [[j] for j in ops[k + 1:]])):
+            out.append(cur)
+            cur = []
+        cur.append(i)
+    return out + [cur] if cur else out
+
+
+def fuse_split(p: Program, plan: DataflowPlan, strategy: str = "auto") -> str:
+    """How :func:`auto_plan` split ``strategy``'s fuse groups into
+    ``plan``'s: ``"whole"`` (kept as :func:`stage_split` made them),
+    ``"per_field"`` (one op per group) or ``"budget"`` (cut by
+    :func:`budget_split` into larger runs)."""
+    if plan.groups == stage_split(p, strategy):
+        return "whole"
+    if all(len(g) == 1 for g in plan.groups):
+        return "per_field"
+    return "budget"
 
 
 def _auto_plan_stream(p: Program, grid: tuple, groups: list, *,

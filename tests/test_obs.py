@@ -12,6 +12,8 @@ Invariants:
   typed ChainDemoted/PlaneDemoted events when traced.
 * PlanCache counts its own hits/misses; a warm tuned compile is provably
   zero timed runs via the ``tune.timed_runs`` counter.
+* A compile that plans counts how the planner cut its fuse groups
+  (``compile.fuse_split.*``) and how many it made (``compile.fuse_groups``).
 """
 
 import json
@@ -21,7 +23,8 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.apps import pw_advection, pw_advection_update
+from repro.apps import (pw_advection, pw_advection_update, tracer_advection,
+                        tracer_advection_update)
 from repro.core import (PlanCache, TileDemotionWarning, TuneConfig,
                         compile_program)
 from repro.core.frontend import ProgramBuilder
@@ -425,6 +428,44 @@ def test_compile_metrics_counters_advance():
                     schedule="stream")
     assert m.counter("compile.compiles").value == c0 + 1
     assert m.counter("compile.stream_lowerings").value == s0 + 1
+
+
+def _fuse_split_counts():
+    m = global_metrics()
+    got = {k: m.counter(f"compile.fuse_split.{k}").value
+           for k in ("whole", "budget", "per_field")}
+    got["groups"] = m.counter("compile.fuse_groups").value
+    return got
+
+
+@pytest.mark.parametrize("case,expected", [
+    ("pw-fits", {"whole": 1, "groups": 1}),
+    ("tracer-cell", {"budget": 1, "groups": 2}),
+    ("tracer-cyclic-cell", {"budget": 1, "groups": 2}),
+    ("explicit-plan", {})],
+    ids=["pw-fits", "tracer-cell", "tracer-cyclic-cell", "explicit-plan"])
+def test_fuse_split_counters_say_how_the_planner_cut(case, expected):
+    """A compile that plans counts how it cut the fuse groups once, and
+    the groups it made; one handed a plan counts nothing.  The tracer's
+    24-op group overflows VMEM at its cell's 512x256x256 grid (planning
+    only: nothing runs)."""
+    before = _fuse_split_counts()
+    if case == "pw-fits":
+        compile_program(pw_advection(), GRID, steps=2,
+                        update=pw_advection_update(0.1))
+    elif case == "explicit-plan":
+        from repro.core.schedule import auto_plan
+        p = small_program()
+        compile_program(p, GRID, plan=auto_plan(p, GRID))
+    else:
+        boundary = ("zero" if case == "tracer-cell"
+                    else ["periodic", "zero", "zero"])
+        compile_program(tracer_advection(boundary=boundary),
+                        (512, 256, 256), steps=100,
+                        update=tracer_advection_update())
+    after = _fuse_split_counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        k: expected.get(k, 0) for k in after}
 
 
 # --------------------------------------------------- PlanCache + tuner obs
